@@ -31,6 +31,7 @@ import time
 import traceback
 
 from benchmarks import util
+from repro.compile_cache import enable_compile_cache
 
 MODULES = [
     ("measurement", "benchmarks.fig_measurement_study"),
@@ -159,6 +160,7 @@ def main() -> None:
                     help="regenerate benchmarks/baselines.json from this run")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = []

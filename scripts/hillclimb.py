@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")
 
 """§Perf hillclimb driver: compile one (arch x shape) under named

@@ -64,11 +64,15 @@ F64 = np.float64
 # Reference jit kernels (shared with the legacy OnlineCSC path)
 # ---------------------------------------------------------------------------
 
+# every dot is full f32: a backend may otherwise run an f32 matmul as a
+# single bf16 pass (the TPU's default precision on its matrix unit)
+_F32_DOT = jax.lax.Precision.HIGHEST
+
 
 @functools.partial(jax.jit, static_argnums=(2,))
 def _csc_predict(w: jax.Array, x: jax.Array, n_classes: int) -> jax.Array:
     xb = jnp.concatenate([x, jnp.ones((1,), x.dtype)])
-    return w @ xb  # (n_classes,) predicted costs
+    return jnp.dot(w, xb, precision=_F32_DOT)  # (n_classes,) predicted costs
 
 
 @jax.jit
@@ -77,7 +81,7 @@ def _csc_update(
 ):
     """One-against-all least-squares step on every class's regressor."""
     xb = jnp.concatenate([x, jnp.ones((1,), x.dtype)])
-    pred = w @ xb
+    pred = jnp.dot(w, xb, precision=_F32_DOT)
     err = pred - costs  # (n_classes,)
     grad = err[:, None] * xb[None, :]  # (n_classes, dim+1)
     g2 = g2 + jnp.square(grad)
@@ -91,7 +95,7 @@ def _csc_update(
 
 
 def _update_core(w, g2, xb, costs, lr):
-    pred = w @ xb
+    pred = jnp.dot(w, xb, precision=_F32_DOT)
     err = pred - costs
     grad = err[:, None] * xb[None, :]
     g2 = g2 + jnp.square(grad)
@@ -102,7 +106,8 @@ def _update_core(w, g2, xb, costs, lr):
 _batched_update = jax.jit(
     jax.vmap(_update_core, in_axes=(0, 0, 0, 0, None)), donate_argnums=(0, 1)
 )
-_batched_predict = jax.jit(jax.vmap(lambda w, xb: w @ xb, in_axes=(0, 0)))
+_batched_predict = jax.jit(jax.vmap(
+    lambda w, xb: jnp.dot(w, xb, precision=_F32_DOT), in_axes=(0, 0)))
 
 # largest vmapped batch ever dispatched: bigger batches are chunked to
 # this, so vmap_backend()'s calibration covers every shape that can run
@@ -242,7 +247,9 @@ def _update_exact(
     lr: np.float32,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Row-stacked NumPy mirror of ``_csc_update``; XLA contracts the
-    AdaGrad accumulation ``g2 + grad**2`` into an FMA, hence _fma32."""
+    AdaGrad accumulation ``g2 + grad**2`` into an FMA, hence _fma32 —
+    except the bias lane of a 3-wide row (dim 2), which XLA's CPU
+    codegen leaves as a rounded square plus an add."""
     pred = _matvec_exact(w, xb)
     pred -= costs
     err = pred  # in place: (rows,)
@@ -251,6 +258,8 @@ def _update_exact(
     else:
         grad = err[:, None] * xb
     g2n = _fma32(grad, grad, g2)
+    if w.shape[-1] == 3:
+        g2n[:, 2] = grad[:, 2] * grad[:, 2] + g2[:, 2]
     denom = np.sqrt(g2n)
     denom += F32(1e-6)
     step = lr * grad

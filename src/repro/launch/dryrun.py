@@ -1,11 +1,13 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) combo.
 
-The two lines above MUST run before any other import (jax locks the
-device count on first init); 512 placeholder host devices back both the
-single-pod (16,16) and multi-pod (2,16,16) production meshes.
+The lines above MUST run before any other import (jax locks the
+platform and device count on first init); 512 placeholder host devices
+back both the single-pod (16,16) and multi-pod (2,16,16) production
+meshes, on the CPU even where a chip is attached.
 
 For each combination this:
   1. builds the production mesh and the sharding spec trees,

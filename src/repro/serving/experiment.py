@@ -88,6 +88,36 @@ class ExperimentResult:
     chain_summary: Optional[Dict[str, float]] = None
 
 
+def build_simulator(
+    policy_name: str,
+    profiles,
+    pool,
+    slo_table,
+    *,
+    seed: int,
+    sim_cfg: Optional[SimConfig],
+    vcpu_confidence: Optional[int] = None,
+    mem_confidence: Optional[int] = None,
+) -> Simulator:
+    """Policy + cluster config -> the Simulator every entry point runs."""
+    policy = make_policy(policy_name, profiles, pool, slo_table, seed=seed)
+    if vcpu_confidence is not None and hasattr(policy, "allocator"):
+        policy.allocator.vcpu_confidence = vcpu_confidence
+    if mem_confidence is not None and hasattr(policy, "allocator"):
+        policy.allocator.mem_confidence = mem_confidence
+
+    # Baselines that keep OpenWhisk's memory-centric load accounting get a
+    # per-worker vCPU limit of +inf (vCPUs oversubscribe, §5 reason 3).
+    cfg = sim_cfg or SimConfig(seed=seed)
+    if not policy.uses_shabari_scheduler:
+        cfg = dataclasses.replace(cfg, vcpu_limit=10_000)
+
+    return Simulator(
+        policy=policy, profiles=profiles, input_pool=pool,
+        slo_table=slo_table, cfg=cfg,
+    )
+
+
 def _run_policy_on_trace(
     policy_name: str,
     trace,
@@ -104,21 +134,9 @@ def _run_policy_on_trace(
 ) -> ExperimentResult:
     """Shared tail of run_experiment/run_scenario: policy -> simulator
     -> summary."""
-    policy = make_policy(policy_name, profiles, pool, slo_table, seed=seed)
-    if vcpu_confidence is not None and hasattr(policy, "allocator"):
-        policy.allocator.vcpu_confidence = vcpu_confidence
-    if mem_confidence is not None and hasattr(policy, "allocator"):
-        policy.allocator.mem_confidence = mem_confidence
-
-    # Baselines that keep OpenWhisk's memory-centric load accounting get a
-    # per-worker vCPU limit of +inf (vCPUs oversubscribe, §5 reason 3).
-    cfg = sim_cfg or SimConfig(seed=seed)
-    if not policy.uses_shabari_scheduler:
-        cfg = dataclasses.replace(cfg, vcpu_limit=10_000)
-
-    sim = Simulator(
-        policy=policy, profiles=profiles, input_pool=pool,
-        slo_table=slo_table, cfg=cfg,
+    sim = build_simulator(
+        policy_name, profiles, pool, slo_table, seed=seed, sim_cfg=sim_cfg,
+        vcpu_confidence=vcpu_confidence, mem_confidence=mem_confidence,
     )
     results = sim.run(trace)
     summary = summarize(results)
@@ -129,6 +147,28 @@ def _run_policy_on_trace(
         container_sizes=sizes,
         chain_summary=sim.chain_summary(),
     )
+
+
+def experiment_inputs(
+    *,
+    rps: float = 4.0,
+    duration_s: float = 600.0,
+    seed: int = 0,
+    slo_multiplier: float = 1.4,
+):
+    """(profiles, input pool, SLO table, Azure-shaped trace) for one
+    run_experiment point."""
+    profiles = build_profiles()
+    pool = build_input_pool(seed=0)  # input pool fixed across policies
+    slo_table = B.build_slo_table(profiles, pool, multiplier=slo_multiplier)
+    trace = generate_trace(
+        rps=rps,
+        functions=sorted(profiles.keys()),
+        inputs_per_function={f: len(pool[f]) for f in profiles},
+        duration_s=duration_s,
+        seed=seed,
+    )
+    return profiles, pool, slo_table, trace
 
 
 def run_experiment(
@@ -143,16 +183,9 @@ def run_experiment(
     mem_confidence: Optional[int] = None,
     keep_results: bool = False,
 ) -> ExperimentResult:
-    profiles = build_profiles()
-    pool = build_input_pool(seed=0)  # input pool fixed across policies
-    slo_table = B.build_slo_table(profiles, pool, multiplier=slo_multiplier)
-    trace = generate_trace(
-        rps=rps,
-        functions=sorted(profiles.keys()),
-        inputs_per_function={f: len(pool[f]) for f in profiles},
-        duration_s=duration_s,
-        seed=seed,
-    )
+    profiles, pool, slo_table, trace = experiment_inputs(
+        rps=rps, duration_s=duration_s, seed=seed,
+        slo_multiplier=slo_multiplier)
     return _run_policy_on_trace(
         policy_name, trace, profiles, pool, slo_table,
         seed=seed, rps=rps, sim_cfg=sim_cfg,

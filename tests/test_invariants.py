@@ -2,21 +2,10 @@
 (scenario, routing, admission, fleet) cell, not just the golden-pinned
 ones, plus the full-registry determinism sweep.
 
-Invariants (checked after a full run, with the release/unreserve
-asserts inside repro.core.cluster guarding the during-run half):
-
-* accounting — every trace invocation terminates exactly once
-  (completed / shed / timed-out / OOM); chain runs additionally
-  account every SPAWNED stage invocation, with ids disjoint from the
-  trace block;
-* capacity — no worker ends over its vcpu/memory limits or below
-  zero, cluster aggregates equal the sum over their workers, and the
-  §5 active-demand aggregates drain back to zero;
-* reservations — every acquire-on-placement reservation is released
-  by completion, cancellation, or timeout: reserved vcpus/memory are
-  zero fleet-wide at the end;
-* image-cache refs — reaping every surviving container leaves no
-  in-use image and no layer with a nonzero refcount.
+The invariants themselves (accounting, capacity, reservations,
+image-cache refs) live in repro.serving.invariants and are checked
+after a full run, with the release/unreserve asserts inside
+repro.core.cluster guarding the during-run half.
 
 The determinism sweep runs every registered scenario twice per
 routing x admission cell assignment and requires byte-identical
@@ -54,6 +43,7 @@ from repro.core.router import ADMISSION_POLICIES, ROUTING_POLICIES
 from repro.serving import baselines as B
 from repro.serving.experiment import make_policy, run_scenario
 from repro.serving.golden import GOLDEN_POLICY, golden_sim_config
+from repro.serving.invariants import check_invariants
 from repro.serving.profiles import build_input_pool, build_profiles
 from repro.serving.simulator import Simulator
 from repro.serving.workload import (
@@ -102,62 +92,13 @@ def _run_cell(stack, seed, duration_s=40.0):
     return sim, trace, sim.run(trace)
 
 
-def _assert_invariants(sim, trace, results):
-    # ---- accounting: every invocation terminates exactly once
-    ids = [r.invocation_id for r in results]
-    assert len(ids) == len(set(ids)), "an invocation terminated twice"
-    got = set(ids)
-    trace_ids = {a.invocation_id for a in trace}
-    assert trace_ids <= got, (
-        f"trace invocations unaccounted: {sorted(trace_ids - got)[:5]}")
-    extra = got - trace_ids
-    if sim._chains is None:
-        assert not extra, f"phantom invocations: {sorted(extra)[:5]}"
-    else:
-        # chain stage spawns mint ids above the trace's 0..n-1 block,
-        # and every spawned stage must itself terminate exactly once
-        assert all(i >= len(trace) for i in extra)
-        assert len(extra) == sim._chains.stage_spawned
-    for r in results:
-        assert not (r.shed and r.timed_out), r
-        if r.shed or r.timed_out:
-            assert not r.oom_killed and r.exec_s == 0.0, r
-
-    # ---- capacity + reservations + §5 aggregates drain
-    for cl in sim.clusters:
-        for w in cl.workers:
-            assert 0 <= w.used_vcpus <= w.vcpu_limit, (w.wid, w.used_vcpus)
-            assert 0 <= w.used_mem_mb <= w.total_mem_mb
-            assert w.reserved_vcpus == 0 and w.reserved_mem_mb == 0, (
-                "reservation leaked on worker", w.wid)
-            assert w.active_demand_vcpus == pytest.approx(0.0, abs=1e-6)
-            assert w.active_net_gbps == pytest.approx(0.0, abs=1e-9)
-            for c in w.containers.values():
-                assert not c.busy, ("busy container at sim end", c.cid)
-        assert cl.reserved_vcpus == 0 and cl.reserved_mem_mb == 0
-        assert cl.used_vcpus == sum(w.used_vcpus for w in cl.workers)
-        assert cl.used_mem_mb == sum(w.used_mem_mb for w in cl.workers)
-
-    # ---- image-cache refs: reap everything -> no refs survive
-    for cl in sim.clusters:
-        for w in cl.workers:
-            for c in list(w.containers.values()):
-                cl.remove_container(c)
-            ic = w.image_cache
-            if ic is not None:
-                assert not ic._inuse_images, (
-                    "image refs leaked", dict(ic._inuse_images))
-                assert all(rec[2] == 0 for rec in ic._layers.values()), (
-                    "layer refcount leaked")
-
-
 @_prop("seed",
        dict(seed=st.integers(0, 10_000)) if hypothesis else None,
        [0, 1, 2, 3, 4, 5, 8, 12],
        max_examples=12)
 def test_invariants_hold_across_random_cells(stack, seed):
     sim, trace, results = _run_cell(stack, seed)
-    _assert_invariants(sim, trace, results)
+    check_invariants(sim, trace, results)
 
 
 def test_invariants_hold_on_chain_scenarios_explicitly(stack):
@@ -169,7 +110,7 @@ def test_invariants_hold_on_chain_scenarios_explicitly(stack):
         seed = names.index(scenario)  # lands _cell on this scenario
         sim, trace, results = _run_cell(stack, seed)
         assert sim._chains is not None and sim._chains.stage_spawned > 0
-        _assert_invariants(sim, trace, results)
+        check_invariants(sim, trace, results)
 
 
 # -------------------------------------------------- determinism sweep
