@@ -41,7 +41,6 @@ MODULES = [
     ("fig9", "benchmarks.fig9_timeline"),
     ("fig10", "benchmarks.fig10_cold_starts"),
     ("fig11_13", "benchmarks.fig11_13_sensitivity"),
-    ("fig14", "benchmarks.fig14_overheads"),
     ("table3", "benchmarks.table3_container_sizes"),
     ("scenario_matrix", "benchmarks.scenario_matrix"),
     ("sim_bench", "benchmarks.sim_bench"),

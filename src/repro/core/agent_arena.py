@@ -57,6 +57,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
+
 F32 = np.float32
 F64 = np.float64
 
@@ -69,10 +71,13 @@ F64 = np.float64
 _F32_DOT = jax.lax.Precision.HIGHEST
 
 
+# Each kernel's operations carry a stable name (``arena/<kernel>``) in
+# the device trace, whatever XLA calls the fusions.
 @functools.partial(jax.jit, static_argnums=(2,))
 def _csc_predict(w: jax.Array, x: jax.Array, n_classes: int) -> jax.Array:
-    xb = jnp.concatenate([x, jnp.ones((1,), x.dtype)])
-    return jnp.dot(w, xb, precision=_F32_DOT)  # (n_classes,) predicted costs
+    with jax.named_scope("arena/csc_predict"):
+        xb = jnp.concatenate([x, jnp.ones((1,), x.dtype)])
+        return jnp.dot(w, xb, precision=_F32_DOT)  # (n_classes,) costs
 
 
 @jax.jit
@@ -80,13 +85,14 @@ def _csc_update(
     w: jax.Array, g2: jax.Array, x: jax.Array, costs: jax.Array, lr: jax.Array
 ):
     """One-against-all least-squares step on every class's regressor."""
-    xb = jnp.concatenate([x, jnp.ones((1,), x.dtype)])
-    pred = jnp.dot(w, xb, precision=_F32_DOT)
-    err = pred - costs  # (n_classes,)
-    grad = err[:, None] * xb[None, :]  # (n_classes, dim+1)
-    g2 = g2 + jnp.square(grad)
-    step = lr * grad / (jnp.sqrt(g2) + 1e-6)
-    return w - step, g2
+    with jax.named_scope("arena/csc_update"):
+        xb = jnp.concatenate([x, jnp.ones((1,), x.dtype)])
+        pred = jnp.dot(w, xb, precision=_F32_DOT)
+        err = pred - costs  # (n_classes,)
+        grad = err[:, None] * xb[None, :]  # (n_classes, dim+1)
+        g2 = g2 + jnp.square(grad)
+        step = lr * grad / (jnp.sqrt(g2) + 1e-6)
+        return w - step, g2
 
 
 # Batched variants: vmap over stacked rows, xb precomputed by the caller.
@@ -103,11 +109,19 @@ def _update_core(w, g2, xb, costs, lr):
     return w - step, g2
 
 
-_batched_update = jax.jit(
-    jax.vmap(_update_core, in_axes=(0, 0, 0, 0, None)), donate_argnums=(0, 1)
-)
-_batched_predict = jax.jit(jax.vmap(
-    lambda w, xb: jnp.dot(w, xb, precision=_F32_DOT), in_axes=(0, 0)))
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _batched_update(w, g2, xb, costs, lr):
+    with jax.named_scope("arena/batched_update"):
+        return jax.vmap(_update_core, in_axes=(0, 0, 0, 0, None))(
+            w, g2, xb, costs, lr)
+
+
+@jax.jit
+def _batched_predict(w, xb):
+    with jax.named_scope("arena/batched_predict"):
+        return jax.vmap(lambda wr, xr: jnp.dot(wr, xr, precision=_F32_DOT))(
+            w, xb)
+
 
 # largest vmapped batch ever dispatched: bigger batches are chunked to
 # this, so vmap_backend()'s calibration covers every shape that can run
@@ -405,6 +419,12 @@ def _timed(fn) -> float:
     return time.perf_counter() - t0
 
 
+def _dispatched(kernel: str, dim: int) -> None:
+    """Count one device dispatch of ``kernel`` for feature dim ``dim``."""
+    if spans.on:
+        spans.count(f"arena.dispatch/{kernel}/{dim}")
+
+
 def calibrate(dims) -> None:
     """Force the one-time per-dim backend calibration + crossover
     benchmark now (results are process-cached). Benchmarks call this
@@ -555,36 +575,44 @@ class ArenaEngine:
         queued, which is what lets batches grow). ``updates()`` counts
         the queued feedback immediately, so confidence thresholds see
         it without a flush."""
-        dim = self._dim_of(function, x)
-        xb = np.concatenate([np.asarray(x, F32), np.ones(1, F32)])
-        self._pending.append(_PendingUpdate(function, xb, obs))
-        self._pending_fns.add(function)
-        c = self._counts.setdefault(function, [0, 0])
-        c[0] += 1
-        c[1] += 1
-        # make sure slots exist so growth happens off the predict path
-        self._arena(self.n_vcpu_classes, dim).slot(function)
-        self._arena(self.n_mem_classes, dim).slot(function)
+        with spans.span("arena.enqueue_update"):
+            dim = self._dim_of(function, x)
+            xb = np.concatenate([np.asarray(x, F32), np.ones(1, F32)])
+            self._pending.append(_PendingUpdate(function, xb, obs))
+            self._pending_fns.add(function)
+            c = self._counts.setdefault(function, [0, 0])
+            c[0] += 1
+            c[1] += 1
+            # make sure slots exist so growth happens off the predict path
+            self._arena(self.n_vcpu_classes, dim).slot(function)
+            self._arena(self.n_mem_classes, dim).slot(function)
 
     # ------------------------------------------------------------- flush
-    def flush(self) -> None:
+    def flush(self, cause: str = "call") -> None:
         """Apply every pending update. Passes preserve per-function
-        order; each pass touches each agent row at most once."""
-        pending = self._pending
-        self._pending = []
-        self._pending_fns.clear()
-        while pending:
-            seen = set()
-            batch: List[_PendingUpdate] = []
-            rest: List[_PendingUpdate] = []
-            for p in pending:
-                if p.function in seen:
-                    rest.append(p)
-                else:
-                    seen.add(p.function)
-                    batch.append(p)
-            self._flush_pass(batch)
-            pending = rest
+        order; each pass touches each agent row at most once. ``cause``
+        says why the flush runs: ``own`` (a predict of a function with
+        pending updates), ``cap`` (the 256-entry queue cap) or ``call``
+        (an explicit call)."""
+        with spans.span("arena.flush"):
+            spans.count("arena.flush_cause/" + cause)
+            pending = self._pending
+            self._pending = []
+            self._pending_fns.clear()
+            while pending:
+                seen = set()
+                batch: List[_PendingUpdate] = []
+                rest: List[_PendingUpdate] = []
+                for p in pending:
+                    if p.function in seen:
+                        rest.append(p)
+                    else:
+                        seen.add(p.function)
+                        batch.append(p)
+                spans.count("arena.flush_pass")
+                spans.count("arena.flush_rows", len(batch))
+                self._flush_pass(batch)
+                pending = rest
 
     def _cost_matrices(self, batch: Sequence[_PendingUpdate]):
         from repro.core.cost_functions import memory_costs
@@ -636,12 +664,16 @@ class ArenaEngine:
                     x = batch[i].xb[:-1]
                     for ar, sl, cs in ((va, vslots[j], vcosts[j]),
                                        (ma, mslots[j], mcosts[j])):
-                        w, g2 = _csc_update(
-                            jnp.asarray(ar.w[sl]), jnp.asarray(ar.g2[sl]),
-                            jnp.asarray(x), jnp.asarray(cs),
-                            jnp.asarray(self.lr))
-                        ar.w[sl] = np.asarray(w)
-                        ar.g2[sl] = np.asarray(g2)
+                        with spans.span("arena.h2d"):
+                            args = (jnp.asarray(ar.w[sl]),
+                                    jnp.asarray(ar.g2[sl]), jnp.asarray(x),
+                                    jnp.asarray(cs), jnp.asarray(self.lr))
+                        with spans.span("arena.launch"):
+                            w, g2 = _csc_update(*args)
+                        _dispatched("csc_update", dim)
+                        with spans.span("arena.d2h"):
+                            ar.w[sl] = np.asarray(w)
+                            ar.g2[sl] = np.asarray(g2)
 
     def _update_numpy(self, va, vslots, ma, mslots, xbs, vcosts, mcosts):
         """One row-stacked exact update covering both resources of the
@@ -698,11 +730,15 @@ class ArenaEngine:
             C[:kc] = costs[lo:lo + kc]
             # padding entries are exact no-ops: zero xb ⇒ zero grad ⇒
             # w/g2 unchanged; padded outputs are simply discarded below
-            nw, ng = _batched_update(jnp.asarray(W), jnp.asarray(G2),
-                                     jnp.asarray(XB), jnp.asarray(C),
-                                     jnp.asarray(self.lr))
-            ar.w[sl] = np.asarray(nw)[:kc]
-            ar.g2[sl] = np.asarray(ng)[:kc]
+            with spans.span("arena.h2d"):
+                args = (jnp.asarray(W), jnp.asarray(G2), jnp.asarray(XB),
+                        jnp.asarray(C), jnp.asarray(self.lr))
+            with spans.span("arena.launch"):
+                nw, ng = _batched_update(*args)
+            _dispatched("batched_update", ar.dim)
+            with spans.span("arena.d2h"):
+                ar.w[sl] = np.asarray(nw)[:kc]
+                ar.g2[sl] = np.asarray(ng)[:kc]
 
     def _predict_jax(self, ar: AgentArena, slots: List[int],
                      xbs: np.ndarray) -> np.ndarray:
@@ -719,8 +755,13 @@ class ArenaEngine:
             XB = np.zeros((kb, d1), F32)
             W[:kc] = ar.w[sl]
             XB[:kc] = xbs[lo:lo + kc]
-            costs = _batched_predict(jnp.asarray(W), jnp.asarray(XB))
-            out[lo:lo + kc] = np.asarray(costs)[:kc]
+            with spans.span("arena.h2d"):
+                args = (jnp.asarray(W), jnp.asarray(XB))
+            with spans.span("arena.launch"):
+                costs = _batched_predict(*args)
+            _dispatched("batched_predict", ar.dim)
+            with spans.span("arena.d2h"):
+                out[lo:lo + kc] = np.asarray(costs)[:kc]
         return out
 
     # ------------------------------------------------------------ predict
@@ -731,90 +772,98 @@ class ArenaEngine:
         want_vcpu, want_mem). Flushes pending updates first (the
         ordering rule), then runs all wanted predictions as one fused
         computation per backend group."""
-        out: List[Tuple[Optional[int], Optional[int]]] = [
-            (None, None) for _ in items
-        ]
-        by_dim: Dict[int, List[int]] = {}
-        for i, (fn, x, want_v, want_m) in enumerate(items):
-            if want_v or want_m:
-                by_dim.setdefault(self._dim_of(fn, x), []).append(i)
-        if not by_dim:
-            # nothing will read agent state, so nothing needs to flush;
-            # a cap keeps the queue bounded through long learning phases
-            if len(self._pending) >= 256:
-                self.flush()
-            return out
-        if self._pending_fns and any(
-                items[i][0] in self._pending_fns
-                for idxs in by_dim.values() for i in idxs):
-            self.flush()
-        elif len(self._pending) >= 256:
-            self.flush()
-        if len(by_dim) == 1 and len(items) == 1:
-            (dim, _), = by_dim.items()
-            fn, x, want_v, want_m = items[0]
-            if numpy_backend(dim):
-                out[0] = self._predict_one_numpy(fn, x, dim, want_v, want_m)
+        with spans.span("arena.predict_batch"):
+            out: List[Tuple[Optional[int], Optional[int]]] = [
+                (None, None) for _ in items
+            ]
+            by_dim: Dict[int, List[int]] = {}
+            for i, (fn, x, want_v, want_m) in enumerate(items):
+                if want_v or want_m:
+                    by_dim.setdefault(self._dim_of(fn, x), []).append(i)
+            if not by_dim:
+                # nothing will read agent state, so nothing needs to flush;
+                # a cap keeps the queue bounded through long learning phases
+                if len(self._pending) >= 256:
+                    self.flush("cap")
                 return out
-        for dim, idxs in by_dim.items():
-            va = self._arena(self.n_vcpu_classes, dim)
-            ma = self._arena(self.n_mem_classes, dim)
-            nv, nm = self.n_vcpu_classes, self.n_mem_classes
-            v_items = [i for i in idxs if items[i][2]]
-            m_items = [i for i in idxs if items[i][3]]
-            rows = len(v_items) * nv + len(m_items) * nm
-            if numpy_backend(dim) and rows <= numpy_crossover_rows(dim):
-                xb_of = {
-                    i: np.concatenate([np.asarray(items[i][1], F32),
-                                       np.ones(1, F32)])
-                    for i in idxs
-                }
-                w = np.concatenate(
-                    [va.w[va.slot(items[i][0])] for i in v_items]
-                    + [ma.w[ma.slot(items[i][0])] for i in m_items]
-                ) if rows else np.zeros((0, dim + 1), F32)
-                xb = np.concatenate(
-                    [np.repeat(xb_of[i][None, :], nv, axis=0) for i in v_items]
-                    + [np.repeat(xb_of[i][None, :], nm, axis=0) for i in m_items]
-                ) if rows else np.zeros((0, dim + 1), F32)
-                costs = _matvec_exact(w, xb)
-                off = 0
-                picks: Dict[int, List[Optional[int]]] = {
-                    i: [None, None] for i in idxs
-                }
-                for i in v_items:
-                    picks[i][0] = int(np.argmin(costs[off:off + nv]))
-                    off += nv
-                for i in m_items:
-                    picks[i][1] = int(np.argmin(costs[off:off + nm]))
-                    off += nm
-                for i in idxs:
-                    out[i] = (picks[i][0], picks[i][1])
-            else:
-                res: Dict[int, List[Optional[int]]] = {i: [None, None]
-                                                       for i in idxs}
-                for slot_items, ar, pos in ((v_items, va, 0), (m_items, ma, 1)):
-                    if len(slot_items) >= 2 and vmap_backend(dim):
-                        # one fused vmapped dispatch per agent group
-                        slots = [ar.slot(items[i][0]) for i in slot_items]
-                        xbs = np.zeros((len(slot_items), dim + 1), F32)
-                        for j, i in enumerate(slot_items):
-                            xbs[j, :dim] = items[i][1]
-                            xbs[j, dim] = 1.0
-                        costs = self._predict_jax(ar, slots, xbs)
-                        for j, i in enumerate(slot_items):
-                            res[i][pos] = int(np.argmin(costs[j]))
-                    else:
-                        for i in slot_items:
-                            fn, x = items[i][0], items[i][1]
-                            c = _csc_predict(
-                                jnp.asarray(ar.w[ar.slot(fn)]),
-                                jnp.asarray(x, dtype=jnp.float32),
-                                ar.n_classes)
-                            res[i][pos] = int(jnp.argmin(c))
-                for i in idxs:
-                    out[i] = (res[i][0], res[i][1])
-        return out
+            if self._pending_fns and any(
+                    items[i][0] in self._pending_fns
+                    for idxs in by_dim.values() for i in idxs):
+                self.flush("own")
+            elif len(self._pending) >= 256:
+                self.flush("cap")
+            if len(by_dim) == 1 and len(items) == 1:
+                (dim, _), = by_dim.items()
+                fn, x, want_v, want_m = items[0]
+                if numpy_backend(dim):
+                    out[0] = self._predict_one_numpy(fn, x, dim, want_v, want_m)
+                    return out
+            for dim, idxs in by_dim.items():
+                va = self._arena(self.n_vcpu_classes, dim)
+                ma = self._arena(self.n_mem_classes, dim)
+                nv, nm = self.n_vcpu_classes, self.n_mem_classes
+                v_items = [i for i in idxs if items[i][2]]
+                m_items = [i for i in idxs if items[i][3]]
+                rows = len(v_items) * nv + len(m_items) * nm
+                if numpy_backend(dim) and rows <= numpy_crossover_rows(dim):
+                    xb_of = {
+                        i: np.concatenate([np.asarray(items[i][1], F32),
+                                           np.ones(1, F32)])
+                        for i in idxs
+                    }
+                    w = np.concatenate(
+                        [va.w[va.slot(items[i][0])] for i in v_items]
+                        + [ma.w[ma.slot(items[i][0])] for i in m_items]
+                    ) if rows else np.zeros((0, dim + 1), F32)
+                    xb = np.concatenate(
+                        [np.repeat(xb_of[i][None, :], nv, axis=0) for i in v_items]
+                        + [np.repeat(xb_of[i][None, :], nm, axis=0) for i in m_items]
+                    ) if rows else np.zeros((0, dim + 1), F32)
+                    costs = _matvec_exact(w, xb)
+                    off = 0
+                    picks: Dict[int, List[Optional[int]]] = {
+                        i: [None, None] for i in idxs
+                    }
+                    for i in v_items:
+                        picks[i][0] = int(np.argmin(costs[off:off + nv]))
+                        off += nv
+                    for i in m_items:
+                        picks[i][1] = int(np.argmin(costs[off:off + nm]))
+                        off += nm
+                    for i in idxs:
+                        out[i] = (picks[i][0], picks[i][1])
+                else:
+                    res: Dict[int, List[Optional[int]]] = {i: [None, None]
+                                                           for i in idxs}
+                    for slot_items, ar, pos in ((v_items, va, 0), (m_items, ma, 1)):
+                        if len(slot_items) >= 2 and vmap_backend(dim):
+                            # one fused vmapped dispatch per agent group
+                            slots = [ar.slot(items[i][0]) for i in slot_items]
+                            xbs = np.zeros((len(slot_items), dim + 1), F32)
+                            for j, i in enumerate(slot_items):
+                                xbs[j, :dim] = items[i][1]
+                                xbs[j, dim] = 1.0
+                            costs = self._predict_jax(ar, slots, xbs)
+                            for j, i in enumerate(slot_items):
+                                res[i][pos] = int(np.argmin(costs[j]))
+                        else:
+                            for i in slot_items:
+                                fn, x = items[i][0], items[i][1]
+                                row = ar.w[ar.slot(fn)]
+                                with spans.span("arena.h2d"):
+                                    args = (jnp.asarray(row),
+                                            jnp.asarray(x, dtype=jnp.float32))
+                                with spans.span("arena.launch"):
+                                    c = _csc_predict(*args, ar.n_classes)
+                                _dispatched("csc_predict", dim)
+                                with spans.span("arena.launch"):
+                                    m = jnp.argmin(c)
+                                _dispatched("argmin", dim)
+                                with spans.span("arena.d2h"):
+                                    res[i][pos] = int(m)
+                    for i in idxs:
+                        out[i] = (res[i][0], res[i][1])
+            return out
 
     def _predict_one_numpy(self, fn: str, x: np.ndarray, dim: int,
                            want_v: bool, want_m: bool):
@@ -858,17 +907,20 @@ class ArenaEngine:
         ``function`` are applied first (see :meth:`enqueue_update`);
         pending updates for OTHER functions are left queued unless the
         256-entry cap forces a drain."""
-        if not (want_vcpu or want_mem):
-            if len(self._pending) >= 256:
-                self.flush()
-            return (None, None)
-        dim = self._dim_of(function, x)
-        if numpy_backend(dim):
-            if function in self._pending_fns or len(self._pending) >= 256:
-                self.flush()
-            return self._predict_one_numpy(function, x, dim,
-                                           want_vcpu, want_mem)
-        return self.predict_batch([(function, x, want_vcpu, want_mem)])[0]
+        with spans.span("arena.predict"):
+            if not (want_vcpu or want_mem):
+                if len(self._pending) >= 256:
+                    self.flush("cap")
+                return (None, None)
+            dim = self._dim_of(function, x)
+            if numpy_backend(dim):
+                if function in self._pending_fns:
+                    self.flush("own")
+                elif len(self._pending) >= 256:
+                    self.flush("cap")
+                return self._predict_one_numpy(function, x, dim,
+                                               want_vcpu, want_mem)
+            return self.predict_batch([(function, x, want_vcpu, want_mem)])[0]
 
     def predicted_costs(self, function: str, x: np.ndarray):
         """Full cost vectors (vcpu, mem) — diagnostics path."""
@@ -882,14 +934,17 @@ class ArenaEngine:
                 _matvec_exact(va.w[va.slot(function)], xb),
                 _matvec_exact(ma.w[ma.slot(function)], xb),
             )
-        return (
-            np.asarray(_csc_predict(jnp.asarray(va.w[va.slot(function)]),
-                                    jnp.asarray(x, jnp.float32),
-                                    va.n_classes)),
-            np.asarray(_csc_predict(jnp.asarray(ma.w[ma.slot(function)]),
-                                    jnp.asarray(x, jnp.float32),
-                                    ma.n_classes)),
-        )
+        out = []
+        for ar in (va, ma):
+            row = ar.w[ar.slot(function)]
+            with spans.span("arena.h2d"):
+                args = (jnp.asarray(row), jnp.asarray(x, jnp.float32))
+            with spans.span("arena.launch"):
+                c = _csc_predict(*args, ar.n_classes)
+            _dispatched("csc_predict", dim)
+            with spans.span("arena.d2h"):
+                out.append(np.asarray(c))
+        return tuple(out)
 
     # ------------------------------------------------------------- debug
     def weights(self, function: str):

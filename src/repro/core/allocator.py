@@ -24,8 +24,7 @@ bit-identical, asserted by the golden harness and the sim_bench A/B):
   (:class:`repro.core.agent_arena.ArenaEngine`): feedbacks are deferred
   into microbatches flushed before the next prediction, and small
   batches run on a calibrated dispatch-free NumPy backend. Fig. 14
-  overheads on the dev container (benchmarks/fig14_overheads.py):
-  predict ~180 µs → ~105 µs (both agents, argmin included), update
+  overheads on the dev container: predict ~180 µs → ~105 µs (both agents, argmin included), update
   ~230 µs eager jit → ~3 µs enqueue + ~60 µs amortized batched flush
   per completion; end to end the engine A/B is worth ~3.8x events/sec
   on a Shabari heavy-tail simulation (sim_bench). The paper's

@@ -32,6 +32,7 @@ import dataclasses
 import hashlib
 from typing import List, Optional, Tuple
 
+from repro import spans
 from repro.core.allocator import Allocation
 from repro.core.cluster import Cluster, Container, Worker
 
@@ -257,32 +258,33 @@ class ShabariScheduler:
     def schedule(self, function: str, alloc: Allocation, now: float) -> Decision:
         """Place one invocation. Does not mutate load — the runtime calls
         ``start``/``finish`` as the invocation actually runs."""
-        vcpus, mem = alloc.vcpus, alloc.mem_mb
+        with spans.span("router.schedule"):
+            vcpus, mem = alloc.vcpus, alloc.mem_mb
 
-        # (1)/(2) warm routing: exact-size container, else smallest
-        # strictly-larger (selection shared with the router's estimate
-        # scoring via warm_candidate)
-        chosen = self.warm_candidate(function, vcpus, mem, now)
-        if chosen is not None:
-            if chosen.vcpus == vcpus and chosen.mem_mb == mem:
-                return Decision(chosen, cold_start=False,
-                                background_launch=None)
-            # case 2: proactively launch the exact size in the background
-            bg = None
-            if self.background_launch:
-                w = self._pick_cold_worker(function, vcpus, mem)
-                if w is not None:
-                    # idle containers carry no load; free to launch now
-                    bg = (w, vcpus, mem)
-            return Decision(chosen, cold_start=False, background_launch=bg)
+            # (1)/(2) warm routing: exact-size container, else smallest
+            # strictly-larger (selection shared with the router's estimate
+            # scoring via warm_candidate)
+            chosen = self.warm_candidate(function, vcpus, mem, now)
+            if chosen is not None:
+                if chosen.vcpus == vcpus and chosen.mem_mb == mem:
+                    return Decision(chosen, cold_start=False,
+                                    background_launch=None)
+                # case 2: proactively launch the exact size in the background
+                bg = None
+                if self.background_launch:
+                    w = self._pick_cold_worker(function, vcpus, mem)
+                    if w is not None:
+                        # idle containers carry no load; free to launch now
+                        bg = (w, vcpus, mem)
+                return Decision(chosen, cold_start=False, background_launch=bg)
 
-        # (3) cold start at the exact size; _pick_cold_worker scanned
-        # every worker, so None means no capacity anywhere — queue
-        w = self._pick_cold_worker(function, vcpus, mem)
-        if w is None:
-            return Decision(None, cold_start=True, background_launch=None,
-                            queued=True)
-        return Decision(None, cold_start=True, background_launch=(w, vcpus, mem))
+            # (3) cold start at the exact size; _pick_cold_worker scanned
+            # every worker, so None means no capacity anywhere — queue
+            w = self._pick_cold_worker(function, vcpus, mem)
+            if w is None:
+                return Decision(None, cold_start=True, background_launch=None,
+                                queued=True)
+            return Decision(None, cold_start=True, background_launch=(w, vcpus, mem))
 
     # ----------------------------------------------------- lifecycle
     def reap_idle(self, now: float) -> int:

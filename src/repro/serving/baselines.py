@@ -22,6 +22,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core.allocator import Allocation, ResourceAllocator
 from repro.core.cost_functions import Observation
 from repro.core.featurizer import Featurizer
@@ -240,24 +241,26 @@ class ShabariPolicy(Policy):
         self._prealloc: Dict[int, Tuple[Allocation, tuple]] = {}
 
     def _featurize(self, arrival, meta, sim):
-        fn = arrival.function
-        x = self.featurizer.extract(fn, sim.profiles[fn].input_type, meta)
-        return x, input_size_mb(fn, meta)
+        with spans.span("policy.featurize", arrival.invocation_id):
+            fn = arrival.function
+            x = self.featurizer.extract(fn, sim.profiles[fn].input_type, meta)
+            return x, input_size_mb(fn, meta)
 
     def allocate_with_aux(self, arrival, meta, sim, aux=None):
-        pre = self._prealloc.pop(arrival.invocation_id, None)
-        if pre is not None:
-            alloc, aux = pre
-            self._features[arrival.invocation_id] = aux[0]
-            return alloc, aux
-        if aux is None:
-            # first sight of this invocation: featurize once; the tuple
-            # rides the retry payload so re-allocations (the legacy
-            # per-retry path) never re-run Featurizer / input_size_mb
-            aux = self._featurize(arrival, meta, sim)
-        x, size = aux
-        self._features[arrival.invocation_id] = x
-        return self.allocator.allocate(arrival.function, x, size), aux
+        with spans.span("policy.allocate", arrival.invocation_id):
+            pre = self._prealloc.pop(arrival.invocation_id, None)
+            if pre is not None:
+                alloc, aux = pre
+                self._features[arrival.invocation_id] = aux[0]
+                return alloc, aux
+            if aux is None:
+                # first sight of this invocation: featurize once; the tuple
+                # rides the retry payload so re-allocations (the legacy
+                # per-retry path) never re-run Featurizer / input_size_mb
+                aux = self._featurize(arrival, meta, sim)
+            x, size = aux
+            self._features[arrival.invocation_id] = x
+            return self.allocator.allocate(arrival.function, x, size), aux
 
     def allocate(self, arrival, meta, sim):
         return self.allocate_with_aux(arrival, meta, sim)[0]
@@ -266,35 +269,38 @@ class ShabariPolicy(Policy):
         """Featurize in event order (the Featurizer's running stats are
         order-sensitive), then serve every first allocation of this
         timestamp with one fused arena predict."""
-        batch = []
-        for arrival, meta in items:
-            aux = self._featurize(arrival, meta, sim)
-            batch.append((arrival.invocation_id, arrival.function, aux))
-        allocs = self.allocator.allocate_batch(
-            [(fn, aux[0], aux[1]) for _, fn, aux in batch]
-        )
-        for (iid, fn, aux), alloc in zip(batch, allocs):
-            self._prealloc[iid] = (alloc, aux)
+        rid = tuple(a.invocation_id for a, _ in items) if spans.on else None
+        with spans.span("policy.begin_batch", rid):
+            batch = []
+            for arrival, meta in items:
+                aux = self._featurize(arrival, meta, sim)
+                batch.append((arrival.invocation_id, arrival.function, aux))
+            allocs = self.allocator.allocate_batch(
+                [(fn, aux[0], aux[1]) for _, fn, aux in batch]
+            )
+            for (iid, fn, aux), alloc in zip(batch, allocs):
+                self._prealloc[iid] = (alloc, aux)
 
     def forget(self, arrival):
         self._features.pop(arrival.invocation_id, None)
         self._prealloc.pop(arrival.invocation_id, None)
 
     def feedback(self, arrival, meta, result, sim):
-        x = self._features.pop(arrival.invocation_id, None)
-        if x is None:
-            return
-        obs = Observation(
-            exec_time_s=result.finish_t - result.arrival_t,
-            slo_s=result.slo_s,
-            alloc_vcpus=result.alloc_vcpus,
-            max_vcpus_used=result.used_vcpus,
-            alloc_mem_mb=result.alloc_mem_mb,
-            max_mem_used_mb=result.used_mem_mb,
-            cold_start=result.cold_start,
-            oom_killed=result.oom_killed,
-        )
-        self.allocator.feedback(arrival.function, x, obs)
+        with spans.span("policy.feedback", arrival.invocation_id):
+            x = self._features.pop(arrival.invocation_id, None)
+            if x is None:
+                return
+            obs = Observation(
+                exec_time_s=result.finish_t - result.arrival_t,
+                slo_s=result.slo_s,
+                alloc_vcpus=result.alloc_vcpus,
+                max_vcpus_used=result.used_vcpus,
+                alloc_mem_mb=result.alloc_mem_mb,
+                max_mem_used_mb=result.used_mem_mb,
+                cold_start=result.cold_start,
+                oom_killed=result.oom_killed,
+            )
+            self.allocator.feedback(arrival.function, x, obs)
 
 
 class FormulationPolicy(ShabariPolicy):

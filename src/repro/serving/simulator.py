@@ -59,6 +59,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core.cluster import Cluster, Container, Worker
 from repro.core.cost_functions import Observation
 from repro.core.daemon import (SAMPLE_INTERVAL_S, UtilizationTrace,
@@ -641,6 +642,7 @@ class Simulator:
             # hottest line of a saturated large-fleet simulation)
             ev = (now + cfg.retry_interval_s, next(self._seq), "arrival",
                   (arrival, first_seen, alloc, aux))
+            spans.count("loop.retries")
             if self._queue is not None:
                 self._retry_q.append(ev)  # FIFO retry lane (see _push)
             else:
@@ -667,9 +669,10 @@ class Simulator:
             if stage is not None:
                 eff_slo, budget_s = stage
             self._chain_alloc[arrival.function] = (alloc.vcpus, alloc.mem_mb)
-        route = self.router.route(arrival.function, alloc, now,
-                                  features=feats, input_mb=in_mb,
-                                  slo_s=eff_slo, budget_s=budget_s)
+        with spans.span("router.route", arrival.invocation_id):
+            route = self.router.route(arrival.function, alloc, now,
+                                      features=feats, input_mb=in_mb,
+                                      slo_s=eff_slo, budget_s=budget_s)
         decision = route.decision
         if route.shed:
             # admission control dropped it at the front door: no retry
@@ -679,6 +682,7 @@ class Simulator:
             # carry the allocation AND the featurization cache: retries
             # must not re-run the policy or the Featurizer (front-door
             # admission queueing lands here too)
+            spans.count("loop.retries")
             self._push(now + self.cfg.retry_interval_s, "arrival",
                        (arrival, first_seen, alloc, aux))
             return
@@ -1018,9 +1022,11 @@ class Simulator:
             # spawned stage invocations get ids above the trace's
             # 0..n-1 block — unique, deterministic, loop-independent
             self._chain_iid = itertools.count(len(arrivals))
-        if self.cfg.legacy_event_loop:
-            return self._run_legacy(arrivals)
-        return self._run_fast(arrivals)
+        # inside a profiler trace the program's spans go into it too
+        with spans.profiled():
+            if self.cfg.legacy_event_loop:
+                return self._run_legacy(arrivals)
+            return self._run_fast(arrivals)
 
     def chain_summary(self) -> Optional[Dict[str, float]]:
         """End-to-end chain metrics, None when ``cfg.chains`` is off."""
