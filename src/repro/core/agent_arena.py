@@ -1,28 +1,40 @@
 """Batched CSOAA agent arena: all functions' regressors in stacked tensors.
 
-``repro.core.allocator`` historically kept one tiny ``OnlineCSC`` object
-per (function, resource) pair, paying one jit'd JAX dispatch per agent
-per event — ~107 µs per predict (+argmin+sync) and ~130 µs per update on
-the bench machine, the dominant cost of learning-policy simulations
-(the very overhead wall the paper measures in Fig. 14). The arena fuses
-them:
+``repro.core.allocator``'s legacy engine keeps one ``OnlineCSC`` per
+(function, resource) pair and pays a jit'd dispatch per agent per event
+(~107 µs a predict, ~130 µs an update on the bench machine), the
+overhead wall the paper measures in Fig. 14. The arena fuses them:
 
 * **Stacked state** — every agent with the same ``(n_classes, dim)``
-  shape lives as one row of a ``(capacity, n_classes, dim+1)`` weight /
-  AdaGrad tensor pair (:class:`AgentArena`). Capacity grows by doubling;
-  a function-name→row map assigns slots, and released slots are zeroed
-  and reused.
+  shape lives as one row of a weight / AdaGrad tensor pair
+  (:class:`AgentArena`); a function-name→row map assigns slots, and
+  released slots are zeroed and reused. Where the NumPy backend holds
+  (below) the pair is a host ``(capacity, n_classes, dim+1)`` array
+  that grows by doubling. Elsewhere (every dimension on a TPU, and the
+  one-hot formulation's uncalibrated features anywhere) the state is
+  *resident on the device* in blocks of ``_MAX_BUCKET`` rows: slot ``s``
+  is row ``s % 16`` of block ``s // 16``, growth appends a zeroed block,
+  and the learning rate is a device scalar made once.
 * **Deferred microbatched updates** — completed-invocation feedbacks are
   queued (:class:`ArenaEngine`) and flushed lazily. The ordering rule —
   *pending updates for function F flush before any predict for F* —
   makes served allocations bit-identical to the sequential path: updates
   touching distinct rows commute exactly (disjoint state), and same-row
   updates are applied in arrival order via conflict-free passes.
-* **One fused dispatch per flush** — each pass runs as a single
-  ``jax.vmap``-over-rows jit'd kernel (:data:`_batched_update` /
-  :data:`_batched_predict`) with ``donate_argnums`` buffer reuse, padded
-  to power-of-two batch sizes with exact no-op entries so steady state
-  compiles a handful of programs and allocates nothing new per call.
+* **Masked block dispatches** — on resident state a flush pass copies
+  in, per touched block, a ``(16, dim+1)`` input and a ``(16,
+  n_classes)`` cost matrix with the pass's rows at their slot offsets
+  and zeros elsewhere, launches :data:`_batched_update` (buffers
+  donated) on the whole block and reads nothing back: a zero input row
+  is an exact no-op (zero gradient, so ``w`` and ``g2`` keep their
+  bits). A predict launches :data:`_batched_predict` once per wanted
+  arena block, reads all its cost blocks in one transfer and takes the
+  arg-min on the host. The kernels are looked up at call time and never
+  wrapped in a further ``jit`` (a fused gather/update/scatter program
+  is not bit-identical to the reference on the CPU). The engine does
+  not consult :func:`vmap_backend`: where the batched kernel differs
+  from the per-row one in its last bits (dims 2, 5 and 6 on a v5e) it
+  runs all the same, held to the chip benchmark's float64 reference.
 * **Calibrated NumPy backend** — for the small batches that dominate a
   discrete-event loop (most events carry one predict or one update), a
   dispatch-free NumPy path beats the JAX call by a wide margin. XLA's
@@ -32,10 +44,9 @@ them:
   via double-precision emulation with a double-rounding hazard check
   (rare hazards fall back to ``libm.fmaf``). The backend is enabled per
   feature dimension only after :func:`numpy_backend` proves it
-  bit-identical to the jitted reference on random samples; uncalibrated
-  shapes (e.g. the one-hot formulation's concatenated features) always
-  take the JAX kernel. :func:`numpy_crossover_rows` benchmarks both
-  backends once per shape so the per-call choice follows measured cost.
+  bit-identical to the jitted reference on random samples; such
+  dimensions keep host state, and :func:`numpy_crossover_rows`
+  benchmarks both backends once per shape to size a flush's chunks.
 
 Bit-identity with the legacy per-object path is the load-bearing
 guarantee — the golden-metrics harness and the ``sim_bench`` engine A/B
@@ -46,10 +57,12 @@ both assert it — which is why the reference kernels (``_csc_predict`` /
 
 from __future__ import annotations
 
+import collections
 import ctypes
 import ctypes.util
 import dataclasses
 import functools
+import itertools
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -123,8 +136,7 @@ def _batched_predict(w, xb):
             w, xb)
 
 
-# largest vmapped batch ever dispatched: bigger batches are chunked to
-# this, so vmap_backend()'s calibration covers every shape that can run
+# rows of a resident block: every device dispatch has this many rows
 _MAX_BUCKET = 16
 
 
@@ -327,36 +339,31 @@ def numpy_backend(dim: int) -> bool:
 @functools.lru_cache(maxsize=None)
 def vmap_backend(dim: int) -> bool:
     """True iff the vmapped batched kernels match per-row reference
-    calls bitwise (they do on CPU XLA for every shape we've met, but the
-    arena refuses to assume it)."""
+    calls bitwise at the resident block's shape (they do on CPU XLA; a
+    diagnostic the benchmark prints, not a switch)."""
     rng = np.random.default_rng(0xBA7C)
     lr = F32(0.5)
-    # covers every power-of-two bucket the padded batch paths can emit
-    # (dispatches are chunked at _MAX_BUCKET, so nothing larger exists)
-    for k in (1, 2, 3, 4, 8, _MAX_BUCKET):
-        for n in (32, 40):
-            stack = [_reference_pair(rng, n, dim) for _ in range(k)]
-            W = np.stack([s[0] for s in stack])
-            G2 = np.stack([s[1] for s in stack])
-            X = np.stack([s[2] for s in stack])
-            C = np.stack([s[3] for s in stack])
-            XB = np.concatenate([X, np.ones((k, 1), F32)], axis=1)
-            # copies: _batched_update donates its first two buffers
-            bw, bg = _batched_update(
-                jnp.asarray(W), jnp.asarray(G2), jnp.asarray(XB),
-                jnp.asarray(C), jnp.asarray(lr),
+    k = _MAX_BUCKET
+    for n in (32, 40):
+        W, G2, X, C = (np.stack(a) for a in zip(
+            *[_reference_pair(rng, n, dim) for _ in range(k)]))
+        XB = np.concatenate([X, np.ones((k, 1), F32)], axis=1)
+        # copies: _batched_update donates its first two buffers
+        bw, bg = _batched_update(
+            jnp.asarray(W), jnp.asarray(G2), jnp.asarray(XB),
+            jnp.asarray(C), jnp.asarray(lr),
+        )
+        bc = _batched_predict(jnp.asarray(W), jnp.asarray(XB))
+        for i in range(k):
+            rw, rg = _csc_update(
+                jnp.asarray(W[i]), jnp.asarray(G2[i]), jnp.asarray(X[i]),
+                jnp.asarray(C[i]), jnp.asarray(lr),
             )
-            bc = _batched_predict(jnp.asarray(W), jnp.asarray(XB))
-            for i in range(k):
-                rw, rg = _csc_update(
-                    jnp.asarray(W[i]), jnp.asarray(G2[i]), jnp.asarray(X[i]),
-                    jnp.asarray(C[i]), jnp.asarray(lr),
-                )
-                rc = _csc_predict(jnp.asarray(W[i]), jnp.asarray(X[i]), n)
-                if not (np.array_equal(np.asarray(bw[i]), np.asarray(rw))
-                        and np.array_equal(np.asarray(bg[i]), np.asarray(rg))
-                        and np.array_equal(np.asarray(bc[i]), np.asarray(rc))):
-                    return False
+            rc = _csc_predict(jnp.asarray(W[i]), jnp.asarray(X[i]), n)
+            if not (np.array_equal(np.asarray(bw[i]), np.asarray(rw))
+                    and np.array_equal(np.asarray(bg[i]), np.asarray(rg))
+                    and np.array_equal(np.asarray(bc[i]), np.asarray(rc))):
+                return False
     return True
 
 
@@ -369,11 +376,11 @@ _NUMPY_MIN_ROWS = 512
 @functools.lru_cache(maxsize=None)
 def numpy_crossover_rows(dim: int, n_classes: int = 32) -> int:
     """Benchmark the NumPy path against one batched JAX dispatch and
-    return the stacked-row count above which JAX wins (the per-call
-    backend pick). On CPU the dispatch overhead (~60-130 µs) dwarfs the
-    NumPy arithmetic until the stack is thousands of rows tall; timing
-    is min-of-reps so a noisy sample can't misroute the steady-state
-    singleton batches."""
+    return the stacked-row count above which JAX wins (a flush's chunk
+    size on the NumPy backend). On CPU the dispatch overhead (~60-130
+    µs) dwarfs the NumPy arithmetic until the stack is thousands of rows
+    tall; timing is min-of-reps so a noisy sample can't misroute the
+    steady-state singleton batches."""
     if not numpy_backend(dim):
         return 0
     rng = np.random.default_rng(3)
@@ -419,10 +426,12 @@ def _timed(fn) -> float:
     return time.perf_counter() - t0
 
 
-def _dispatched(kernel: str, dim: int) -> None:
-    """Count one device dispatch of ``kernel`` for feature dim ``dim``."""
+def _dispatched(kernel: str, dim: int, rows: int) -> None:
+    """Count one device dispatch of ``kernel`` for feature dim ``dim``
+    carrying ``rows`` real agent rows."""
     if spans.on:
         spans.count(f"arena.dispatch/{kernel}/{dim}")
+        spans.count("arena.dispatch_rows", rows)
 
 
 def calibrate(dims) -> None:
@@ -442,20 +451,29 @@ def calibrate(dims) -> None:
 
 class AgentArena:
     """Stacked homogeneous agents: one ``(n_classes, dim+1)`` row pair
-    per agent in doubling-growth weight/AdaGrad tensors."""
+    per agent, in doubling-growth host tensors where ``numpy_backend``
+    holds for ``dim`` and in device-resident 16-row blocks elsewhere."""
 
     def __init__(self, n_classes: int, dim: int, lr: float = 0.5,
                  capacity: int = 4):
         self.n_classes = n_classes
         self.dim = dim
         self.lr = F32(lr)
-        self.w = np.zeros((capacity, n_classes, dim + 1), F32)
-        self.g2 = np.zeros((capacity, n_classes, dim + 1), F32)
+        self.resident = not numpy_backend(dim)
+        if self.resident:
+            # (w, g2) per block of _MAX_BUCKET slots, on the device
+            self.blocks: List[Tuple[jax.Array, jax.Array]] = []
+            self.lr_dev = jax.device_put(self.lr)
+        else:
+            self.w = np.zeros((capacity, n_classes, dim + 1), F32)
+            self.g2 = np.zeros((capacity, n_classes, dim + 1), F32)
         self._slots: Dict[str, int] = {}
         self._free: List[int] = []
 
     @property
     def capacity(self) -> int:
+        if self.resident:
+            return len(self.blocks) * _MAX_BUCKET
         return self.w.shape[0]
 
     def slot(self, name: str) -> int:
@@ -467,7 +485,11 @@ class AgentArena:
             s = self._free.pop()
         else:
             s = len(self._slots)
-            if s >= self.capacity:  # grow by doubling
+            if s >= self.capacity and self.resident:  # a zeroed block
+                shape = (_MAX_BUCKET, self.n_classes, self.dim + 1)
+                self.blocks.append((jnp.zeros(shape, F32),
+                                    jnp.zeros(shape, F32)))
+            elif s >= self.capacity:  # grow by doubling
                 pad = np.zeros_like(self.w)
                 self.w = np.concatenate([self.w, pad])
                 self.g2 = np.concatenate([self.g2, np.zeros_like(pad)])
@@ -477,14 +499,94 @@ class AgentArena:
     def has(self, name: str) -> bool:
         return name in self._slots
 
+    def row(self, name: str) -> Tuple[np.ndarray, np.ndarray]:
+        """Host copies of ``name``'s (w, g2) rows."""
+        s = self.slot(name)
+        if not self.resident:
+            return self.w[s].copy(), self.g2[s].copy()
+        b, r = divmod(s, _MAX_BUCKET)
+        w, g2 = jax.device_get(self.blocks[b])
+        return w[r].copy(), g2[r].copy()
+
     def release(self, name: str) -> None:
         """Free ``name``'s row for reuse; the row is zeroed so a future
         tenant starts as a fresh agent (per-function isolation)."""
         s = self._slots.pop(name, None)
-        if s is not None:
+        if s is None:
+            return
+        if self.resident:
+            b, r = divmod(s, _MAX_BUCKET)
+            self.blocks[b] = tuple(a.at[r].set(0.0) for a in self.blocks[b])
+        else:
             self.w[s] = 0.0
             self.g2[s] = 0.0
-            self._free.append(s)
+        self._free.append(s)
+
+
+def _stage(groups):
+    """Copy in the inputs of resident dispatches, all in one
+    ``device_put``. Per ``(arena, functions, xbs, costs or None)`` group
+    (functions distinct) and touched block: ``XB (16, dim+1)`` and ``C
+    (16, n_classes)`` with the group's rows at their slot offsets, zeros
+    elsewhere; equal ``XB`` are copied once. Returns ``(group, arena,
+    block, js, rs, XB, C)`` per block: ``js`` index the group's rows,
+    ``rs`` their offsets in the block."""
+    host: List[np.ndarray] = []
+    seen: Dict[bytes, int] = {}
+    todo = []
+    for g, (ar, fns, xbs, costs) in enumerate(groups):
+        by_block: Dict[int, Tuple[List[int], List[int]]] = {}
+        for j, fn in enumerate(fns):
+            b, r = divmod(ar.slot(fn), _MAX_BUCKET)
+            js, rs = by_block.setdefault(b, ([], []))
+            js.append(j)
+            rs.append(r)
+        for b, (js, rs) in by_block.items():
+            XB = np.zeros((_MAX_BUCKET, ar.dim + 1), F32)
+            XB[rs] = xbs[js]
+            kx = seen.setdefault(XB.tobytes(), len(host))
+            if kx == len(host):
+                host.append(XB)
+            kc = None
+            if costs is not None:
+                C = np.zeros((_MAX_BUCKET, ar.n_classes), F32)
+                C[rs] = costs[js]
+                kc = len(host)
+                host.append(C)
+            todo.append((g, ar, b, js, rs, kx, kc))
+    with spans.span("arena.h2d"):
+        dev = jax.device_put(host)
+    return [(g, ar, b, js, rs, dev[kx], None if kc is None else dev[kc])
+            for g, ar, b, js, rs, kx, kc in todo]
+
+
+def _update_resident(groups) -> None:
+    """Apply each ``(arena, functions, xbs, costs)`` group: one masked
+    ``_batched_update`` per touched block, state rebound to its donated
+    outputs, nothing read back."""
+    for _, ar, b, js, _, XB, C in _stage(groups):
+        with spans.span("arena.launch"):
+            ar.blocks[b] = _batched_update(*ar.blocks[b], XB, C, ar.lr_dev)
+        _dispatched("batched_update", ar.dim, len(js))
+
+
+def _predict_resident(groups) -> List[np.ndarray]:
+    """``(len(functions), n_classes)`` cost rows of each ``(arena,
+    functions, xbs, None)`` group: one masked ``_batched_predict`` per
+    touched block, then one read of every block's costs."""
+    staged = _stage(groups)
+    costs = []
+    for _, ar, b, js, _, XB, _ in staged:
+        with spans.span("arena.launch"):
+            costs.append(_batched_predict(ar.blocks[b][0], XB))
+        _dispatched("batched_predict", ar.dim, len(js))
+    with spans.span("arena.d2h"):
+        costs = jax.device_get(costs)
+    out = [np.empty((len(fns), ar.n_classes), F32)
+           for ar, fns, _, _ in groups]
+    for (g, _, _, js, rs, _, _), c in zip(staged, costs):
+        out[g][js] = c[rs]
+    return out
 
 
 @dataclasses.dataclass
@@ -502,9 +604,9 @@ class ArenaEngine:
     are disjoint state, so inter-row reordering is exact) and runs each
     pass as one fused computation: the calibrated NumPy path stacks
     every agent of equal dim (vCPU and memory regressors included) into
-    a single row-stacked update; otherwise the vmapped jit kernel runs
-    one dispatch per (n_classes, dim) group, padded to power-of-two
-    batches with exact no-op entries and donated buffers."""
+    a single row-stacked update; on resident state the pass copies its
+    inputs in once and launches one masked block update per arena and
+    touched block, reading nothing back."""
 
     def __init__(
         self,
@@ -637,43 +739,30 @@ class ArenaEngine:
         for i, p in enumerate(batch):
             by_dim.setdefault(len(p.xb) - 1, []).append(i)
         vc, mc = self._cost_matrices(batch)
+        resident = []
         for dim, idxs in by_dim.items():
             va = self._arena(self.n_vcpu_classes, dim)
             ma = self._arena(self.n_mem_classes, dim)
-            vslots = [va.slot(batch[i].function) for i in idxs]
-            mslots = [ma.slot(batch[i].function) for i in idxs]
+            fns = [batch[i].function for i in idxs]
             xbs = np.stack([batch[i].xb for i in idxs])
             vcosts = np.ascontiguousarray(vc[idxs]).astype(F32)
             mcosts = np.ascontiguousarray(mc[idxs]).astype(F32)
-            k = len(idxs)
+            if va.resident:
+                resident += [(va, fns, xbs, vcosts), (ma, fns, xbs, mcosts)]
+                continue
+            vslots = [va.slot(f) for f in fns]
+            mslots = [ma.slot(f) for f in fns]
+            # row-disjoint chunks are exact, so oversized passes (e.g.
+            # the pending-cap flush during the learning phase) split
+            # instead of falling back to a fresh XLA compile
             per_item = self.n_vcpu_classes + self.n_mem_classes
-            if numpy_backend(dim):
-                # row-disjoint chunks are exact, so oversized passes
-                # (e.g. the pending-cap flush during the learning phase)
-                # split instead of falling back to a fresh XLA compile
-                step = max(numpy_crossover_rows(dim) // per_item, 1)
-                for lo in range(0, k, step):
-                    sl = slice(lo, lo + step)
-                    self._update_numpy(va, vslots[sl], ma, mslots[sl],
-                                       xbs[sl], vcosts[sl], mcosts[sl])
-            elif vmap_backend(dim):
-                self._update_jax(va, vslots, xbs, vcosts)
-                self._update_jax(ma, mslots, xbs, mcosts)
-            else:  # sequential reference kernels (always bit-identical)
-                for j, i in enumerate(idxs):
-                    x = batch[i].xb[:-1]
-                    for ar, sl, cs in ((va, vslots[j], vcosts[j]),
-                                       (ma, mslots[j], mcosts[j])):
-                        with spans.span("arena.h2d"):
-                            args = (jnp.asarray(ar.w[sl]),
-                                    jnp.asarray(ar.g2[sl]), jnp.asarray(x),
-                                    jnp.asarray(cs), jnp.asarray(self.lr))
-                        with spans.span("arena.launch"):
-                            w, g2 = _csc_update(*args)
-                        _dispatched("csc_update", dim)
-                        with spans.span("arena.d2h"):
-                            ar.w[sl] = np.asarray(w)
-                            ar.g2[sl] = np.asarray(g2)
+            step = max(numpy_crossover_rows(dim) // per_item, 1)
+            for lo in range(0, len(idxs), step):
+                sl = slice(lo, lo + step)
+                self._update_numpy(va, vslots[sl], ma, mslots[sl],
+                                   xbs[sl], vcosts[sl], mcosts[sl])
+        if resident:
+            _update_resident(resident)
 
     def _update_numpy(self, va, vslots, ma, mslots, xbs, vcosts, mcosts):
         """One row-stacked exact update covering both resources of the
@@ -709,61 +798,6 @@ class ArenaEngine:
         ma.w[mslots] = nw[split:].reshape(k, nm, d1)
         ma.g2[mslots] = ng[split:].reshape(k, nm, d1)
 
-    @staticmethod
-    def _bucket(k: int) -> int:
-        return min(1 << (k - 1).bit_length(), _MAX_BUCKET)
-
-    def _update_jax(self, ar: AgentArena, slots: List[int],
-                    xbs: np.ndarray, costs: np.ndarray) -> None:
-        k, d1 = xbs.shape
-        for lo in range(0, k, _MAX_BUCKET):  # never exceed a calibrated shape
-            sl = slots[lo:lo + _MAX_BUCKET]
-            kc = len(sl)
-            kb = self._bucket(kc)
-            W = np.zeros((kb, ar.n_classes, d1), F32)
-            G2 = np.zeros((kb, ar.n_classes, d1), F32)
-            XB = np.zeros((kb, d1), F32)
-            C = np.zeros((kb, ar.n_classes), F32)
-            W[:kc] = ar.w[sl]
-            G2[:kc] = ar.g2[sl]
-            XB[:kc] = xbs[lo:lo + kc]
-            C[:kc] = costs[lo:lo + kc]
-            # padding entries are exact no-ops: zero xb ⇒ zero grad ⇒
-            # w/g2 unchanged; padded outputs are simply discarded below
-            with spans.span("arena.h2d"):
-                args = (jnp.asarray(W), jnp.asarray(G2), jnp.asarray(XB),
-                        jnp.asarray(C), jnp.asarray(self.lr))
-            with spans.span("arena.launch"):
-                nw, ng = _batched_update(*args)
-            _dispatched("batched_update", ar.dim)
-            with spans.span("arena.d2h"):
-                ar.w[sl] = np.asarray(nw)[:kc]
-                ar.g2[sl] = np.asarray(ng)[:kc]
-
-    def _predict_jax(self, ar: AgentArena, slots: List[int],
-                     xbs: np.ndarray) -> np.ndarray:
-        """(k, n_classes) cost rows via the fused vmapped kernel, with
-        the same bucket/pad/chunk policy as _update_jax (padded rows'
-        outputs are discarded)."""
-        k, d1 = xbs.shape
-        out = np.empty((k, ar.n_classes), F32)
-        for lo in range(0, k, _MAX_BUCKET):
-            sl = slots[lo:lo + _MAX_BUCKET]
-            kc = len(sl)
-            kb = self._bucket(kc)
-            W = np.zeros((kb, ar.n_classes, d1), F32)
-            XB = np.zeros((kb, d1), F32)
-            W[:kc] = ar.w[sl]
-            XB[:kc] = xbs[lo:lo + kc]
-            with spans.span("arena.h2d"):
-                args = (jnp.asarray(W), jnp.asarray(XB))
-            with spans.span("arena.launch"):
-                costs = _batched_predict(*args)
-            _dispatched("batched_predict", ar.dim)
-            with spans.span("arena.d2h"):
-                out[lo:lo + kc] = np.asarray(costs)[:kc]
-        return out
-
     # ------------------------------------------------------------ predict
     def predict_batch(
         self, items: Sequence[Tuple[str, np.ndarray, bool, bool]]
@@ -774,8 +808,7 @@ class ArenaEngine:
         computation per backend group."""
         with spans.span("arena.predict_batch"):
             out: List[Tuple[Optional[int], Optional[int]]] = [
-                (None, None) for _ in items
-            ]
+                (None, None) for _ in items]
             by_dim: Dict[int, List[int]] = {}
             for i, (fn, x, want_v, want_m) in enumerate(items):
                 if want_v or want_m:
@@ -792,77 +825,56 @@ class ArenaEngine:
                 self.flush("own")
             elif len(self._pending) >= 256:
                 self.flush("cap")
-            if len(by_dim) == 1 and len(items) == 1:
-                (dim, _), = by_dim.items()
-                fn, x, want_v, want_m = items[0]
-                if numpy_backend(dim):
-                    out[0] = self._predict_one_numpy(fn, x, dim, want_v, want_m)
-                    return out
+            picks: Dict[int, List[Optional[int]]] = {
+                i: [None, None] for idxs in by_dim.values() for i in idxs}
+            groups, owners = [], []  # resident predicts, their items
             for dim, idxs in by_dim.items():
                 va = self._arena(self.n_vcpu_classes, dim)
                 ma = self._arena(self.n_mem_classes, dim)
+                if not va.resident and len(items) == 1:
+                    fn, x, want_v, want_m = items[0]
+                    out[0] = self._predict_one_numpy(fn, x, dim, want_v, want_m)
+                    return out
+                xb_of = {i: np.append(np.asarray(items[i][1], F32), F32(1))
+                         for i in idxs}
+                if va.resident:
+                    # a function twice in the cohort shares a row, so
+                    # its second item goes in the next round's dispatch
+                    rounds = collections.defaultdict(list)
+                    seen = collections.Counter()
+                    for i in idxs:
+                        rounds[seen[items[i][0]]].append(i)
+                        seen[items[i][0]] += 1
+                    for rnd, (ar, pos) in itertools.product(
+                            rounds.values(), ((va, 0), (ma, 1))):
+                        want = [i for i in rnd if items[i][2 + pos]]
+                        if want:
+                            groups.append((ar, [items[i][0] for i in want],
+                                           np.stack([xb_of[i] for i in want]),
+                                           None))
+                            owners.append((pos, want))
+                    continue
                 nv, nm = self.n_vcpu_classes, self.n_mem_classes
                 v_items = [i for i in idxs if items[i][2]]
                 m_items = [i for i in idxs if items[i][3]]
-                rows = len(v_items) * nv + len(m_items) * nm
-                if numpy_backend(dim) and rows <= numpy_crossover_rows(dim):
-                    xb_of = {
-                        i: np.concatenate([np.asarray(items[i][1], F32),
-                                           np.ones(1, F32)])
-                        for i in idxs
-                    }
-                    w = np.concatenate(
-                        [va.w[va.slot(items[i][0])] for i in v_items]
-                        + [ma.w[ma.slot(items[i][0])] for i in m_items]
-                    ) if rows else np.zeros((0, dim + 1), F32)
-                    xb = np.concatenate(
-                        [np.repeat(xb_of[i][None, :], nv, axis=0) for i in v_items]
-                        + [np.repeat(xb_of[i][None, :], nm, axis=0) for i in m_items]
-                    ) if rows else np.zeros((0, dim + 1), F32)
-                    costs = _matvec_exact(w, xb)
-                    off = 0
-                    picks: Dict[int, List[Optional[int]]] = {
-                        i: [None, None] for i in idxs
-                    }
-                    for i in v_items:
-                        picks[i][0] = int(np.argmin(costs[off:off + nv]))
-                        off += nv
-                    for i in m_items:
-                        picks[i][1] = int(np.argmin(costs[off:off + nm]))
-                        off += nm
-                    for i in idxs:
-                        out[i] = (picks[i][0], picks[i][1])
-                else:
-                    res: Dict[int, List[Optional[int]]] = {i: [None, None]
-                                                           for i in idxs}
-                    for slot_items, ar, pos in ((v_items, va, 0), (m_items, ma, 1)):
-                        if len(slot_items) >= 2 and vmap_backend(dim):
-                            # one fused vmapped dispatch per agent group
-                            slots = [ar.slot(items[i][0]) for i in slot_items]
-                            xbs = np.zeros((len(slot_items), dim + 1), F32)
-                            for j, i in enumerate(slot_items):
-                                xbs[j, :dim] = items[i][1]
-                                xbs[j, dim] = 1.0
-                            costs = self._predict_jax(ar, slots, xbs)
-                            for j, i in enumerate(slot_items):
-                                res[i][pos] = int(np.argmin(costs[j]))
-                        else:
-                            for i in slot_items:
-                                fn, x = items[i][0], items[i][1]
-                                row = ar.w[ar.slot(fn)]
-                                with spans.span("arena.h2d"):
-                                    args = (jnp.asarray(row),
-                                            jnp.asarray(x, dtype=jnp.float32))
-                                with spans.span("arena.launch"):
-                                    c = _csc_predict(*args, ar.n_classes)
-                                _dispatched("csc_predict", dim)
-                                with spans.span("arena.launch"):
-                                    m = jnp.argmin(c)
-                                _dispatched("argmin", dim)
-                                with spans.span("arena.d2h"):
-                                    res[i][pos] = int(m)
-                    for i in idxs:
-                        out[i] = (res[i][0], res[i][1])
+                w = np.concatenate(
+                    [va.w[va.slot(items[i][0])] for i in v_items]
+                    + [ma.w[ma.slot(items[i][0])] for i in m_items])
+                xb = np.concatenate(
+                    [np.repeat(xb_of[i][None, :], nv, axis=0) for i in v_items]
+                    + [np.repeat(xb_of[i][None, :], nm, axis=0) for i in m_items])
+                costs = _matvec_exact(w, xb)
+                off = 0
+                for sel, n, pos in ((v_items, nv, 0), (m_items, nm, 1)):
+                    for i in sel:
+                        picks[i][pos] = int(np.argmin(costs[off:off + n]))
+                        off += n
+            for (pos, want), costs in zip(
+                    owners, _predict_resident(groups) if groups else []):
+                for i, c in zip(want, costs):
+                    picks[i][pos] = int(np.argmin(c))
+            for i, (v, m) in picks.items():
+                out[i] = (v, m)
             return out
 
     def _predict_one_numpy(self, fn: str, x: np.ndarray, dim: int,
@@ -913,7 +925,7 @@ class ArenaEngine:
                     self.flush("cap")
                 return (None, None)
             dim = self._dim_of(function, x)
-            if numpy_backend(dim):
+            if not self._arena(self.n_vcpu_classes, dim).resident:
                 if function in self._pending_fns:
                     self.flush("own")
                 elif len(self._pending) >= 256:
@@ -929,22 +941,12 @@ class ArenaEngine:
         va = self._arena(self.n_vcpu_classes, dim)
         ma = self._arena(self.n_mem_classes, dim)
         xb = np.concatenate([np.asarray(x, F32), np.ones(1, F32)])
-        if numpy_backend(dim):
-            return (
-                _matvec_exact(va.w[va.slot(function)], xb),
-                _matvec_exact(ma.w[ma.slot(function)], xb),
-            )
-        out = []
-        for ar in (va, ma):
-            row = ar.w[ar.slot(function)]
-            with spans.span("arena.h2d"):
-                args = (jnp.asarray(row), jnp.asarray(x, jnp.float32))
-            with spans.span("arena.launch"):
-                c = _csc_predict(*args, ar.n_classes)
-            _dispatched("csc_predict", dim)
-            with spans.span("arena.d2h"):
-                out.append(np.asarray(c))
-        return tuple(out)
+        if va.resident:
+            vc, mc = _predict_resident([(va, [function], xb[None], None),
+                                        (ma, [function], xb[None], None)])
+            return vc[0], mc[0]
+        return (_matvec_exact(va.w[va.slot(function)], xb),
+                _matvec_exact(ma.w[ma.slot(function)], xb))
 
     # ------------------------------------------------------------- debug
     def weights(self, function: str):
@@ -953,6 +955,4 @@ class ArenaEngine:
         dim = self._dims[function]
         va = self._arena(self.n_vcpu_classes, dim)
         ma = self._arena(self.n_mem_classes, dim)
-        sv, sm = va.slot(function), ma.slot(function)
-        return (va.w[sv].copy(), va.g2[sv].copy(),
-                ma.w[sm].copy(), ma.g2[sm].copy())
+        return va.row(function) + ma.row(function)

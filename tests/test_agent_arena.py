@@ -306,6 +306,156 @@ def test_jax_fallback_chunks_past_max_bucket(monkeypatch):
         _assert_same_weights(arena, legacy, f)
 
 
+# ------------------------------------------------- device-resident state
+RESIDENT_DIMS = [1, 2, 3, 5, 6]
+
+
+@pytest.fixture
+def resident(monkeypatch):
+    """The NumPy backend off, so that every arena is device-resident."""
+    monkeypatch.setattr(agent_arena, "numpy_backend", lambda d: False)
+
+
+def _resident_stream(dim, seed, n_fns=agent_arena._MAX_BUCKET + 5, n_ops=90,
+                     check=True):
+    """Served allocations of an arena/legacy pair over ``n_fns``
+    functions of one dim, with singleton and batched allocations (the
+    batches repeat functions) and feedbacks, each allocation checked
+    against legacy when ``check``; returns both allocators too."""
+    rng = np.random.default_rng(seed)
+    arena, legacy = _pair(vcpu_confidence=1, mem_confidence=2)
+    fns = [f"r{i}" for i in range(n_fns)]
+    for f in fns:  # every function gets a row: past the first block
+        obs = _rand_obs(rng)
+        x = rng.standard_normal(dim).astype(np.float32)
+        arena.feedback(f, x, obs)
+        legacy.feedback(f, x, obs)
+    served = []
+    for _ in range(n_ops):
+        r = rng.random()
+        if r < 0.25:
+            picks = [fns[i] for i in rng.integers(len(fns), size=5)]
+            batch = [(f, rng.standard_normal(dim).astype(np.float32), 0.0)
+                     for f in picks]
+            got = arena.allocate_batch(batch)
+            assert not check or got == [legacy.allocate(*b) for b in batch]
+            served.append(got)
+        else:
+            f = fns[int(rng.integers(len(fns)))]
+            x = rng.standard_normal(dim).astype(np.float32)
+            if r < 0.6:
+                got = arena.allocate(f, x, 0.0)
+                assert not check or got == legacy.allocate(f, x, 0.0)
+                served.append(got)
+            else:
+                obs = _rand_obs(rng)
+                arena.feedback(f, x, obs)
+                legacy.feedback(f, x, obs)
+    return served, arena, legacy, fns
+
+
+@pytest.mark.parametrize("dim", RESIDENT_DIMS)
+def test_resident_engine_matches_legacy_past_one_block(resident, dim):
+    """More than 16 functions of one dim grow the resident state into a
+    second block; served classes and weights stay bit-identical."""
+    _, arena, legacy, fns = _resident_stream(dim, seed=100 + dim)
+    va = arena._arena._arena(arena.n_vcpu_classes, dim)
+    assert va.resident and len(va.blocks) == 2
+    for f in fns:
+        _assert_same_weights(arena, legacy, f)
+
+
+@pytest.mark.parametrize("dim", RESIDENT_DIMS)
+def test_resident_release_and_reuse_starts_fresh(resident, dim):
+    rng = np.random.default_rng(200 + dim)
+    arena, legacy = _pair(vcpu_confidence=1, mem_confidence=1)
+    xa = rng.standard_normal(dim).astype(np.float32)
+    for _ in range(3):
+        obs = _rand_obs(rng)
+        for al in (arena, legacy):
+            al.feedback("a", xa, obs)
+            al.feedback("bystander", -xa, obs)
+    before = arena._arena.weights("bystander")
+    eng = arena._arena
+    slot_a = eng._arena(arena.n_vcpu_classes, dim).slot("a")
+    arena.release("a")
+    legacy.release("a")
+    eng._dim_of("fresh", xa)
+    assert eng._arena(arena.n_vcpu_classes, dim).slot("fresh") == slot_a
+    for row in eng._arena(arena.n_vcpu_classes, dim).row("fresh") + \
+            eng._arena(arena.n_mem_classes, dim).row("fresh"):
+        assert not row.any()  # a fresh zero agent
+    obs = _rand_obs(rng)
+    arena.feedback("fresh", xa, obs)
+    legacy.feedback("fresh", xa, obs)
+    assert arena.allocate("fresh", xa) == legacy.allocate("fresh", xa)
+    _assert_same_weights(arena, legacy, "fresh")
+    for b, a in zip(before, arena._arena.weights("bystander")):
+        assert np.array_equal(b, a)
+
+
+@pytest.mark.parametrize("dim", RESIDENT_DIMS)
+def test_resident_pass_leaves_untouched_rows_bit_identical(resident, dim):
+    """A masked block update changes the pass's rows only: every other
+    row of the touched blocks, fresh zero rows included, keeps its bits."""
+    import jax
+
+    _, arena, _, fns = _resident_stream(dim, seed=300 + dim, n_ops=20)
+    eng = arena._arena
+    eng.flush()
+    arenas = [eng._arena(n, dim) for n in (arena.n_vcpu_classes,
+                                           arena.n_mem_classes)]
+    before = [jax.device_get(ar.blocks) for ar in arenas]
+    rng = np.random.default_rng(dim)
+    touched = [fns[0], fns[7], fns[agent_arena._MAX_BUCKET + 1]]
+    for f in touched:
+        eng.enqueue_update(f, rng.standard_normal(dim).astype(np.float32),
+                           _rand_obs(rng))
+    eng.flush()
+    for ar, old in zip(arenas, before):
+        moved = {ar.slot(f) for f in touched}
+        for b, (ow, og) in enumerate(old):
+            nw, ng = jax.device_get(ar.blocks[b])
+            for r in range(agent_arena._MAX_BUCKET):
+                s = b * agent_arena._MAX_BUCKET + r
+                if s in moved:
+                    assert not np.array_equal(nw[r], ow[r])
+                else:
+                    assert np.array_equal(nw[r], ow[r])
+                    assert np.array_equal(ng[r], og[r])
+
+
+@pytest.mark.parametrize("dim", RESIDENT_DIMS)
+def test_resident_cohort_repeating_a_function(resident, dim):
+    """Two items of one function in a cohort share a row; served as
+    legacy serves them one after the other."""
+    rng = np.random.default_rng(400 + dim)
+    arena, legacy = _pair(vcpu_confidence=1, mem_confidence=1)
+    xs = [rng.standard_normal(dim).astype(np.float32) for _ in range(4)]
+    for f in ("p", "q"):
+        for x in xs[:2]:
+            obs = _rand_obs(rng)
+            arena.feedback(f, x, obs)
+            legacy.feedback(f, x, obs)
+    batch = [("p", xs[0], 0.0), ("q", xs[1], 0.0), ("p", xs[2], 0.0),
+             ("p", xs[3], 0.0)]
+    assert arena.allocate_batch(batch) == [legacy.allocate(*b) for b in batch]
+
+
+@pytest.mark.parametrize("dim", RESIDENT_DIMS)
+def test_resident_kernels_are_looked_up_at_call_time(resident, monkeypatch,
+                                                     dim):
+    """A kernel patched into the module after import is the one the
+    resident path runs: here one that learns from negated costs."""
+    served, *_ = _resident_stream(dim, seed=500 + dim, n_ops=40)
+    orig = agent_arena._batched_update
+    monkeypatch.setattr(agent_arena, "_batched_update",
+                        lambda w, g2, xb, c, lr: orig(w, g2, xb, -c, lr))
+    patched, *_ = _resident_stream(dim, seed=500 + dim, n_ops=40,
+                                   check=False)
+    assert patched != served
+
+
 def test_arena_growth_preserves_weights():
     ar = AgentArena(n_classes=4, dim=2, capacity=2)
     s0 = ar.slot("x")
