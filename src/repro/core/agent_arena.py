@@ -489,6 +489,7 @@ class AgentArena:
                 shape = (_MAX_BUCKET, self.n_classes, self.dim + 1)
                 self.blocks.append((jnp.zeros(shape, F32),
                                     jnp.zeros(shape, F32)))
+                spans.count("arena.block_grow")
             elif s >= self.capacity:  # grow by doubling
                 pad = np.zeros_like(self.w)
                 self.w = np.concatenate([self.w, pad])

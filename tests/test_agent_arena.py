@@ -6,8 +6,9 @@ capacity growth across the doubling boundary, per-function isolation
 after slot release/reuse, the flush ordering rule (updates for F apply
 before any predict for F), batched-vs-scalar cost vectors, the
 calibrated NumPy backend and the vmapped JAX fallback, same-timestamp
-arrival microbatching in the simulator, the retry-payload featurization
-cache, and the legacy-engine golden pin."""
+arrival microbatching in the simulator, cloned functions whose resident
+agents span two device blocks through the simulator, the retry-payload
+featurization cache, and the legacy-engine golden pin."""
 
 import json
 import os
@@ -530,6 +531,64 @@ def test_engines_identical_through_simulator():
     assert len(res_a) == len(res_l)
     for a, b in zip(res_a, res_l):
         assert a == b
+
+
+def _drop_updates_past_block_0(monkeypatch):
+    """Updates of agents whose slot lies past block 0 never land."""
+    orig = agent_arena._update_resident
+
+    def update_resident(groups):
+        kept = []
+        for ar, fns, xbs, costs in groups:
+            js = [j for j, fn in enumerate(fns)
+                  if ar.slot(fn) < agent_arena._MAX_BUCKET]
+            if js:
+                kept.append((ar, [fns[j] for j in js], xbs[js], costs[js]))
+        if kept:
+            orig(kept)
+
+    monkeypatch.setattr(agent_arena, "_update_resident", update_resident)
+
+
+@pytest.mark.parametrize("clones,fault,same", [(6, False, True),
+                                                (6, True, False),
+                                                (1, True, True)])
+def test_cloned_functions_through_simulator_past_one_block(
+        resident, monkeypatch, clones, fault, same):
+    """Six deployments of each profiled function (72 agents, 18-24 of
+    them per feature dim in dims 1, 3 and 6) under uniform load on eight
+    workers: the
+    resident arenas span two blocks and the full stack stays identical
+    to the legacy engine. Dropping the updates past block 0 shows there,
+    and not with the 12 functions alone, whose arenas hold one block."""
+    from repro.serving.experiment import expand_function_clones
+    from repro.serving.workload import ScenarioSpec, generate_scenario
+
+    profiles, pool, slo = expand_function_clones(*_sim_fixture(), clones)
+    spec = ScenarioSpec(scenario="cold-storm", rps=4.0, duration_s=150.0,
+                        seed=5)
+    trace = generate_scenario(
+        spec, functions=sorted(profiles),
+        inputs_per_function={f: len(pool[f]) for f in profiles})
+    pol_l, res_l = _run_shabari("legacy", trace, profiles, pool, slo,
+                                n_workers=8)
+    if fault:
+        _drop_updates_past_block_0(monkeypatch)
+    pol_a, res_a = _run_shabari("arena", trace, profiles, pool, slo,
+                                n_workers=8)
+    arenas = pol_a.allocator._arena._arenas.values()
+    assert all(ar.resident for ar in arenas)
+    assert max(len(ar.blocks) for ar in arenas) == (2 if clones > 1 else 1)
+    weights = []
+    for fn in profiles:
+        if fn in pol_l.allocator._agents:
+            try:
+                _assert_same_weights(pol_a.allocator, pol_l.allocator, fn)
+                weights.append(True)
+            except AssertionError:
+                weights.append(False)
+    assert len(weights) == len(profiles)
+    assert (all(weights) and res_a == res_l) is same
 
 
 def test_same_timestamp_arrivals_batch_identically():

@@ -242,3 +242,72 @@ def test_tracing_changes_no_decision_and_no_weight(v5e):
     for fn in off[1]:
         for a, b in zip(on[1][fn], off[1][fn]):
             assert np.array_equal(a, b)
+
+
+def _staged(monkeypatch):
+    """Blocks that each ``_stage`` call hands back, call by call."""
+    staged = []
+    orig = agent_arena._stage
+
+    def stage(groups):
+        out = orig(groups)
+        staged.append(len(out))
+        return out
+
+    monkeypatch.setattr(agent_arena, "_stage", stage)
+    return staged
+
+
+THREE_BLOCKS = 2 * BLOCK + 8
+
+
+@pytest.mark.parametrize("call", ["flush", "predict_vcpu", "predict_both"])
+def test_dispatch_counters_count_the_blocks_staged(v5e, monkeypatch, call):
+    """Functions of one dim over three blocks: one flush pass, or one
+    predict call, stages one block per arena and touched block, copies
+    them in once and launches once on each, so the dispatch counters
+    count the blocks a call touches."""
+    eng, xs = _engine(THREE_BLOCKS)
+    rng = np.random.default_rng(7)
+    for f, x in xs.items():
+        eng.enqueue_update(f, x, _obs(rng))
+    if call.startswith("predict"):
+        eng.flush()
+    staged = _staged(monkeypatch)
+    v5e.clear()
+    spans.enable()
+    if call == "flush":
+        eng.flush()
+        arenas = 2
+    else:
+        arenas = 1 if call == "predict_vcpu" else 2
+        eng.predict_batch([(f, x, True, arenas == 2) for f, x in xs.items()])
+    snap = spans.snapshot()
+    assert staged == [3 * arenas]
+    assert snap["spans"]["arena.h2d"]["calls"] == 1
+    assert sum(n for k, n in snap["counters"].items()
+               if k.startswith("arena.dispatch/")) == sum(staged)
+    assert sum(v5e.values()) == sum(staged)
+    assert "arena.block_grow" not in snap["counters"]  # no new function
+
+
+def test_block_grow_counts_the_blocks_appended(v5e):
+    spans.enable()
+    eng, xs = _engine(THREE_BLOCKS)
+    grown = sum(len(ar.blocks) for ar in eng._arenas.values())
+    assert grown == 2 * 3
+    assert spans.snapshot()["counters"]["arena.block_grow"] == grown
+    # a known function, or a slot freed and taken again, grows nothing
+    eng.release("h0")
+    eng.enqueue_update("new", np.ones(2, np.float32),
+                       _obs(np.random.default_rng(1)))
+    eng.enqueue_update("h1", np.ones(2, np.float32),
+                       _obs(np.random.default_rng(2)))
+    assert spans.snapshot()["counters"]["arena.block_grow"] == grown
+
+
+def test_block_counters_stay_silent_with_spans_off(v5e):
+    eng, xs = _engine(THREE_BLOCKS)
+    eng.predict_batch([(f, x, True, True) for f, x in xs.items()])
+    assert sum(len(ar.blocks) for ar in eng._arenas.values()) == 2 * 3
+    assert spans.snapshot()["counters"] == {}
