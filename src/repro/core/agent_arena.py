@@ -10,11 +10,16 @@ overhead wall the paper measures in Fig. 14. The arena fuses them:
   (:class:`AgentArena`); a function-name→row map assigns slots, and
   released slots are zeroed and reused. Where the NumPy backend holds
   (below) the pair is a host ``(capacity, n_classes, dim+1)`` array
-  that grows by doubling. Elsewhere (every dimension on a TPU, and the
-  one-hot formulation's uncalibrated features anywhere) the state is
-  *resident on the device* in blocks of ``_MAX_BUCKET`` rows: slot ``s``
-  is row ``s % 16`` of block ``s // 16``, growth appends a zeroed block,
-  and the learning rate is a device scalar made once.
+  that grows by doubling, one arena per resource. Elsewhere (every
+  dimension on a TPU, and the one-hot formulation's uncalibrated
+  features anywhere) the state is *resident on the device* in blocks of
+  ``_MAX_BUCKET`` rows: slot ``s`` is row ``s % 16`` of block ``s //
+  16``, growth appends a zeroed block, and the learning rate is a device
+  scalar made once. A resident dim keeps ONE arena: a function's row
+  stacks its vCPU regressors (classes ``[:n_vcpu]``) and its memory
+  regressors (the rest) on the class axis. CSOAA updates and predicts
+  each class row on its own, so the stacked row computes exactly what
+  the two agents compute apart, with one slot, launch and cost block.
 * **Deferred microbatched updates** — completed-invocation feedbacks are
   queued (:class:`ArenaEngine`) and flushed lazily. The ordering rule —
   *pending updates for function F flush before any predict for F* —
@@ -23,18 +28,19 @@ overhead wall the paper measures in Fig. 14. The arena fuses them:
   updates are applied in arrival order via conflict-free passes.
 * **Masked block dispatches** — on resident state a flush pass copies
   in, per touched block, a ``(16, dim+1)`` input and a ``(16,
-  n_classes)`` cost matrix with the pass's rows at their slot offsets
-  and zeros elsewhere, launches :data:`_batched_update` (buffers
+  n_vcpu + n_mem)`` cost matrix with the pass's rows at their slot
+  offsets and zeros elsewhere, launches :data:`_batched_update` (buffers
   donated) on the whole block and reads nothing back: a zero input row
   is an exact no-op (zero gradient, so ``w`` and ``g2`` keep their
-  bits). A predict launches :data:`_batched_predict` once per wanted
-  arena block, reads all its cost blocks in one transfer and takes the
-  arg-min on the host. The kernels are looked up at call time and never
-  wrapped in a further ``jit`` (a fused gather/update/scatter program
-  is not bit-identical to the reference on the CPU). The engine does
-  not consult :func:`vmap_backend`: where the batched kernel differs
-  from the per-row one in its last bits (dims 2, 5 and 6 on a v5e) it
-  runs all the same, held to the chip benchmark's float64 reference.
+  bits). A predict launches :data:`_batched_predict` once per touched
+  block, reads all its cost blocks in one transfer and takes the
+  arg-min of each wanted resource's classes on the host. The kernels
+  are looked up at call time and never wrapped in a further ``jit`` (a
+  fused gather/update/scatter program is not bit-identical to the
+  reference on the CPU). The engine does not consult
+  :func:`vmap_backend`: where the batched kernel differs from the
+  per-row one in its last bits (some dims on a v5e) it runs all the
+  same, held to the chip benchmark's float64 reference.
 * **Calibrated NumPy backend** — for the small batches that dominate a
   discrete-event loop (most events carry one predict or one update), a
   dispatch-free NumPy path beats the JAX call by a wide margin. XLA's
@@ -62,7 +68,6 @@ import ctypes
 import ctypes.util
 import dataclasses
 import functools
-import itertools
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -338,31 +343,33 @@ def numpy_backend(dim: int) -> bool:
 
 @functools.lru_cache(maxsize=None)
 def vmap_backend(dim: int) -> bool:
-    """True iff the vmapped batched kernels match per-row reference
-    calls bitwise at the resident block's shape (they do on CPU XLA; a
-    diagnostic the benchmark prints, not a switch)."""
+    """True iff the vmapped batched kernels, on a resident block whose
+    rows stack 32 vCPU and 40 memory classes, match per-agent reference
+    calls on each half bitwise (they do on CPU XLA; a diagnostic the
+    benchmark prints, not a switch)."""
     rng = np.random.default_rng(0xBA7C)
     lr = F32(0.5)
     k = _MAX_BUCKET
-    for n in (32, 40):
-        W, G2, X, C = (np.stack(a) for a in zip(
-            *[_reference_pair(rng, n, dim) for _ in range(k)]))
-        XB = np.concatenate([X, np.ones((k, 1), F32)], axis=1)
-        # copies: _batched_update donates its first two buffers
-        bw, bg = _batched_update(
-            jnp.asarray(W), jnp.asarray(G2), jnp.asarray(XB),
-            jnp.asarray(C), jnp.asarray(lr),
-        )
-        bc = _batched_predict(jnp.asarray(W), jnp.asarray(XB))
-        for i in range(k):
+    W, G2, X, C = (np.stack(a) for a in zip(
+        *[_reference_pair(rng, 32 + 40, dim) for _ in range(k)]))
+    XB = np.concatenate([X, np.ones((k, 1), F32)], axis=1)
+    # copies: _batched_update donates its first two buffers
+    bw, bg = (np.asarray(a) for a in _batched_update(
+        jnp.asarray(W), jnp.asarray(G2), jnp.asarray(XB),
+        jnp.asarray(C), jnp.asarray(lr),
+    ))
+    bc = np.asarray(_batched_predict(jnp.asarray(W), jnp.asarray(XB)))
+    for i in range(k):
+        for h in (slice(0, 32), slice(32, 72)):
             rw, rg = _csc_update(
-                jnp.asarray(W[i]), jnp.asarray(G2[i]), jnp.asarray(X[i]),
-                jnp.asarray(C[i]), jnp.asarray(lr),
+                jnp.asarray(W[i, h]), jnp.asarray(G2[i, h]),
+                jnp.asarray(X[i]), jnp.asarray(C[i, h]), jnp.asarray(lr),
             )
-            rc = _csc_predict(jnp.asarray(W[i]), jnp.asarray(X[i]), n)
-            if not (np.array_equal(np.asarray(bw[i]), np.asarray(rw))
-                    and np.array_equal(np.asarray(bg[i]), np.asarray(rg))
-                    and np.array_equal(np.asarray(bc[i]), np.asarray(rc))):
+            rc = _csc_predict(jnp.asarray(W[i, h]), jnp.asarray(X[i]),
+                              h.stop - h.start)
+            if not (np.array_equal(bw[i, h], np.asarray(rw))
+                    and np.array_equal(bg[i, h], np.asarray(rg))
+                    and np.array_equal(bc[i, h], np.asarray(rc))):
                 return False
     return True
 
@@ -529,11 +536,10 @@ def _stage(groups):
     ``device_put``. Per ``(arena, functions, xbs, costs or None)`` group
     (functions distinct) and touched block: ``XB (16, dim+1)`` and ``C
     (16, n_classes)`` with the group's rows at their slot offsets, zeros
-    elsewhere; equal ``XB`` are copied once. Returns ``(group, arena,
-    block, js, rs, XB, C)`` per block: ``js`` index the group's rows,
-    ``rs`` their offsets in the block."""
+    elsewhere. Returns ``(group, arena, block, js, rs, XB, C)`` per
+    block: ``js`` index the group's rows, ``rs`` their offsets in the
+    block."""
     host: List[np.ndarray] = []
-    seen: Dict[bytes, int] = {}
     todo = []
     for g, (ar, fns, xbs, costs) in enumerate(groups):
         by_block: Dict[int, Tuple[List[int], List[int]]] = {}
@@ -545,9 +551,8 @@ def _stage(groups):
         for b, (js, rs) in by_block.items():
             XB = np.zeros((_MAX_BUCKET, ar.dim + 1), F32)
             XB[rs] = xbs[js]
-            kx = seen.setdefault(XB.tobytes(), len(host))
-            if kx == len(host):
-                host.append(XB)
+            kx = len(host)
+            host.append(XB)
             kc = None
             if costs is not None:
                 C = np.zeros((_MAX_BUCKET, ar.n_classes), F32)
@@ -598,15 +603,16 @@ class _PendingUpdate:
 
 
 class ArenaEngine:
-    """The vCPU + memory arena pair behind ``ResourceAllocator``.
+    """The vCPU and memory agents behind ``ResourceAllocator``.
 
     Feedbacks enqueue; predicts flush. A flush drains the queue in
     conflict-free passes (each agent row at most once per pass — rows
     are disjoint state, so inter-row reordering is exact) and runs each
     pass as one fused computation: the calibrated NumPy path stacks
     every agent of equal dim (vCPU and memory regressors included) into
-    a single row-stacked update; on resident state the pass copies its
-    inputs in once and launches one masked block update per arena and
+    a single row-stacked update; on resident state, where each dim's
+    vCPU and memory regressors share one stacked arena, the pass copies
+    its inputs in once and launches one masked block update per dim and
     touched block, reading nothing back."""
 
     def __init__(
@@ -627,7 +633,8 @@ class ArenaEngine:
         self.lr = F32(lr)
         self._vcpu_batch_fn = CF.BATCHED_COST_FNS.get(vcpu_cost_fn)
         self._mem_batch_fn = CF.memory_costs_batch
-        self._arenas: Dict[Tuple[int, int], AgentArena] = {}
+        self._arenas: Dict[Tuple[int, str], AgentArena] = {}  # (dim, agents)
+        self._dim_arenas: Dict[int, Tuple[AgentArena, ...]] = {}
         self._dims: Dict[str, int] = {}  # function → feature dim
         self._counts: Dict[str, List[int]] = {}  # eager, incl. pending
         self._pending: List[_PendingUpdate] = []
@@ -638,13 +645,24 @@ class ArenaEngine:
         self._pending_fns: set = set()
 
     # ------------------------------------------------------------ slots
-    def _arena(self, n_classes: int, dim: int) -> AgentArena:
-        key = (n_classes, dim)
-        ar = self._arenas.get(key)
-        if ar is None:
-            ar = AgentArena(n_classes, dim, lr=float(self.lr))
-            self._arenas[key] = ar
-        return ar
+    def _arenas_of(self, dim: int) -> Tuple[AgentArena, ...]:
+        """The arenas of ``dim``'s agents. Where their state is resident,
+        one arena of ``n_vcpu_classes + n_mem_classes`` classes whose row
+        holds a function's vCPU regressors in classes ``[:n_vcpu_classes]``
+        and its memory regressors after them, so that one slot, one
+        launch and one cost block serve both; on the NumPy backend, the
+        vCPU and the memory host arenas."""
+        ars = self._dim_arenas.get(dim)
+        if ars is None:
+            nv, nm = self.n_vcpu_classes, self.n_mem_classes
+            parts = ({"vcpu": nv, "mem": nm} if numpy_backend(dim)
+                     else {"vcpu+mem": nv + nm})
+            for part, n in parts.items():
+                self._arenas[(dim, part)] = AgentArena(n, dim,
+                                                       lr=float(self.lr))
+            ars = tuple(self._arenas[(dim, part)] for part in parts)
+            self._dim_arenas[dim] = ars
+        return ars
 
     def _dim_of(self, function: str, x: np.ndarray) -> int:
         dim = self._dims.setdefault(function, len(x))
@@ -664,8 +682,8 @@ class ArenaEngine:
         self._pending = [p for p in self._pending if p.function != function]
         self._pending_fns.discard(function)
         if dim is not None:
-            self._arena(self.n_vcpu_classes, dim).release(function)
-            self._arena(self.n_mem_classes, dim).release(function)
+            for ar in self._arenas_of(dim):
+                ar.release(function)
 
     # ---------------------------------------------------------- feedback
     def enqueue_update(self, function: str, x: np.ndarray, obs) -> None:
@@ -687,8 +705,8 @@ class ArenaEngine:
             c[0] += 1
             c[1] += 1
             # make sure slots exist so growth happens off the predict path
-            self._arena(self.n_vcpu_classes, dim).slot(function)
-            self._arena(self.n_mem_classes, dim).slot(function)
+            for ar in self._arenas_of(dim):
+                ar.slot(function)
 
     # ------------------------------------------------------------- flush
     def flush(self, cause: str = "call") -> None:
@@ -742,15 +760,16 @@ class ArenaEngine:
         vc, mc = self._cost_matrices(batch)
         resident = []
         for dim, idxs in by_dim.items():
-            va = self._arena(self.n_vcpu_classes, dim)
-            ma = self._arena(self.n_mem_classes, dim)
+            ars = self._arenas_of(dim)
             fns = [batch[i].function for i in idxs]
             xbs = np.stack([batch[i].xb for i in idxs])
+            if ars[0].resident:  # vCPU then memory costs, as rows stack
+                costs = np.concatenate([vc[idxs], mc[idxs]], axis=1)
+                resident.append((ars[0], fns, xbs, costs.astype(F32)))
+                continue
+            va, ma = ars
             vcosts = np.ascontiguousarray(vc[idxs]).astype(F32)
             mcosts = np.ascontiguousarray(mc[idxs]).astype(F32)
-            if va.resident:
-                resident += [(va, fns, xbs, vcosts), (ma, fns, xbs, mcosts)]
-                continue
             vslots = [va.slot(f) for f in fns]
             mslots = [ma.slot(f) for f in fns]
             # row-disjoint chunks are exact, so oversized passes (e.g.
@@ -828,17 +847,17 @@ class ArenaEngine:
                 self.flush("cap")
             picks: Dict[int, List[Optional[int]]] = {
                 i: [None, None] for idxs in by_dim.values() for i in idxs}
+            nv, nm = self.n_vcpu_classes, self.n_mem_classes
             groups, owners = [], []  # resident predicts, their items
             for dim, idxs in by_dim.items():
-                va = self._arena(self.n_vcpu_classes, dim)
-                ma = self._arena(self.n_mem_classes, dim)
-                if not va.resident and len(items) == 1:
+                ars = self._arenas_of(dim)
+                if not ars[0].resident and len(items) == 1:
                     fn, x, want_v, want_m = items[0]
                     out[0] = self._predict_one_numpy(fn, x, dim, want_v, want_m)
                     return out
                 xb_of = {i: np.append(np.asarray(items[i][1], F32), F32(1))
                          for i in idxs}
-                if va.resident:
+                if ars[0].resident:
                     # a function twice in the cohort shares a row, so
                     # its second item goes in the next round's dispatch
                     rounds = collections.defaultdict(list)
@@ -846,16 +865,13 @@ class ArenaEngine:
                     for i in idxs:
                         rounds[seen[items[i][0]]].append(i)
                         seen[items[i][0]] += 1
-                    for rnd, (ar, pos) in itertools.product(
-                            rounds.values(), ((va, 0), (ma, 1))):
-                        want = [i for i in rnd if items[i][2 + pos]]
-                        if want:
-                            groups.append((ar, [items[i][0] for i in want],
-                                           np.stack([xb_of[i] for i in want]),
-                                           None))
-                            owners.append((pos, want))
+                    for rnd in rounds.values():
+                        groups.append((ars[0], [items[i][0] for i in rnd],
+                                       np.stack([xb_of[i] for i in rnd]),
+                                       None))
+                        owners.append(rnd)
                     continue
-                nv, nm = self.n_vcpu_classes, self.n_mem_classes
+                va, ma = ars
                 v_items = [i for i in idxs if items[i][2]]
                 m_items = [i for i in idxs if items[i][3]]
                 w = np.concatenate(
@@ -870,10 +886,20 @@ class ArenaEngine:
                     for i in sel:
                         picks[i][pos] = int(np.argmin(costs[off:off + n]))
                         off += n
-            for (pos, want), costs in zip(
+            # a stacked row's costs: the vCPU classes, then the memory
+            # classes; a side not wanted was computed and is ignored
+            for rnd, costs in zip(
                     owners, _predict_resident(groups) if groups else []):
-                for i, c in zip(want, costs):
-                    picks[i][pos] = int(np.argmin(c))
+                for i, c in zip(rnd, costs):
+                    want_v, want_m = items[i][2], items[i][3]
+                    if want_v:
+                        picks[i][0] = int(np.argmin(c[:nv]))
+                    if want_m:
+                        picks[i][1] = int(np.argmin(c[nv:]))
+                    if spans.on:
+                        spans.count("arena.predict_want/" + (
+                            "both" if want_v and want_m
+                            else "vcpu" if want_v else "mem"))
             for i, (v, m) in picks.items():
                 out[i] = (v, m)
             return out
@@ -885,8 +911,7 @@ class ArenaEngine:
         certified float64 screen picks the arg-min without running the
         exact FMA chain; near-ties (and all-zero agents) fall back to
         the bit-exact matvec."""
-        va = self._arena(self.n_vcpu_classes, dim)
-        ma = self._arena(self.n_mem_classes, dim)
+        va, ma = self._arenas_of(dim)
         nv = self.n_vcpu_classes
         if want_v and want_m:
             w = np.concatenate([va.w[va.slot(fn)], ma.w[ma.slot(fn)]])
@@ -926,7 +951,7 @@ class ArenaEngine:
                     self.flush("cap")
                 return (None, None)
             dim = self._dim_of(function, x)
-            if not self._arena(self.n_vcpu_classes, dim).resident:
+            if not self._arenas_of(dim)[0].resident:
                 if function in self._pending_fns:
                     self.flush("own")
                 elif len(self._pending) >= 256:
@@ -938,14 +963,12 @@ class ArenaEngine:
     def predicted_costs(self, function: str, x: np.ndarray):
         """Full cost vectors (vcpu, mem) — diagnostics path."""
         self.flush()
-        dim = self._dim_of(function, x)
-        va = self._arena(self.n_vcpu_classes, dim)
-        ma = self._arena(self.n_mem_classes, dim)
+        ars = self._arenas_of(self._dim_of(function, x))
         xb = np.concatenate([np.asarray(x, F32), np.ones(1, F32)])
-        if va.resident:
-            vc, mc = _predict_resident([(va, [function], xb[None], None),
-                                        (ma, [function], xb[None], None)])
-            return vc[0], mc[0]
+        if ars[0].resident:
+            (c,) = _predict_resident([(ars[0], [function], xb[None], None)])
+            return c[0, :self.n_vcpu_classes], c[0, self.n_vcpu_classes:]
+        va, ma = ars
         return (_matvec_exact(va.w[va.slot(function)], xb),
                 _matvec_exact(ma.w[ma.slot(function)], xb))
 
@@ -953,7 +976,9 @@ class ArenaEngine:
     def weights(self, function: str):
         """(vcpu_w, vcpu_g2, mem_w, mem_g2) copies for tests; flushes."""
         self.flush()
-        dim = self._dims[function]
-        va = self._arena(self.n_vcpu_classes, dim)
-        ma = self._arena(self.n_mem_classes, dim)
-        return va.row(function) + ma.row(function)
+        ars = self._arenas_of(self._dims[function])
+        if not ars[0].resident:
+            return ars[0].row(function) + ars[1].row(function)
+        w, g2 = ars[0].row(function)
+        nv = self.n_vcpu_classes
+        return w[:nv], g2[:nv], w[nv:], g2[nv:]

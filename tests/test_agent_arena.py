@@ -7,8 +7,10 @@ after slot release/reuse, the flush ordering rule (updates for F apply
 before any predict for F), batched-vs-scalar cost vectors, the
 calibrated NumPy backend and the vmapped JAX fallback, same-timestamp
 arrival microbatching in the simulator, cloned functions whose resident
-agents span two device blocks through the simulator, the retry-payload
-featurization cache, and the legacy-engine golden pin."""
+agents span two device blocks through the simulator, the resident
+arena's stacked rows (one slot for a function's vCPU and memory agents,
+each side served only where wanted), the retry-payload featurization
+cache, and the legacy-engine golden pin."""
 
 import json
 import os
@@ -126,7 +128,7 @@ def test_growth_across_doubling_boundary():
         assert arena.allocate(f, xs[f]) == legacy.allocate(f, xs[f])
         _assert_same_weights(arena, legacy, f)
     eng = arena._arena
-    va = eng._arena(arena.n_vcpu_classes, 3)
+    va, _ = eng._arenas_of(3)  # the vCPU and memory host arenas
     assert va.capacity >= 11 and va.capacity % 4 == 0
     assert len({va.slot(f) for f in fns}) == len(fns)
 
@@ -145,7 +147,8 @@ def test_slot_release_and_reuse_isolation():
             al.feedback("bystander", xb, obs)
     before = arena._arena.weights("bystander")
     eng = arena._arena
-    slot_a = eng._arena(arena.n_vcpu_classes, 3).slot("a")
+    va, _ = eng._arenas_of(3)
+    slot_a = va.slot("a")
     arena.release("a")
     legacy.release("a")
     assert arena.agent_updates("a") == (0, 0) == legacy.agent_updates("a")
@@ -153,7 +156,7 @@ def test_slot_release_and_reuse_isolation():
     obs = _rand_obs(rng)
     arena.feedback("fresh", xa, obs)
     legacy.feedback("fresh", xa, obs)
-    assert eng._arena(arena.n_vcpu_classes, 3).slot("fresh") == slot_a
+    assert va.slot("fresh") == slot_a
     # ...and behaves exactly like a from-scratch agent
     assert arena.allocate("fresh", xa) == legacy.allocate("fresh", xa)
     _assert_same_weights(arena, legacy, "fresh")
@@ -360,8 +363,9 @@ def test_resident_engine_matches_legacy_past_one_block(resident, dim):
     """More than 16 functions of one dim grow the resident state into a
     second block; served classes and weights stay bit-identical."""
     _, arena, legacy, fns = _resident_stream(dim, seed=100 + dim)
-    va = arena._arena._arena(arena.n_vcpu_classes, dim)
-    assert va.resident and len(va.blocks) == 2
+    (ar,) = arena._arena._arenas_of(dim)  # both agents, stacked
+    assert ar.resident and len(ar.blocks) == 2
+    assert ar.n_classes == arena.n_vcpu_classes + arena.n_mem_classes
     for f in fns:
         _assert_same_weights(arena, legacy, f)
 
@@ -378,14 +382,14 @@ def test_resident_release_and_reuse_starts_fresh(resident, dim):
             al.feedback("bystander", -xa, obs)
     before = arena._arena.weights("bystander")
     eng = arena._arena
-    slot_a = eng._arena(arena.n_vcpu_classes, dim).slot("a")
+    (ar,) = eng._arenas_of(dim)
+    slot_a = ar.slot("a")
     arena.release("a")
     legacy.release("a")
     eng._dim_of("fresh", xa)
-    assert eng._arena(arena.n_vcpu_classes, dim).slot("fresh") == slot_a
-    for row in eng._arena(arena.n_vcpu_classes, dim).row("fresh") + \
-            eng._arena(arena.n_mem_classes, dim).row("fresh"):
-        assert not row.any()  # a fresh zero agent
+    assert ar.slot("fresh") == slot_a
+    for row in ar.row("fresh"):
+        assert not row.any()  # a fresh zero agent, both halves
     obs = _rand_obs(rng)
     arena.feedback("fresh", xa, obs)
     legacy.feedback("fresh", xa, obs)
@@ -404,8 +408,7 @@ def test_resident_pass_leaves_untouched_rows_bit_identical(resident, dim):
     _, arena, _, fns = _resident_stream(dim, seed=300 + dim, n_ops=20)
     eng = arena._arena
     eng.flush()
-    arenas = [eng._arena(n, dim) for n in (arena.n_vcpu_classes,
-                                           arena.n_mem_classes)]
+    arenas = eng._arenas_of(dim)
     before = [jax.device_get(ar.blocks) for ar in arenas]
     rng = np.random.default_rng(dim)
     touched = [fns[0], fns[7], fns[agent_arena._MAX_BUCKET + 1]]
@@ -455,6 +458,75 @@ def test_resident_kernels_are_looked_up_at_call_time(resident, monkeypatch,
     patched, *_ = _resident_stream(dim, seed=500 + dim, n_ops=40,
                                    check=False)
     assert patched != served
+
+
+@pytest.mark.parametrize("dim", RESIDENT_DIMS)
+def test_stacked_resident_engine_matches_legacy_per_wanted_side(resident,
+                                                                 dim):
+    """Cohorts over two blocks whose items want the vCPU class only, the
+    memory class only or both, each repeating a function, between
+    feedbacks: the stacked rows serve each wanted side as the legacy
+    agent does, nothing for a side not wanted, and keep its weights."""
+    rng = np.random.default_rng(600 + dim)
+    arena, legacy = _pair(vcpu_confidence=1, mem_confidence=1)
+    eng = arena._arena
+    fns = [f"s{i}" for i in range(agent_arena._MAX_BUCKET + 3)]
+
+    def feed(f):
+        x = rng.standard_normal(dim).astype(np.float32)
+        obs = _rand_obs(rng)
+        arena.feedback(f, x, obs)
+        legacy.feedback(f, x, obs)
+
+    for f in fns:
+        feed(f)
+    sides = [(True, False), (False, True), (True, True)]
+    seen = set()
+    for _ in range(12):
+        picks = [fns[i] for i in rng.integers(len(fns), size=6)]
+        picks[-1] = picks[0]
+        items = [(f, rng.standard_normal(dim).astype(np.float32),
+                  *sides[int(rng.integers(3))]) for f in picks]
+        seen |= {(v, m) for _, _, v, m in items}
+        ag = legacy._agents
+        assert eng.predict_batch(items) == [
+            (ag[f].vcpu.predict(x) if v else None,
+             ag[f].mem.predict(x) if m else None) for f, x, v, m in items]
+        for f in picks[:2]:
+            feed(f)
+    assert seen == set(sides)
+    for f in fns:
+        _assert_same_weights(arena, legacy, f)
+
+
+@pytest.mark.parametrize("dim", RESIDENT_DIMS)
+def test_stacked_row_one_slot_and_release_zeroes_both_halves(resident, dim):
+    """One slot holds a function's vCPU and memory agents side by side
+    on the class axis; release zeroes the whole row and no other."""
+    rng = np.random.default_rng(700 + dim)
+    arena, _ = _pair(vcpu_confidence=1, mem_confidence=1)
+    eng = arena._arena
+    for f in ("a", "b"):
+        for _ in range(2):
+            eng.enqueue_update(f, rng.standard_normal(dim).astype(np.float32),
+                               _rand_obs(rng))
+    vw, vg, mw, mg = eng.weights("a")
+    (ar,) = eng._arenas_of(dim)
+    assert list(eng._arenas.values()) == [ar]
+    nv, nm = eng.n_vcpu_classes, eng.n_mem_classes
+    assert vw.shape == vg.shape == (nv, dim + 1)
+    assert mw.shape == mg.shape == (nm, dim + 1)
+    assert all(a.any() for a in (vw, vg, mw, mg))  # both agents learned
+    w, g2 = ar.row("a")
+    assert np.array_equal(w, np.concatenate([vw, mw]))
+    assert np.array_equal(g2, np.concatenate([vg, mg]))
+    b, r = divmod(ar.slot("a"), agent_arena._MAX_BUCKET)
+    bystander = ar.row("b")
+    eng.release("a")
+    for a in ar.blocks[b]:
+        assert not np.asarray(a[r]).any()
+    for old, new in zip(bystander, ar.row("b")):
+        assert np.array_equal(old, new)
 
 
 def test_arena_growth_preserves_weights():

@@ -1,11 +1,16 @@
 """The agent arena's spans and counters with the TPU v5e's backend table
 forced (no NumPy path, so every dimension keeps its state resident on
-the device): every device dispatch is a masked 16-row block kernel,
-counted once under its kernel and feature dim with the real rows it
-carries; a flush pass copies in once and launches once per arena and
-touched block, reading nothing back; a predict call copies in once,
-launches once per wanted arena and touched block and reads once; and
-tracing changes no decision and no weight."""
+the device, one arena per dimension whose rows stack a function's vCPU
+and memory agents): every device dispatch is a masked 16-row block
+kernel, counted once under its kernel and feature dim with the real rows
+it carries; a flush pass copies in once, an input and a cost block per
+dimension and touched block, launches once per dimension and touched
+block and reads nothing back; a predict call copies in once, launches
+once per dimension and touched block, whichever sides its items want,
+and reads once, a cost block per launch; ``arena.predict_want/*`` count
+the items a stacked predict serves; the arena's copy, launch and own
+host seconds still make up its outermost seconds; and tracing changes no
+decision and no weight."""
 
 import collections
 
@@ -164,33 +169,59 @@ def test_predicted_costs_dispatch_without_argmin(v5e):
     assert mc.shape == (alloc._arena.n_mem_classes,)
     snap = spans.snapshot()
     counters = snap["counters"]
-    assert counters["arena.dispatch/batched_predict/2"] == 2
-    assert counters["arena.dispatch/batched_update/2"] == 2
-    assert counters["arena.dispatch_rows"] == 4
+    assert counters["arena.dispatch/batched_predict/2"] == 1
+    assert counters["arena.dispatch/batched_update/2"] == 1
+    assert counters["arena.dispatch_rows"] == 2
     assert not any(k.startswith("arena.dispatch/argmin") for k in counters)
     assert not any("csc_" in k for k in counters)
     assert snap["spans"]["arena.d2h"]["calls"] == 1
 
 
-@pytest.mark.parametrize("want", [(True, True), (True, False), (False, True)])
-def test_predict_launches_once_per_wanted_arena_block(v5e, want):
-    """A cohort spanning two blocks of one dim: one launch per wanted
-    arena and touched block, one copy-in (the arenas share the input
-    block) and exactly one read for the whole call."""
+WANTS = {(True, True): "both", (True, False): "vcpu", (False, True): "mem"}
+
+
+@pytest.fixture
+def transfers(monkeypatch):
+    """The arrays of each copy-in and of each read, call by call."""
+    import jax
+
+    seen = {"in": [], "out": []}
+
+    def counted(key, fn):
+        def wrapped(x, *args, **kwargs):
+            seen[key].append(len(x) if isinstance(x, list) else 1)
+            return fn(x, *args, **kwargs)
+        return wrapped
+
+    monkeypatch.setattr(jax, "device_put", counted("in", jax.device_put))
+    monkeypatch.setattr(jax, "device_get", counted("out", jax.device_get))
+    return seen
+
+
+@pytest.mark.parametrize("want", list(WANTS))
+def test_predict_launches_once_per_wanted_arena_block(v5e, transfers, want):
+    """A cohort spanning two blocks of one dim: one launch per touched
+    block of the dim's stacked arena, whichever sides the items want,
+    one copy-in of an input block per launch and exactly one read for
+    the whole call, carrying one cost block per launch."""
     eng, xs = _engine(BLOCK + 4)
     v5e.clear()
+    transfers["in"].clear()
     spans.enable()
     items = [(f, x, *want) for f, x in xs.items()]
     picks = eng.predict_batch(items)
     snap = spans.snapshot()
     assert all((v is not None) == want[0] and (m is not None) == want[1]
                for v, m in picks)
-    assert dict(_dispatches(snap["counters"])) == {
-        ("batched_predict", 2): 2 * sum(want)}
-    assert v5e == {("batched_predict", 2): 2 * sum(want)}
-    assert snap["counters"]["arena.dispatch_rows"] == len(items) * sum(want)
+    assert dict(_dispatches(snap["counters"])) == {("batched_predict", 2): 2}
+    assert v5e == {("batched_predict", 2): 2}
+    assert snap["counters"]["arena.dispatch_rows"] == len(items)
     assert snap["spans"]["arena.h2d"]["calls"] == 1
     assert snap["spans"]["arena.d2h"]["calls"] == 1
+    assert transfers == {"in": [2], "out": [2]}
+    assert {k: n for k, n in snap["counters"].items()
+            if k.startswith("arena.predict_want/")} == {
+        "arena.predict_want/" + WANTS[want]: len(items)}
     assert "arena.flush" not in snap["spans"]  # nothing was pending
 
 
@@ -201,19 +232,22 @@ def test_predict_splits_a_repeated_function_into_rounds(v5e):
     v5e.clear()
     spans.enable()
     (f, x), (g, y) = list(xs.items())[:2]
-    picks = eng.predict_batch([(f, x, True, True), (g, y, True, True),
-                               (f, -x, True, True)])
+    picks = eng.predict_batch([(f, x, True, True), (g, y, True, False),
+                               (f, -x, False, True)])
     snap = spans.snapshot()
-    assert v5e == {("batched_predict", 2): 4}
-    assert snap["counters"]["arena.dispatch_rows"] == 6
+    assert v5e == {("batched_predict", 2): 2}
+    assert snap["counters"]["arena.dispatch_rows"] == 3
     assert snap["spans"]["arena.d2h"]["calls"] == 1
     assert picks[0] == eng.predict_batch([(f, x, True, True)])[0]
-    assert picks[2] == eng.predict_batch([(f, -x, True, True)])[0]
+    assert picks[1] == eng.predict_batch([(g, y, True, False)])[0]
+    assert picks[2] == eng.predict_batch([(f, -x, False, True)])[0]
 
 
-def test_flush_pass_launches_once_per_arena_block_and_reads_nothing(v5e):
+def test_flush_pass_launches_once_per_arena_block_and_reads_nothing(
+        v5e, transfers):
     """A pass over 20 functions of dim 2 (two blocks) and one of dim 5:
-    one launch per arena and touched block, one copy-in, no read."""
+    one launch per dim and touched block, one copy-in of an input and a
+    stacked cost block per launch, no read."""
     eng, xs = _engine(BLOCK + 4)
     rng = np.random.default_rng(5)
     for f, x in xs.items():
@@ -221,13 +255,15 @@ def test_flush_pass_launches_once_per_arena_block_and_reads_nothing(v5e):
     eng.enqueue_update("s5", rng.standard_normal(5).astype(np.float32),
                        _obs(rng))
     v5e.clear()
+    transfers["in"].clear()
     spans.enable()
     eng.flush()
     snap = spans.snapshot()
-    assert v5e == {("batched_update", 2): 4, ("batched_update", 5): 2}
+    assert v5e == {("batched_update", 2): 2, ("batched_update", 5): 1}
     assert snap["counters"]["arena.flush_pass"] == 1
-    assert snap["counters"]["arena.dispatch_rows"] == 2 * (len(xs) + 1)
+    assert snap["counters"]["arena.dispatch_rows"] == len(xs) + 1
     assert snap["spans"]["arena.h2d"]["calls"] == 1
+    assert transfers == {"in": [2 * 3], "out": []}
     assert "arena.d2h" not in snap["spans"]
 
 
@@ -264,9 +300,10 @@ THREE_BLOCKS = 2 * BLOCK + 8
 @pytest.mark.parametrize("call", ["flush", "predict_vcpu", "predict_both"])
 def test_dispatch_counters_count_the_blocks_staged(v5e, monkeypatch, call):
     """Functions of one dim over three blocks: one flush pass, or one
-    predict call, stages one block per arena and touched block, copies
-    them in once and launches once on each, so the dispatch counters
-    count the blocks a call touches."""
+    predict call, stages one block per touched block of the dim's
+    stacked arena, whichever sides it wants, copies them in once and
+    launches once on each, so the dispatch counters count the blocks a
+    call touches."""
     eng, xs = _engine(THREE_BLOCKS)
     rng = np.random.default_rng(7)
     for f, x in xs.items():
@@ -278,12 +315,11 @@ def test_dispatch_counters_count_the_blocks_staged(v5e, monkeypatch, call):
     spans.enable()
     if call == "flush":
         eng.flush()
-        arenas = 2
     else:
-        arenas = 1 if call == "predict_vcpu" else 2
-        eng.predict_batch([(f, x, True, arenas == 2) for f, x in xs.items()])
+        eng.predict_batch([(f, x, True, call == "predict_both")
+                           for f, x in xs.items()])
     snap = spans.snapshot()
-    assert staged == [3 * arenas]
+    assert staged == [3]
     assert snap["spans"]["arena.h2d"]["calls"] == 1
     assert sum(n for k, n in snap["counters"].items()
                if k.startswith("arena.dispatch/")) == sum(staged)
@@ -295,7 +331,7 @@ def test_block_grow_counts_the_blocks_appended(v5e):
     spans.enable()
     eng, xs = _engine(THREE_BLOCKS)
     grown = sum(len(ar.blocks) for ar in eng._arenas.values())
-    assert grown == 2 * 3
+    assert grown == 3
     assert spans.snapshot()["counters"]["arena.block_grow"] == grown
     # a known function, or a slot freed and taken again, grows nothing
     eng.release("h0")
@@ -309,5 +345,44 @@ def test_block_grow_counts_the_blocks_appended(v5e):
 def test_block_counters_stay_silent_with_spans_off(v5e):
     eng, xs = _engine(THREE_BLOCKS)
     eng.predict_batch([(f, x, True, True) for f, x in xs.items()])
-    assert sum(len(ar.blocks) for ar in eng._arenas.values()) == 2 * 3
+    assert sum(len(ar.blocks) for ar in eng._arenas.values()) == 3
     assert spans.snapshot()["counters"] == {}
+
+
+ARENA_CALLS = ("arena.predict", "arena.predict_batch", "arena.flush",
+               "arena.enqueue_update")
+
+
+def test_predict_wants_count_the_items_and_shares_partition_the_arena(
+        v5e, monkeypatch):
+    """Over a random stream: the ``arena.predict_want/*`` counters sum
+    to the items that want a class, one launch serves both sides of
+    some, and the arena's copy, launch and own host seconds make up
+    the seconds inside its outermost public calls, as before."""
+    predicted = [0]
+    orig = agent_arena.ArenaEngine.predict_batch
+
+    def predict_batch(self, items):
+        predicted[0] += sum(bool(v or m) for _, _, v, m in items)
+        return orig(self, items)
+
+    monkeypatch.setattr(agent_arena.ArenaEngine, "predict_batch",
+                        predict_batch)
+    spans.enable()
+    _stream()
+    snap = spans.snapshot()
+    wants = {k.rsplit("/", 1)[1]: n for k, n in snap["counters"].items()
+             if k.startswith("arena.predict_want/")}
+    # memory's threshold is the higher here, so no item wants it alone
+    assert set(wants) == {"both", "vcpu"}
+    assert sum(wants.values()) == predicted[0] > 0
+    recs = snap["records"]
+    outer = 1e-9 * sum(
+        t1 - t0 for name, t0, t1, parent, _ in recs
+        if name in ARENA_CALLS
+        and (parent is None or recs[parent][0] not in ARENA_CALLS))
+    sp = snap["spans"]
+    parts = (sum(sp[n]["self_seconds"] for n in ARENA_CALLS if n in sp)
+             + sum(sp[n]["seconds"] for n in ("arena.h2d", "arena.d2h",
+                                              "arena.launch")))
+    assert parts == pytest.approx(outer, rel=1e-6)

@@ -3,8 +3,10 @@
 Nothing runs: the chip is described, not attached, and its compiler
 compiles each kernel at the shapes the simulator dispatches — 32 vCPU
 and 40 memory classes, the feature dims of the 12 functions (1, 2, 3,
-5, 6), the resident arena's 16-row block and the 1-row bucket. Every
-program must stay in f32: no bf16 anywhere in the compiled text.
+5, 6), the resident arena's 16-row block and the 1-row bucket — and the
+block kernels at the resident block whose rows stack both agents' 72
+classes. Every program must stay in f32: no bf16 anywhere in the
+compiled text.
 
 The topology is described inside a module-scoped fixture, never while
 a module is imported, and the persistent compile cache is off around
@@ -80,4 +82,14 @@ def test_arena_kernel_compiles_for_v5e_in_f32(one_chip, kernel, n, dim,
     compiled = _lower(kernel, n, dim, bucket, one_chip).compile()
     text = compiled.as_text()
     assert "f32[" in text
+    assert "bf16" not in text, f"{kernel} lowers to bf16 on v5e"
+
+
+@pytest.mark.parametrize("dim", [1, 2, 3, 5, 6])
+@pytest.mark.parametrize("kernel", ["_batched_update", "_batched_predict"])
+def test_stacked_block_kernel_compiles_for_v5e_in_f32(one_chip, kernel, dim):
+    """The block every resident dispatch has: 16 rows of a function's
+    32 vCPU and 40 memory classes, stacked."""
+    text = _lower(kernel, 32 + 40, dim, 16, one_chip).compile().as_text()
+    assert "f32[16,72," in text
     assert "bf16" not in text, f"{kernel} lowers to bf16 on v5e"
