@@ -1,17 +1,13 @@
-"""Acquire-on-placement + admission-control sweep.
+"""Admission-control sweep.
 
-Four resource-lifecycle/admission modes on the saturating scenarios
-(oversubscribe, flash-crowd, multi-cluster) plus the well-provisioned
-poisson-steady control, all behind a 2-cluster spill-over front door on
-the same total worker footprint:
+Five admission modes on the saturating scenarios (oversubscribe,
+flash-crowd, multi-cluster) plus the well-provisioned poisson-steady
+control, all behind a 2-cluster spill-over front door on the same total
+worker footprint. Every mode reserves capacity at placement:
 
-* ``legacy``        — acquire-on-START (pre-reservation accounting): a
-  cold-started container holds no load until warm, so arrivals inside
-  the warm-up window see a free-looking worker and stack cold starts
-  onto it (the Fifer over-commitment failure mode);
-* ``reserve``       — acquire-on-PLACEMENT (the default): placed cold
-  starts reserve capacity immediately, so ``Worker.fits`` and
-  ``Router._load`` are truthful about committed-but-warming load;
+* ``reserve``       — no admission control: placed cold starts reserve
+  capacity immediately, so ``Worker.fits`` and ``Router._load`` are
+  truthful about committed-but-warming load;
 * ``reserve+shed``  — reservation plus front-door shedding when every
   cluster's committed load exceeds the admission headroom;
 * ``reserve+queue`` — reservation plus front-door queueing under the
@@ -21,12 +17,8 @@ the same total worker footprint:
   estimate (per-input when calibrated) already exceeds their remaining
   SLO budget, instead of shedding on load alone.
 
-The headline A/Bs (also CI gates, like sim_bench's retry check):
+The headline A/B (also a CI gate):
 
-* truthful reservation accounting must not stack cold starts — p99
-  cold-start queueing on ``oversubscribe`` must not be worse than
-  legacy's — and must stay SLO-neutral on the uncontended
-  ``poisson-steady`` control;
 * SLO-native admission must DOMINATE load-headroom shedding on at
   least one saturating cell — no more violations from no more sheds
   (it drops only work that was doomed anyway) — and must stay neutral
@@ -57,7 +49,7 @@ HEADROOM = 0.95
 
 # deeply saturating shapes (the admission regime — fleet-wide overload,
 # unlike router_bench's hot-cluster-only loads) + a well-provisioned
-# poisson-steady control where reservation accounting must be neutral.
+# poisson-steady control where admission must be neutral.
 # Each entry: (scenario params, rps scale) — the control runs at half
 # the offered load so it genuinely has headroom.
 SCENARIOS = {
@@ -75,7 +67,6 @@ SCENARIOS = {
 MATCH_HEADROOM = 0.90
 
 MODES = (
-    ("legacy", dict(legacy_acquire=True)),
     ("reserve", dict()),
     ("reserve+shed", dict(admission="shed", admission_headroom=HEADROOM)),
     ("reserve+shed@match", dict(admission="shed",
@@ -166,35 +157,7 @@ def run() -> None:
                 f"|admission_queue_events={router.admission_queue_events}",
             )
 
-    # headline deltas: what acquire-on-placement buys over acquire-on-start
-    for scenario in SCENARIOS:
-        legacy, reserve = cells[(scenario, "legacy")], cells[(scenario, "reserve")]
-        emit(
-            f"admission_bench.{scenario}.reserve_delta",
-            0.0,
-            f"slo_viol_pts={reserve['slo_violation_pct'] - legacy['slo_violation_pct']:+.2f}"
-            f"|cold_queue_p99_delta_s="
-            f"{reserve['cold_queue_p99_s'] - legacy['cold_queue_p99_s']:+.3f}"
-            f"|wasted_vcpus_p95_delta="
-            f"{reserve['wasted_vcpus_p95'] - legacy['wasted_vcpus_p95']:+.2f}",
-        )
-
-    # CI gates for the tentpole semantics (mirrors sim_bench's retry gate)
-    over_legacy = cells[("oversubscribe", "legacy")]
-    over_reserve = cells[("oversubscribe", "reserve")]
-    if over_reserve["cold_queue_p99_s"] > over_legacy["cold_queue_p99_s"] + 1e-9:
-        raise RuntimeError(
-            "acquire-on-placement stacked cold starts worse than legacy on "
-            f"oversubscribe: p99 cold queueing {over_reserve['cold_queue_p99_s']:.3f}s "
-            f"> {over_legacy['cold_queue_p99_s']:.3f}s")
-    steady_legacy = cells[("poisson-steady", "legacy")]
     steady_reserve = cells[("poisson-steady", "reserve")]
-    if (steady_reserve["slo_violation_pct"]
-            > steady_legacy["slo_violation_pct"] + 0.5):
-        raise RuntimeError(
-            "acquire-on-placement raised SLO violations on the "
-            f"poisson-steady control: {steady_reserve['slo_violation_pct']:.2f}% "
-            f"> {steady_legacy['slo_violation_pct']:.2f}%")
 
     # CI gates for SLO-native admission. Dominance: on at least one
     # saturating cell, reserve+slo must beat the matched-shed-rate

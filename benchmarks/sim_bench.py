@@ -1,47 +1,26 @@
 """Simulator-core throughput: events/sec on a 10k-invocation trace.
 
-Three A/Bs, each against a pre-fix path kept behind a config switch:
+* ``incremental`` — the simulator core on heavy-tail-inputs under
+  memory-centric scheduling (vCPU oversubscription), which holds
+  hundreds of invocations running concurrently; its events/sec floor
+  rides benchmarks/baselines.json.
 
-* ``legacy_scans`` — the incremental simulator core (per-worker
-  contention aggregates + per-function warm-container index) vs the
-  O(running)/O(containers) scans. Both runs must produce identical
-  ``summarize()`` metrics — the refactor is a pure fast path. The trace
-  is heavy-tail-inputs under memory-centric scheduling (vCPU
-  oversubscription), which holds hundreds of invocations running
-  concurrently.
-
-* ``legacy_retry_alloc`` — the cached-retry-allocation fix vs the
-  pre-fix retry path that re-ran ``policy.allocate`` (a jit'd jax
-  dispatch per predict for learning policies) on every 0.5 s retry of a
-  queued invocation. Measured on the oversubscribe scenario, whose
-  retry storm is where the per-retry dispatch dominated. For
-  deterministic-allocation policies the fix is metric-neutral even
-  under saturation (same allocation on every retry), which the bench
-  asserts with static-large; for learning policies only QUEUED
-  invocations can change (they now keep their first prediction). Note
-  the legacy leg re-runs only the PREDICT per retry — the featurized
-  input rides the retry payload either way — so the ratio isolates the
-  dispatch cost the fix removed.
+* ``image_cache_on`` — the same trace with
+  ``SimConfig(image_cache=ImageCacheSpec())``, so the per-node layer
+  cache's per-cold-start overhead has its own floor next to
+  ``incremental`` (the cache-off default path).
 
 * allocator engine — the batched agent arena
   (``ResourceAllocator(engine="arena")``, see repro.core.agent_arena)
   vs the per-function-object path (``engine="legacy"``: two jit'd JAX
   dispatches per allocate and two per feedback) with the SHABARI
-  policy on the same heavy-tail trace as the scans A/B. This is the
-  learning-path throughput gate: the arena must be ≥3x events/sec AND
-  bit-identical in summary metrics (enforced here, not just printed).
+  policy on the same trace. This is the learning-path throughput
+  gate: the arena must be ≥3x events/sec AND bit-identical in summary
+  metrics (enforced here, not just printed).
 
-Plus the ``image_cache_on`` cell — the scans-A/B trace re-run with
-``SimConfig(image_cache=ImageCacheSpec())`` so the per-node layer
-cache's per-cold-start overhead has its own events/sec floor next to
-the ``incremental`` cell's (the cache-off default path) — and the
-``scale`` tier (run_stack_ab + run_scale): a full-stack A/B —
-array-backed event loop + indexed scans + agent arena vs
-``legacy_event_loop`` + ``legacy_scans`` + the legacy engine, hard-
-failing on any summary-metric difference — and the azure-24h cell, one
-production day at Azure-trace scale (~100k invocations under
-BENCH_QUICK=1, 1M otherwise) whose events/sec floor rides
-benchmarks/baselines.json.
+* ``scale`` — the azure-24h cell, one production day at Azure-trace
+  scale (~100k invocations under BENCH_QUICK=1, 1M otherwise) whose
+  events/sec floor rides benchmarks/baselines.json.
 
   PYTHONPATH=src python -m benchmarks.sim_bench
 """
@@ -64,13 +43,12 @@ SCENARIO = "heavy-tail-inputs"
 POLICY = "static-large"
 
 
-def _run_once(trace, profiles, pool, slo_table, *, legacy: bool,
-              policy: str = POLICY):
+def _run_once(trace, profiles, pool, slo_table, *, policy: str = POLICY):
     # uncapped worker resources: every invocation is admitted, so the
     # event count is pure start/finish work and the running set grows to
-    # the hundreds (retry storms would otherwise dominate both sides)
+    # the hundreds (retry storms would otherwise dominate)
     cfg = SimConfig(seed=0, vcpu_limit=100_000,
-                    mem_mb_per_worker=4_000_000, legacy_scans=legacy)
+                    mem_mb_per_worker=4_000_000)
     pol = make_policy(policy, profiles, pool, slo_table, seed=0)
     sim = Simulator(policy=pol, profiles=profiles, input_pool=pool,
                     slo_table=slo_table, cfg=cfg)
@@ -83,7 +61,7 @@ def _run_once(trace, profiles, pool, slo_table, *, legacy: bool,
 # ------------------------------------------------------- image-cache cell
 def run_cache_cell(trace, profiles, pool, slo_table) -> None:
     """events/sec with the per-node image/layer cache ENABLED on the
-    same uncapped heavy-tail cell as the scans A/B (floor rides
+    same uncapped heavy-tail cell as ``incremental`` (floor rides
     benchmarks/baselines.json). The cache adds per-cold-start work —
     a residual-pull rank across the walk plus the pull bookkeeping —
     so this cell prices that overhead next to ``sim_bench.incremental``
@@ -109,11 +87,11 @@ def run_cache_cell(trace, profiles, pool, slo_table) -> None:
 def run_engine_ab(trace, profiles, pool, slo_table) -> None:
     """Shabari (learning) policy: agent arena vs per-object agents.
 
-    Hard gates, mirroring the scans A/B's metrics_identical check:
-    summary metrics must be BIT-identical (the arena is a pure fast
-    path — its NumPy backend is calibrated against the jit kernels and
-    its flush ordering reproduces the sequential update/predict
-    interleaving), and the arena must clear 3x events/sec."""
+    Hard gates: summary metrics must be BIT-identical (the arena is a
+    pure fast path — its NumPy backend is calibrated against the jit
+    kernels and its flush ordering reproduces the sequential
+    update/predict interleaving), and the arena must clear 3x
+    events/sec."""
     # throwaway warm-up: run the arena's one-time backend calibration
     # (NumPy-vs-JAX bit-identity proofs + crossover benchmark, which
     # trace XLA programs) and the legacy jit kernels outside both timed
@@ -122,16 +100,14 @@ def run_engine_ab(trace, profiles, pool, slo_table) -> None:
 
     agent_arena.calibrate(range(1, 7))
     warm = trace[: max(len(trace) // 10, 1)]
-    _run_once(warm, profiles, pool, slo_table, legacy=False,
-              policy="shabari")
-    _run_once(warm, profiles, pool, slo_table, legacy=False,
+    _run_once(warm, profiles, pool, slo_table, policy="shabari")
+    _run_once(warm, profiles, pool, slo_table,
               policy="shabari-legacy-engine")
 
     ev_l, wall_l, sum_l = _run_once(
-        trace, profiles, pool, slo_table, legacy=False,
-        policy="shabari-legacy-engine")
+        trace, profiles, pool, slo_table, policy="shabari-legacy-engine")
     ev_a, wall_a, sum_a = _run_once(
-        trace, profiles, pool, slo_table, legacy=False, policy="shabari")
+        trace, profiles, pool, slo_table, policy="shabari")
     eps_l = ev_l / wall_l
     eps_a = ev_a / wall_a
     emit("sim_bench.shabari_legacy_engine", wall_l / ev_l * 1e6,
@@ -148,72 +124,6 @@ def run_engine_ab(trace, profiles, pool, slo_table) -> None:
         raise RuntimeError(
             "agent arena below the 3x events/sec target: "
             f"{eps_a:.0f} vs legacy {eps_l:.0f}")
-
-
-# --------------------------------------------------------- retry-path A/B
-RETRY_RPS = 1.5 if QUICK else 2.0
-RETRY_DURATION_S = 120.0 if QUICK else 240.0
-
-
-def _run_retry(trace, profiles, pool, slo_table, *, policy: str, legacy: bool):
-    # a small saturating cluster: the oversubscribe backlog retries every
-    # 0.5 s, so the retry path dominates event count
-    cfg = SimConfig(n_workers=4, vcpus_per_worker=32, physical_cores=32,
-                    mem_mb_per_worker=16 * 1024, vcpu_limit=32,
-                    retry_interval_s=0.5, queue_timeout_s=60.0, seed=0,
-                    legacy_retry_alloc=legacy)
-    pol = make_policy(policy, profiles, pool, slo_table, seed=0)
-    sim = Simulator(policy=pol, profiles=profiles, input_pool=pool,
-                    slo_table=slo_table, cfg=cfg)
-    t0 = time.perf_counter()
-    results = sim.run(trace)
-    wall = time.perf_counter() - t0
-    return sim.events_processed, wall, summarize(results)
-
-
-def run_retry_ab(profiles, pool, slo_table) -> None:
-    spec = ScenarioSpec(scenario="oversubscribe", rps=RETRY_RPS,
-                        duration_s=RETRY_DURATION_S, seed=0,
-                        params={"load_mult": 4.0})
-    trace = generate_scenario(
-        spec, functions=sorted(profiles),
-        inputs_per_function={f: len(pool[f]) for f in profiles},
-    )
-
-    # throwaway warm-up: trace shabari's jit kernels (predict/update per
-    # feature-dim shape) so the one-time compiles are charged to neither
-    # timed leg below
-    _run_retry(trace[: max(len(trace) // 4, 1)], profiles, pool, slo_table,
-               policy="shabari", legacy=False)
-
-    # the events/sec win: shabari's jit'd predict no longer runs per retry
-    ev_legacy, wall_legacy, _ = _run_retry(
-        trace, profiles, pool, slo_table, policy="shabari", legacy=True)
-    ev_fast, wall_fast, _ = _run_retry(
-        trace, profiles, pool, slo_table, policy="shabari", legacy=False)
-    eps_legacy = ev_legacy / wall_legacy
-    eps_fast = ev_fast / wall_fast
-    emit("sim_bench.retry_legacy", wall_legacy / ev_legacy * 1e6,
-         f"n={len(trace)}|events={ev_legacy}|events_per_sec={eps_legacy:.0f}")
-    emit("sim_bench.retry_cached", wall_fast / ev_fast * 1e6,
-         f"n={len(trace)}|events={ev_fast}|events_per_sec={eps_fast:.0f}")
-
-    # metric neutrality: with a deterministic allocation the cached and
-    # re-predicted retry paths are the same decision sequence, queued
-    # and timed-out invocations included
-    _, _, sum_legacy = _run_retry(
-        trace, profiles, pool, slo_table, policy="static-large", legacy=True)
-    _, _, sum_fast = _run_retry(
-        trace, profiles, pool, slo_table, policy="static-large", legacy=False)
-    emit("sim_bench.retry_speedup", 0.0,
-         f"x{eps_fast / eps_legacy:.2f}"
-         f"|static_metrics_identical={sum_fast == sum_legacy}")
-    if sum_fast != sum_legacy:
-        # this is the CI gate for the cached-retry fast path, not just
-        # a printed observation
-        raise RuntimeError(
-            "retry-allocation cache changed metrics for a deterministic "
-            f"policy: {sum_fast} != {sum_legacy}")
 
 
 # ------------------------------------------------------------- scale tier
@@ -257,58 +167,6 @@ def run_scale(profiles, pool, slo_table) -> None:
          f"|trace_build_s={build_wall:.2f}|timeouts={timeouts}")
 
 
-def _run_stack(trace, profiles, pool, slo_table, *, legacy: bool):
-    """One leg of the full-stack A/B: the fast stack (array-backed
-    event loop + indexed scans + agent arena) or the whole legacy stack
-    (global heapq loop + O(running)/O(containers) scans + per-object
-    agent engine). Same uncapped cell as the scans A/B."""
-    cfg = SimConfig(seed=0, vcpu_limit=100_000,
-                    mem_mb_per_worker=4_000_000,
-                    legacy_event_loop=legacy, legacy_scans=legacy)
-    pol = make_policy("shabari-legacy-engine" if legacy else "shabari",
-                      profiles, pool, slo_table, seed=0)
-    sim = Simulator(policy=pol, profiles=profiles, input_pool=pool,
-                    slo_table=slo_table, cfg=cfg)
-    t0 = time.perf_counter()
-    results = sim.run(trace)
-    wall = time.perf_counter() - t0
-    return sim.events_processed, wall, summarize(results)
-
-
-def run_stack_ab(trace, profiles, pool, slo_table) -> None:
-    """Learning-policy full-stack A/B on the heavy-tail trace.
-
-    Every layer of the legacy stack is a metric-identical slow path
-    (the event loop, the scan refactor, and the agent engine are all
-    pure fast paths), so the summaries must match BIT-identically —
-    enforced with a hard failure, same as the engine A/B. The speedup
-    floor here is a conservative in-bench backstop; the real
-    events/sec floors ride benchmarks/baselines.json where the
-    best-of-3 re-measure absorbs machine noise."""
-    # jit kernels + arena calibration are already warm: run() calls
-    # run_engine_ab first, which traces both engines on this trace
-    ev_l, wall_l, sum_l = _run_stack(
-        trace, profiles, pool, slo_table, legacy=True)
-    ev_f, wall_f, sum_f = _run_stack(
-        trace, profiles, pool, slo_table, legacy=False)
-    eps_l = ev_l / wall_l
-    eps_f = ev_f / wall_f
-    emit("sim_bench.scale_legacy_stack", wall_l / ev_l * 1e6,
-         f"n={len(trace)}|events={ev_l}|events_per_sec={eps_l:.0f}")
-    emit("sim_bench.scale_fast_stack", wall_f / ev_f * 1e6,
-         f"n={len(trace)}|events={ev_f}|events_per_sec={eps_f:.0f}")
-    emit("sim_bench.scale_stack_speedup", 0.0,
-         f"x{eps_f / eps_l:.2f}|metrics_identical={sum_f == sum_l}")
-    if sum_f != sum_l:
-        raise RuntimeError(
-            "fast stack changed shabari summary metrics vs the full "
-            f"legacy stack: {sum_f} != {sum_l}")
-    if eps_f < 4.0 * eps_l:
-        raise RuntimeError(
-            "fast stack below the 4x events/sec backstop vs the full "
-            f"legacy stack: {eps_f:.0f} vs {eps_l:.0f}")
-
-
 def run() -> None:
     profiles = build_profiles()
     pool = build_input_pool(seed=0)
@@ -322,24 +180,12 @@ def run() -> None:
         inputs_per_function={f: len(pool[f]) for f in profiles},
     )
 
-    ev_legacy, wall_legacy, sum_legacy = _run_once(
-        trace, profiles, pool, slo_table, legacy=True)
-    ev_fast, wall_fast, sum_fast = _run_once(
-        trace, profiles, pool, slo_table, legacy=False)
-
-    eps_legacy = ev_legacy / wall_legacy
-    eps_fast = ev_fast / wall_fast
-    emit("sim_bench.legacy_scan", wall_legacy / ev_legacy * 1e6,
-         f"n={len(trace)}|events={ev_legacy}|events_per_sec={eps_legacy:.0f}")
-    emit("sim_bench.incremental", wall_fast / ev_fast * 1e6,
-         f"n={len(trace)}|events={ev_fast}|events_per_sec={eps_fast:.0f}")
-    emit("sim_bench.speedup", 0.0,
-         f"x{eps_fast / eps_legacy:.2f}|metrics_identical={sum_fast == sum_legacy}")
+    ev, wall, _ = _run_once(trace, profiles, pool, slo_table)
+    emit("sim_bench.incremental", wall / ev * 1e6,
+         f"n={len(trace)}|events={ev}|events_per_sec={ev / wall:.0f}")
 
     run_cache_cell(trace, profiles, pool, slo_table)
     run_engine_ab(trace, profiles, pool, slo_table)
-    run_retry_ab(profiles, pool, slo_table)
-    run_stack_ab(trace, profiles, pool, slo_table)
     run_scale(profiles, pool, slo_table)
 
 

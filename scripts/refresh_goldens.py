@@ -9,10 +9,11 @@ so a semantics change can't sail through on stale snapshots.
     PYTHONPATH=src python scripts/refresh_goldens.py [--only a,b]
                                                      [--out-dir DIR]
 
-Besides the per-scenario snapshots, the acquire-on-placement A/B
-scenarios (``LEGACY_ACQUIRE_SCENARIOS``) are snapshotted a second time
-under ``<out-dir>/legacy-acquire/`` with ``SimConfig(legacy_acquire=
-True)``, pinning the pre-reservation accounting independently.
+Besides the per-scenario snapshots, the A/B scenarios of
+``repro.serving.golden`` (``*_SCENARIOS``) are snapshotted a second
+time under ``<out-dir>/<subdir>/`` with their one switch flipped:
+``legacy-engine/``, ``estimate-routing/``, ``cache-disabled/`` and
+``chain-uniform/``.
 """
 
 from __future__ import annotations
@@ -31,27 +32,21 @@ from repro.serving.golden import (  # noqa: E402
     CHAIN_UNIFORM_SCENARIOS,
     ESTIMATE_ROUTING_SCENARIOS,
     GOLDEN_POLICY,
-    LEGACY_ACQUIRE_SCENARIOS,
     LEGACY_ENGINE_SCENARIOS,
-    LEGACY_EVENT_LOOP_SCENARIOS,
     golden_specs,
     run_golden,
 )
 
 GOLDEN_DIR = os.path.join(os.path.dirname(__file__), "..", "tests", "goldens")
-LEGACY_SUBDIR = "legacy-acquire"
 LEGACY_ENGINE_SUBDIR = "legacy-engine"
-LEGACY_EVENT_LOOP_SUBDIR = "legacy-event-loop"
 ESTIMATE_SUBDIR = "estimate-routing"
 CACHE_DISABLED_SUBDIR = "cache-disabled"
 CHAIN_UNIFORM_SUBDIR = "chain-uniform"
 
 
 def write_snapshot(scenario: str, out_dir: str, *,
-                   legacy_acquire: bool = False,
                    legacy_engine: bool = False,
                    estimate_routing: bool = False,
-                   legacy_event_loop: bool = False,
                    cache_disabled: bool = False,
                    chain_uniform: bool = False) -> Dict:
     """Run one golden scenario and write its snapshot JSON; returns the
@@ -61,10 +56,8 @@ def write_snapshot(scenario: str, out_dir: str, *,
         "policy": ("shabari-legacy-engine" if legacy_engine
                    else GOLDEN_POLICY),
         "spec": dataclasses.asdict(golden_specs()[scenario]),
-        "summary": run_golden(scenario, legacy_acquire=legacy_acquire,
-                              legacy_engine=legacy_engine,
+        "summary": run_golden(scenario, legacy_engine=legacy_engine,
                               estimate_routing=estimate_routing,
-                              legacy_event_loop=legacy_event_loop,
                               cache_disabled=cache_disabled,
                               chain_uniform=chain_uniform),
     }
@@ -72,10 +65,8 @@ def write_snapshot(scenario: str, out_dir: str, *,
     with open(path, "w") as f:
         json.dump(doc, f, indent=2, sort_keys=True)
         f.write("\n")
-    tag = (" (legacy-acquire)" if legacy_acquire
-           else " (legacy-engine)" if legacy_engine
+    tag = (" (legacy-engine)" if legacy_engine
            else " (estimate-routing)" if estimate_routing
-           else " (legacy-event-loop)" if legacy_event_loop
            else " (cache-disabled)" if cache_disabled
            else " (chain-uniform)" if chain_uniform else "")
     print(f"{scenario:>20}{tag}: n={doc['summary']['n']:.0f} "
@@ -88,17 +79,10 @@ def refresh(out_dir: str = GOLDEN_DIR, only: Optional[set] = None) -> None:
         if only and scenario not in only:
             continue
         write_snapshot(scenario, out_dir)
-        if scenario in LEGACY_ACQUIRE_SCENARIOS:
-            write_snapshot(scenario, os.path.join(out_dir, LEGACY_SUBDIR),
-                           legacy_acquire=True)
         if scenario in LEGACY_ENGINE_SCENARIOS:
             write_snapshot(
                 scenario, os.path.join(out_dir, LEGACY_ENGINE_SUBDIR),
                 legacy_engine=True)
-        if scenario in LEGACY_EVENT_LOOP_SCENARIOS:
-            write_snapshot(
-                scenario, os.path.join(out_dir, LEGACY_EVENT_LOOP_SUBDIR),
-                legacy_event_loop=True)
         if scenario in ESTIMATE_ROUTING_SCENARIOS:
             write_snapshot(
                 scenario, os.path.join(out_dir, ESTIMATE_SUBDIR),

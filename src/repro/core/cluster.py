@@ -23,7 +23,7 @@ invocations) rescans per route):
 
 * ``Worker.idle_warm`` / ``Cluster.has_idle_warm`` — warm containers
   usable NOW (``warm_at <= now``), via the per-function index;
-* ``Worker.warming_soon`` / ``Cluster.warming_soon`` — uncommitted
+* ``Cluster.warming_soon`` — uncommitted
   containers still warming whose ``warm_at`` falls within a horizon
   (background exact-size launches, §5 case 2). Invisible to the warm
   lookups above, these are placement targets for the router's
@@ -289,35 +289,6 @@ class Worker:
             return []
         return [c for c in byf.values() if not c.busy and c.warm_at <= now]
 
-    def warming_soon(self, function: str, now: float, horizon_s: float,
-                     vcpus: int, mem_mb: int) -> Optional[Container]:
-        """The soonest-warm UNCOMMITTED container for ``function`` that
-        is at least (vcpus, mem_mb) big, still warming with ``warm_at``
-        within ``horizon_s`` of ``now``, and whose reservation this
-        worker can still take (``fits`` is checked per container, not
-        after selection — a too-big soonest candidate must not hide a
-        later one that fits).
-
-        Only background-launched containers qualify: a cold start placed
-        for a specific invocation is ``busy`` (and ``reserved``) for its
-        whole warm-up, so it can never be handed to a second invocation.
-        Uses the per-function index — cost is O(this function's
-        containers on the worker), not O(all containers)."""
-        byf = self.by_function.get(function)
-        if not byf:
-            return None
-        best: Optional[Container] = None
-        for c in byf.values():
-            if c.busy or c.warm_at <= now or c.warm_at > now + horizon_s:
-                continue
-            if c.vcpus < vcpus or c.mem_mb < mem_mb:
-                continue
-            if not self.fits(c.vcpus, c.mem_mb):
-                continue
-            if best is None or c.warm_at < best.warm_at:
-                best = c
-        return best
-
 
 class Cluster:
     def __init__(
@@ -326,13 +297,8 @@ class Cluster:
         vcpus_per_worker: int = 90,
         mem_mb_per_worker: int = 125 * 1024,
         vcpu_limit: Optional[int] = None,
-        legacy_scans: bool = False,
         machines: Optional[Sequence[MachineType]] = None,
     ):
-        # legacy_scans restores the pre-refactor O(containers) warm
-        # lookup (see Simulator's SimConfig.legacy_scans) for A/B
-        # benchmarking; results are identical either way.
-        self.legacy_scans = legacy_scans
         # cluster-level load aggregates, maintained by Worker.acquire/
         # release — the router's O(1) spill-target metric. Reservations
         # (committed-but-warming cold starts) are included in used_*;
@@ -369,20 +335,12 @@ class Cluster:
             )
             for i, m in enumerate(machines)
         ]
-        # cluster-level mirror of each worker's per-function container
-        # index: warm lookups for a function touch only ITS containers
-        # cluster-wide instead of probing all workers (most hold none).
-        # Iteration order is container-creation order; selection-order
-        # parity with the per-worker scans is restored by explicit
-        # (wid, cid) tie-break keys at the call sites (scheduler,
-        # warming_soon below).
-        self.by_function: Dict[str, Dict[int, Container]] = {}
-        # per-function dict of the IDLE (busy == False) subset of
-        # ``by_function``: warm lookups and warming-soon scans touch
-        # only containers that can actually be candidates, instead of
-        # every container of the function. Maintained eagerly by
-        # mark_busy/mark_idle at each busy flip (two O(1) dict ops per
-        # invocation lifecycle); iteration order is irrelevant because
+        # per-function index of the cluster's IDLE (busy == False)
+        # containers: warm lookups and warming-soon scans for a function
+        # touch only containers that can actually be candidates, instead
+        # of probing every worker. Maintained eagerly by mark_busy/
+        # mark_idle at each busy flip (two O(1) dict ops per invocation
+        # lifecycle); iteration order is container-creation order, and
         # every reader selects by an explicit total (.., wid, cid) key.
         self.idle_by_function: Dict[str, Dict[int, Container]] = {}
 
@@ -415,7 +373,6 @@ class Cluster:
         )
         worker.containers[c.cid] = c
         worker.by_function.setdefault(function, {})[c.cid] = c
-        self.by_function.setdefault(function, {})[c.cid] = c
         # containers are created idle; cold-start placement marks the
         # new container busy immediately after, removing it again
         self.idle_by_function.setdefault(function, {})[c.cid] = c
@@ -431,20 +388,14 @@ class Cluster:
         byf = c.worker.by_function.get(c.function)
         if byf is not None:
             byf.pop(c.cid, None)
-        cbf = self.by_function.get(c.function)
-        if cbf is not None:
-            cbf.pop(c.cid, None)
         ibf = self.idle_by_function.get(c.function)
         if ibf is not None:
             ibf.pop(c.cid, None)
 
     def has_idle_warm(self, function: str, now: float) -> bool:
-        """Emptiness probe — the router's warm-spill pre-check. The
-        cluster-level index holds exactly the union of the per-worker
-        indexes, so the predicate matches Worker.idle_warm; legacy_scans
-        keeps the per-worker probe for A/B."""
-        if self.legacy_scans:
-            return any(w.idle_warm(function, now) for w in self.workers)
+        """Emptiness probe — the router's warm-spill pre-check: is any
+        of ``function``'s containers idle and warm (``warm_at <= now``)
+        somewhere in the cluster."""
         byf = self.idle_by_function.get(function)
         if not byf:
             return False
@@ -454,26 +405,23 @@ class Cluster:
 
     def warming_soon(self, function: str, now: float, horizon_s: float,
                      vcpus: int, mem_mb: int) -> Optional[Container]:
-        """Cluster-wide soonest-warm uncommitted container within the
-        horizon whose worker can still take its reservation — the
-        estimate router's warming-soon placement candidate. The
-        per-worker scan (kept under ``legacy_scans``) picks per-worker
-        minima by (warm_at, insertion order) and then keeps the earliest
-        worker on ties — i.e. the global min by (warm_at, wid, cid); the
-        indexed path selects by that exact key."""
-        if self.legacy_scans:
-            best: Optional[Container] = None
-            for w in self.workers:
-                c = w.warming_soon(function, now, horizon_s, vcpus, mem_mb)
-                if c is None:
-                    continue
-                if best is None or c.warm_at < best.warm_at:
-                    best = c
-            return best
+        """Cluster-wide soonest-warm UNCOMMITTED container for
+        ``function`` — the estimate router's warming-soon placement
+        candidate: at least (vcpus, mem_mb) big, still warming with
+        ``warm_at`` within ``horizon_s`` of ``now``, and on a worker that
+        can still take its reservation (``fits`` is checked per
+        container, not after selection — a too-big soonest candidate
+        must not hide a later one that fits). The min by
+        (warm_at, wid, cid) wins.
+
+        Only background-launched containers qualify: a cold start placed
+        for a specific invocation is ``busy`` (and ``reserved``) for its
+        whole warm-up, so it can never be handed to a second
+        invocation."""
         byf = self.idle_by_function.get(function)
         if not byf:
             return None
-        best = None
+        best: Optional[Container] = None
         best_key = None
         deadline = now + horizon_s
         for c in byf.values():
@@ -490,14 +438,6 @@ class Cluster:
 
     def idle_warm(self, function: str, now: float) -> List[Container]:
         out: List[Container] = []
-        if self.legacy_scans:
-            for w in self.workers:
-                out.extend(
-                    c for c in w.containers.values()
-                    if c.function == function and not c.busy
-                    and c.warm_at <= now
-                )
-            return out
         for w in self.workers:
             out.extend(w.idle_warm(function, now))
         return out

@@ -32,8 +32,7 @@ The DEFAULT fleet — one uniform machine type built from the
 :class:`~repro.serving.simulator.SimConfig` constants, zero-cost links
 (:meth:`Topology.is_free`) — reproduces the homogeneous behavior
 bit-for-bit: every golden snapshot is byte-identical with
-``SimConfig(fleet=None)``, the same A/B discipline as ``legacy_scans``/
-``legacy_acquire``. The FleetSpec is also the single source of the §5
+``SimConfig(fleet=None)``. The FleetSpec is also the single source of the §5
 model constants: the simulator charges and the router forecasts from
 the SAME ``MachineType`` carried on each ``Worker``, so the two can no
 longer drift apart through parallel constructor arguments.

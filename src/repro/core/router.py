@@ -48,9 +48,8 @@ estimate mode).
 The ``_load`` signal is truthful about in-flight cold starts: the
 runtime reserves capacity at PLACEMENT (``Worker.reserve``), so a
 cold-started container counts against its cluster's load for the whole
-warm-up window and arrivals inside that ~0.5-1 s window no longer herd
-onto the same least-loaded remote (the old acquire-on-start behavior is
-kept behind ``SimConfig(legacy_acquire=True)`` for A/B).
+warm-up window and arrivals inside that ~0.5-1 s window do not herd
+onto the same least-loaded remote.
 
 On top of that signal the router applies front-door ADMISSION CONTROL:
 

@@ -180,38 +180,11 @@ class ShabariScheduler:
         binds through this method, so the router's estimate mode scores
         the contention of the worker that will actually serve the
         invocation, not merely *a* warm worker."""
-        if self.cluster.legacy_scans:
-            # pre-index selection, kept for A/B: materialize the
-            # worker-major warm list and stable-sort it
-            warm = self.cluster.idle_warm(function, now)
-            exact = [c for c in warm if c.vcpus == vcpus and c.mem_mb == mem_mb
-                     and c.worker.fits(vcpus, mem_mb)]
-            if exact:
-                exact.sort(key=lambda c: c.last_used)
-                return exact[0]
-            if not self.route_larger:
-                return None
-            larger = [
-                c for c in warm
-                if c.vcpus >= vcpus and c.mem_mb >= mem_mb
-                and c.worker.fits(c.vcpus, c.mem_mb)
-            ]
-            if not larger:
-                return None
-            larger.sort(key=lambda c: (c.vcpus - vcpus, c.mem_mb - mem_mb))
-            return larger[0]
-        # Indexed path: one pass over the cluster's IDLE containers of
-        # this function (mark_busy/mark_idle keep that index exact), so
-        # busy containers never even surface. Selection parity with the
-        # legacy stable sorts: the worker-major warm list is ordered by
-        # (wid, cid) — worker list order, then per-worker insertion
-        # order, and cids increase with creation time — so "stable sort
-        # by k, take first" is exactly "min by (k, wid, cid)". The
-        # legacy larger-branch also admits exact-size containers, but
-        # an exact-size candidate either passes the identical
-        # fits(vcpus, mem_mb) test (then the exact branch wins with its
-        # (0, 0) size-delta key anyway) or fails it in both branches —
-        # so bucketing exact and strictly-larger separately is safe.
+        # One pass over the cluster's IDLE containers of this function
+        # (mark_busy/mark_idle keep that index exact), so busy
+        # containers never even surface. Ties break by worker, then by
+        # container: the exact fit is the min by (last_used, wid, cid),
+        # the larger fit the min by (size deltas, wid, cid).
         idle = self.cluster.idle_by_function.get(function)
         if not idle:
             return None

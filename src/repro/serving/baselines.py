@@ -254,9 +254,9 @@ class ShabariPolicy(Policy):
                 self._features[arrival.invocation_id] = aux[0]
                 return alloc, aux
             if aux is None:
-                # first sight of this invocation: featurize once; the tuple
-                # rides the retry payload so re-allocations (the legacy
-                # per-retry path) never re-run Featurizer / input_size_mb
+                # first sight of this invocation: featurize once; the
+                # tuple rides the retry payload, where the simulator
+                # reads the invocation's features and input size
                 aux = self._featurize(arrival, meta, sim)
             x, size = aux
             self._features[arrival.invocation_id] = x

@@ -1,4 +1,4 @@
-"""Bucketed calendar event queue for the array-backed simulator loop.
+"""Bucketed calendar event queue for the simulator's event loop.
 
 A classic calendar queue (Brown 1988) specialised for the simulator's
 access pattern: events are pushed with a ``(t, seq)`` priority and
@@ -13,10 +13,10 @@ density per ``bucket_s`` window rather than by trace length.
 Two properties the simulator depends on:
 
 * **Total order parity with ``heapq``.** Within a bucket the heap
-  orders ``(t, seq, ...)`` tuples exactly as the legacy global heap
-  did, and buckets are drained in id order, so the pop sequence is
-  byte-identical to a single ``heapq`` over the same pushes (``seq`` is
-  a strictly increasing tiebreak, so priorities are unique).
+  orders ``(t, seq, ...)`` tuples, and buckets are drained in id
+  order, so the pop sequence is byte-identical to a single ``heapq``
+  over the same pushes (``seq`` is a strictly increasing tiebreak, so
+  priorities are unique).
 * **Safe insert-into-draining-bucket.** Simulated time never goes
   backwards: every push carries ``t >= now`` (handlers schedule only
   into the future), so pushing into the *currently draining* bucket is

@@ -33,30 +33,13 @@ GOLDEN_POLICY = "shabari"
 RTOL = 1e-5
 ATOL = 1e-8
 
-# The acquire-on-placement A/B: these scenarios are also snapshotted
-# under tests/goldens/legacy-acquire/ with SimConfig(legacy_acquire=
-# True), pinning the pre-reservation accounting so the two semantics
-# stay independently regression-tested (tests/test_reservation.py).
-LEGACY_ACQUIRE_SCENARIOS = ("multi-cluster", "oversubscribe", "poisson-steady")
-
 # The allocator-engine A/B: snapshotted under tests/goldens/
 # legacy-engine/ with ResourceAllocator(engine="legacy") — the
-# per-object pre-arena path. Unlike the acquire A/B this is NOT a
-# semantics fork: the snapshot must equal the main golden bit-for-bit
-# (the arena is a pure fast path), which tests/test_agent_arena.py
-# asserts, so a numerics drift in either engine trips CI.
+# per-object pre-arena path. This is NOT a semantics fork: the
+# snapshot must equal the main golden bit-for-bit (the arena is a pure
+# fast path), which tests/test_agent_arena.py asserts, so a numerics
+# drift in either engine trips CI.
 LEGACY_ENGINE_SCENARIOS = ("heavy-tail-inputs",)
-
-# The event-loop A/B: snapshotted under tests/goldens/
-# legacy-event-loop/ with SimConfig(legacy_event_loop=True) — the
-# pre-refactor single-heapq hot loop. Like the engine A/B this is NOT
-# a semantics fork: the snapshot must equal the main golden
-# bit-for-bit (the array-backed loop + calendar queue is a pure fast
-# path), which tests/test_event_loop.py asserts, so drift in either
-# loop trips CI. oversubscribe is the pin because its golden exercises
-# retries, sheds, and queue timeouts — the event classes the fast
-# loop's merge logic reorders most easily if it is wrong.
-LEGACY_EVENT_LOOP_SCENARIOS = ("oversubscribe",)
 
 # The completion-time-estimate routing mode: snapshotted under
 # tests/goldens/estimate-routing/ with SimConfig(routing="estimate"),
@@ -191,20 +174,14 @@ def golden_specs() -> Dict[str, ScenarioSpec]:
     }
 
 
-def run_golden(scenario: str, *, legacy_acquire: bool = False,
-               legacy_engine: bool = False,
+def run_golden(scenario: str, *, legacy_engine: bool = False,
                estimate_routing: bool = False,
-               legacy_event_loop: bool = False,
                cache_disabled: bool = False,
                chain_uniform: bool = False) -> Dict[str, float]:
     spec = golden_specs()[scenario]
     cfg = golden_sim_config(scenario)
-    if legacy_acquire:
-        cfg = dataclasses.replace(cfg, legacy_acquire=True)
     if estimate_routing:
         cfg = dataclasses.replace(cfg, routing="estimate")
-    if legacy_event_loop:
-        cfg = dataclasses.replace(cfg, legacy_event_loop=True)
     if cache_disabled:
         cfg = dataclasses.replace(cfg, image_cache=None)
     if chain_uniform:

@@ -19,8 +19,7 @@ and contention. Modeled effects, each tied to a paper observation:
   eventually time out (the §7.5 oversubscription study). The
   Allocation — and the policy's featurization cache (aux) — is decided
   ONCE at first arrival and carried through retries; timed-out
-  invocations report it without re-entering the policy (pre-fix
-  behavior behind ``SimConfig.legacy_retry_alloc``).
+  invocations report it without re-entering the policy.
 
 Event-loop microbatching: consecutive same-timestamp arrivals are
 popped together and offered to ``Policy.begin_arrival_batch`` before
@@ -44,15 +43,13 @@ container, start at its ``warm_at``).
 Resource lifecycle: capacity is acquired at PLACEMENT, not at start — a
 placed cold start reserves its container's (vcpus, mem) for the whole
 warm-up window, so ``Worker.fits`` and ``Router._load`` see committed-
-but-warming capacity (``SimConfig.legacy_acquire`` restores the old
-acquire-on-start accounting for A/B). ``SimConfig.admission`` adds
-front-door admission control (shed / queue) under fleet-wide overload.
+but-warming capacity. ``SimConfig.admission`` adds front-door admission
+control (shed / queue / slo) under fleet-wide overload.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import heapq
 import itertools
 from collections import deque
 from typing import Dict, List, Optional, Tuple
@@ -62,8 +59,7 @@ import numpy as np
 from repro import spans
 from repro.core.cluster import Cluster, Container, Worker
 from repro.core.cost_functions import Observation
-from repro.core.daemon import (SAMPLE_INTERVAL_S, UtilizationTrace,
-                               WorkerDaemon, synth_trace)
+from repro.core.daemon import SAMPLE_INTERVAL_S
 from repro.core.fleet import COLD_JITTER_SIGMA, FleetSpec, MachineType
 from repro.core.image_cache import (ImageCacheSpec, NodeImageCache,
                                     default_images)
@@ -95,21 +91,12 @@ class SimConfig:
     # How co-runner contention is applied to an invocation:
     #   "snapshot" (default) — the slowdown is computed ONCE at start
     #     time from the co-runners active at that instant and held for
-    #     the invocation's whole run. This is the original semantics;
-    #     with it, metrics match the pre-refactor per-event scan.
+    #     the invocation's whole run. This is the original semantics.
     #   "dynamic" — the slowdown is re-evaluated whenever a co-runner
     #     starts or finishes on the same worker: remaining work is
     #     rescaled and the finish event re-queued. Closer to real
     #     cgroup CPU-share behavior; metrics differ from snapshot.
     contention_mode: str = "snapshot"
-    # Compatibility switch for A/B benchmarking (benchmarks/sim_bench):
-    # restore the pre-refactor O(N) loops — the per-event scan over
-    # every running invocation for contention demand, and the
-    # per-schedule scan over every container for warm lookups — instead
-    # of the incremental per-worker aggregates and per-function index.
-    # Metrics are identical either way; only speed differs. Only
-    # meaningful with contention_mode="snapshot".
-    legacy_scans: bool = False
     # Multi-cluster front door (repro.core.router): number of clusters
     # behind the router and the routing policy applied per arrival —
     # "hashing" | "spill-over" | "estimate" | "random". With
@@ -129,23 +116,6 @@ class SimConfig:
     # covers the full cold-start range of the paper's container sizes
     # (~0.5-1.3 s). Read only when routing == "estimate".
     estimate_horizon_s: float = 1.5
-    # Compatibility switch for A/B benchmarking (benchmarks/sim_bench):
-    # restore the pre-fix retry path — one policy.allocate (a jit'd jax
-    # dispatch for learning policies) per 0.5 s RETRY of a queued
-    # invocation, run even when the invocation is about to time out —
-    # instead of caching the Allocation in the retry payload.
-    legacy_retry_alloc: bool = False
-    # Resource lifecycle (benchmarks/admission_bench A/B). Default is
-    # acquire-on-PLACEMENT: a cold-started invocation reserves its
-    # container's (vcpus, mem) the moment it is placed, so Worker.fits,
-    # the per-worker aggregates, and Router._load all see committed-but-
-    # warming capacity; the reservation converts to a running
-    # acquisition when the cold start completes and is released if the
-    # invocation's queue timeout lapses first. legacy_acquire=True
-    # restores acquire-on-START (capacity held only once the container
-    # is warm), under which arrivals inside the warm-up window see a
-    # free-looking worker and stack cold starts onto it.
-    legacy_acquire: bool = False
     # Router-level admission control. The load-headroom modes act under
     # fleet-wide overload — when EVERY cluster's committed load exceeds
     # admission_headroom, "shed" drops the arrival at the front door
@@ -183,19 +153,6 @@ class SimConfig:
     # fleet) and charges arrival→cluster input-payload transfer time on
     # remote placements over non-free links.
     fleet: Optional[FleetSpec] = None
-    # Compatibility switch for A/B benchmarking (benchmarks/sim_bench
-    # scale tier) and equality testing (tests/test_event_loop.py):
-    # restore the pre-refactor hot loop — one global heapq over every
-    # event (arrivals pre-pushed, so a 24 h trace seeds a million-entry
-    # heap) and the full synth_trace utilization series per completion —
-    # instead of the array-backed loop (arrival stream kept as a sorted
-    # array, calendar-bucketed queue for scheduled events, slim daemon
-    # path that draws the identical rng stream without materializing
-    # samples nobody reads). Metrics and goldens are byte-identical
-    # either way; only speed differs. The same flush-before-read
-    # discipline applies on both paths (pending agent updates flush
-    # before any same-function prediction).
-    legacy_event_loop: bool = False
     # Estimate-mode A/B for the fleet refactor: when True (default) the
     # router PRICES the same input-payload transfer time the simulator
     # charges on remote placements (plus each machine's cold curve and
@@ -292,11 +249,10 @@ class Policy:
     def allocate_with_aux(self, arrival: Arrival, meta: Dict,
                           sim: "Simulator", aux=None):
         """``allocate`` plus an opaque per-invocation cache. The
-        simulator threads ``aux`` through the retry payload alongside
-        the cached Allocation, so any path that re-enters allocation
-        (``SimConfig.legacy_retry_alloc``) reuses the first attempt's
-        featurized input + input size instead of re-running the
-        Featurizer every 0.5 s retry."""
+        simulator carries ``aux`` through the retry payload alongside
+        the cached Allocation and reads the invocation's features from
+        it (``Simulator._aux_features``); retries never re-enter
+        allocation."""
         return self.allocate(arrival, meta, sim), aux
 
     def begin_arrival_batch(self, items: List[Tuple[Arrival, Dict]],
@@ -384,10 +340,7 @@ class Simulator:
         # the event stream is bit-identical to pre-fleet behavior
         self._charge_transfer = not self.fleet.topology.is_free()
         self.clusters = [
-            Cluster(
-                legacy_scans=self.cfg.legacy_scans,
-                machines=spec.worker_machines(),
-            )
+            Cluster(machines=spec.worker_machines())
             for spec in self.fleet.clusters
         ]
         # worker ids become globally unique across clusters: the
@@ -469,10 +422,8 @@ class Simulator:
         self.cluster = self.clusters[0]
         self.scheduler = self.schedulers[0]
         self.store = MetadataStore()
-        self.daemon = WorkerDaemon(self.store)
         self.results: List[InvocationResult] = []
         self.container_sizes: Dict[str, set] = {}
-        self._events: List[Tuple[float, int, str, object]] = []
         self._seq = itertools.count()
         self._running: Dict[int, _Running] = {}
         # per-worker index of running invocations (dynamic-mode retiming
@@ -484,15 +435,10 @@ class Simulator:
         assert self.cfg.contention_mode in ("snapshot", "dynamic")
         self.events_processed = 0
         self.now = 0.0
-        # array-backed loop state: the calendar queue replaces the
-        # global heap while _run_fast is active (None = legacy heap);
-        # the slim daemon path replays synth_trace's exact rng draws
-        # without materializing utilization samples nobody reads
-        self._queue: Optional[CalendarQueue] = None
-        self._retry_q: Optional[deque] = None
-        self._slim_daemon = not self.cfg.legacy_event_loop
-        self._rng_advance = isinstance(self.rng.bit_generator,
-                                       np.random.PCG64)
+        # scheduled events (finish / warm_start / xfer_start /
+        # chain_arrival / reap) and the FIFO retry lane; see _run
+        self._queue = CalendarQueue()
+        self._retry_q: deque = deque()
         self._zero_feat = np.zeros(1, np.float32)
         self._run_pool: List[_Running] = []
         # function chains (repro.serving.chains): None stays a single
@@ -509,19 +455,15 @@ class Simulator:
     # ------------------------------------------------------------ events
     def _push(self, t: float, kind: str, payload) -> None:
         ev = (t, next(self._seq), kind, payload)
-        q = self._queue
-        if q is not None:
-            if kind == "arrival":
-                # retry lane: every arrival re-push is scheduled at
-                # now + retry_interval_s with now non-decreasing and
-                # seq strictly increasing, so append order IS (t, seq)
-                # order — a deque replaces a heap for the storm-hot
-                # event class (_run_fast merges it back in)
-                self._retry_q.append(ev)
-            else:
-                q.push(ev)
+        if kind == "arrival":
+            # retry lane: every arrival re-push is scheduled at
+            # now + retry_interval_s with now non-decreasing and seq
+            # strictly increasing, so append order IS (t, seq) order —
+            # a deque replaces a heap for the storm-hot event class
+            # (_run merges it back in)
+            self._retry_q.append(ev)
         else:
-            heapq.heappush(self._events, ev)
+            self._queue.push(ev)
 
     # ------------------------------------------------------------ helpers
     def cold_latency(self, vcpus: int, mem_mb: int,
@@ -547,18 +489,9 @@ class Simulator:
 
     def _contention(self, w: Worker, fn: str, extra_demand: float,
                     extra_net: float) -> float:
-        if self.cfg.legacy_scans:
-            # pre-refactor loop, kept for A/B benchmarking (sim_bench)
-            demand = extra_demand + sum(
-                r.demand_vcpus for r in self._running.values() if r.worker is w
-            )
-            net = extra_net + sum(
-                r.net_gbps for r in self._running.values() if r.worker is w
-            )
-        else:
-            soa, i = w.soa, w.sidx
-            demand = extra_demand + float(soa.active_demand_vcpus[i])
-            net = extra_net + float(soa.active_net_gbps[i])
+        soa, i = w.soa, w.sidx
+        demand = extra_demand + float(soa.active_demand_vcpus[i])
+        net = extra_net + float(soa.active_net_gbps[i])
         cpu_slow = max(1.0, demand / w.machine.physical_cores)
         net_slow = (max(1.0, net / w.machine.nic_gbps)
                     if base_function(fn) in NETWORK_FED else 1.0)
@@ -612,14 +545,6 @@ class Simulator:
         now = self.now
         cfg = self.cfg
         meta = None
-        if cfg.legacy_retry_alloc:
-            # pre-fix retry path kept for A/B benchmarking (sim_bench):
-            # re-predict on every retry, even when about to time out.
-            # The featurized input + input size ride the retry payload
-            # (aux), so only the PREDICT re-runs — not the Featurizer.
-            meta = self.input_pool[arrival.function][arrival.input_idx]
-            alloc, aux = self.policy.allocate_with_aux(
-                arrival, meta, self, aux)
         if now - first_seen > cfg.queue_timeout_s:
             # the cached allocation from the first attempt is reported;
             # a timed-out invocation never touches the policy again
@@ -636,17 +561,14 @@ class Simulator:
             # retry of a front-door-held arrival while the fleet is
             # still past the queue-mode admission headroom: route()
             # would rebuild the same queued decision without touching
-            # any scheduler, so skip straight to the re-push (shared by
-            # both event loops — bit-identical to the long way around;
-            # _push is inlined because retry storms make this the
-            # hottest line of a saturated large-fleet simulation)
-            ev = (now + cfg.retry_interval_s, next(self._seq), "arrival",
-                  (arrival, first_seen, alloc, aux))
+            # any scheduler, so skip straight to the re-push
+            # (bit-identical to the long way around; _push is inlined
+            # because retry storms make this the hottest line of a
+            # saturated large-fleet simulation)
             spans.count("loop.retries")
-            if self._queue is not None:
-                self._retry_q.append(ev)  # FIFO retry lane (see _push)
-            else:
-                heapq.heappush(self._events, ev)
+            self._retry_q.append(  # FIFO retry lane (see _push)
+                (now + cfg.retry_interval_s, next(self._seq), "arrival",
+                 (arrival, first_seen, alloc, aux)))
             return
         if meta is None:
             meta = self.input_pool[arrival.function][arrival.input_idx]
@@ -710,9 +632,8 @@ class Simulator:
             # whatever of the payload transfer the warm-up doesn't hide).
             c = decision.pending
             c.worker.cluster.mark_busy(c)
-            if not self.cfg.legacy_acquire:
-                c.worker.reserve(c.vcpus, c.mem_mb)
-                c.reserved = True
+            c.worker.reserve(c.vcpus, c.mem_mb)
+            c.reserved = True
             self._push(max(c.warm_at, now + xfer), "warm_start",
                        (arrival, meta, alloc, c, c.warm_at - now, first_seen,
                         aux))
@@ -749,12 +670,11 @@ class Simulator:
             c = cluster.new_container(w, arrival.function, v, m, now,
                                       warm_at=now + lat)
             cluster.mark_busy(c)
-            if not self.cfg.legacy_acquire:
-                # acquire-on-placement: hold the capacity for the whole
-                # warm-up window (converted to a running acquisition in
-                # _start, released in _cancel_cold_start)
-                w.reserve(v, m)
-                c.reserved = True
+            # acquire-on-placement: hold the capacity for the whole
+            # warm-up window (converted to a running acquisition in
+            # _start, released in _cancel_cold_start)
+            w.reserve(v, m)
+            c.reserved = True
             self._note_size(arrival.function, v, m)
             self._push(now + max(lat, xfer), "warm_start",
                        (arrival, meta, alloc, c, lat, first_seen, aux))
@@ -927,53 +847,29 @@ class Simulator:
         w.cluster.mark_idle(c)
         self.results.append(res)
 
-        if self._slim_daemon:
-            # Array-backed loop's daemon path: nothing downstream reads
-            # the UtilizationTrace SAMPLES — only its maxima, which
-            # synth_trace forces to exactly (used_vcpus, used_mem_mb)
-            # via the argmax write. So draw the identical rng stream
-            # (two random(n) batches, same n) to keep the shared
-            # generator bit-aligned with the legacy path, and build the
-            # Observation/record directly with the interned zero
-            # feature vector instead of allocating one per completion.
-            n_smp = max(int(res.exec_s / SAMPLE_INTERVAL_S), 4)
-            n_smp = min(n_smp, 4096)
-            if self._rng_advance:
-                # PCG64's random(n) consumes exactly n raw uint64s, so
-                # jumping the state 2*n forward is bit-identical to the
-                # two jitter batches synth_trace would have drawn —
-                # O(log n) instead of generating values nothing reads
-                self.rng.bit_generator.advance(2 * n_smp)
-            else:
-                self.rng.random(n_smp)
-                self.rng.random(n_smp)
-            obs = Observation(
-                exec_time_s=now - res.arrival_t,  # end-to-end vs SLO
-                slo_s=res.slo_s,
-                alloc_vcpus=res.alloc_vcpus,
-                max_vcpus_used=res.used_vcpus,
-                alloc_mem_mb=res.alloc_mem_mb,
-                max_mem_used_mb=res.used_mem_mb,
-                cold_start=res.cold_start,
-                oom_killed=res.oom_killed,
-            )
-            self.store.push(InvocationRecord(
-                function=res.function, invocation_id=res.invocation_id,
-                features=self._zero_feat, observation=obs,
-                finish_time=now,
-            ))
-        else:
-            trace = synth_trace(res.used_vcpus, res.used_mem_mb, res.exec_s,
-                                self.rng)
-            obs = self.daemon.report_completion(
-                function=res.function, invocation_id=res.invocation_id,
-                features=np.zeros(1, np.float32),  # policy recomputes if needed
-                exec_time_s=now - res.arrival_t,  # end-to-end vs SLO
-                slo_s=res.slo_s, alloc_vcpus=res.alloc_vcpus,
-                alloc_mem_mb=res.alloc_mem_mb, trace=trace,
-                finish_time=now, cold_start=res.cold_start,
-                oom_killed=res.oom_killed,
-            )
+        # the daemon's report (paper §6): the cost functions read only
+        # the maxima of the 10 ms utilization series, which are exactly
+        # (used_vcpus, used_mem_mb), so the series itself is not built.
+        # The shared rng still skips the two jitter draws per sample the
+        # series once took (PCG64's random(n) consumes n raw uint64s):
+        # every golden depends on that stream.
+        n_smp = min(max(int(res.exec_s / SAMPLE_INTERVAL_S), 4), 4096)
+        self.rng.bit_generator.advance(2 * n_smp)
+        obs = Observation(
+            exec_time_s=now - res.arrival_t,  # end-to-end vs SLO
+            slo_s=res.slo_s,
+            alloc_vcpus=res.alloc_vcpus,
+            max_vcpus_used=res.used_vcpus,
+            alloc_mem_mb=res.alloc_mem_mb,
+            max_mem_used_mb=res.used_mem_mb,
+            cold_start=res.cold_start,
+            oom_killed=res.oom_killed,
+        )
+        self.store.push(InvocationRecord(
+            function=res.function, invocation_id=res.invocation_id,
+            features=self._zero_feat, observation=obs,
+            finish_time=now,
+        ))
         self.policy.feedback(arrival, meta, res, self)
         # estimator calibration: report the UNCONTENDED exec time and
         # the NIC draw so estimate-mode scoring can apply each
@@ -995,10 +891,10 @@ class Simulator:
             else:
                 # spawn every stage whose LAST parent this completion
                 # was: a fresh arrival at t == now, pushed as its own
-                # scheduled-event kind so both event loops route it
-                # through the calendar/heap (the fast loop's retry
-                # deque is arrivals-at-now+interval ONLY — a same-t
-                # arrival push would break its ordering invariant)
+                # scheduled-event kind so it goes through the calendar
+                # queue (the retry deque is arrivals-at-now+interval
+                # ONLY — a same-t arrival push would break its ordering
+                # invariant)
                 for inst, stage, fn_c, idx_c in ch.on_complete(
                         arrival.invocation_id, now):
                     child = Arrival(next(self._chain_iid), now, fn_c, idx_c)
@@ -1024,22 +920,20 @@ class Simulator:
             self._chain_iid = itertools.count(len(arrivals))
         # inside a profiler trace the program's spans go into it too
         with spans.profiled():
-            if self.cfg.legacy_event_loop:
-                return self._run_legacy(arrivals)
-            return self._run_fast(arrivals)
+            return self._run(arrivals)
 
     def chain_summary(self) -> Optional[Dict[str, float]]:
         """End-to-end chain metrics, None when ``cfg.chains`` is off."""
         return None if self._chains is None else self._chains.summary()
 
     def _process_arrival_cohort(self, t: float, payloads: list) -> None:
-        """Handle one same-timestamp arrival cohort in event order —
-        shared by both loops. Microbatching every CONSECUTIVE same-
-        timestamp arrival is bit-identical to processing them one by
-        one: nothing can be interleaved between them (an intervening
-        finish/warm_start would break the cohort), and pending agent
-        updates flush before any prediction for the same function."""
-        if len(payloads) > 1 and not self.cfg.legacy_retry_alloc:
+        """Handle one same-timestamp arrival cohort in event order.
+        Microbatching every CONSECUTIVE same-timestamp arrival is
+        bit-identical to processing them one by one: nothing can be
+        interleaved between them (an intervening finish/warm_start
+        would break the cohort), and pending agent updates flush before
+        any prediction for the same function."""
+        if len(payloads) > 1:
             fresh = [
                 (a, self.input_pool[a.function][a.input_idx])
                 for a, fs, alloc, _ in payloads
@@ -1052,7 +946,7 @@ class Simulator:
             self._on_arrival(arrival, first_seen, alloc, aux)
 
     def _handle_scheduled(self, t: float, kind: str, payload) -> None:
-        """Dispatch one non-arrival, non-reap event (both loops)."""
+        """Dispatch one non-arrival, non-reap event."""
         if kind == "warm_start":
             arrival, meta, alloc, c, lat, first_seen, aux = payload
             if c.reserved and t - first_seen > self.cfg.queue_timeout_s:
@@ -1085,128 +979,67 @@ class Simulator:
             arrival, meta, gen = payload
             self._on_finish(arrival, meta, gen)
 
-    def _run_legacy(self, arrivals: List[Arrival]) -> List[InvocationResult]:
-        """Pre-refactor hot loop (``legacy_event_loop=True``): one
-        global heapq with every arrival pre-pushed."""
-        for a in arrivals:
-            self._push(a.t, "arrival", (a, a.t, None, None))
-        reap_t = 60.0
-        self._push(reap_t, "reap", None)
-        while self._events:
-            t, _, kind, payload = heapq.heappop(self._events)
-            self.now = t
-            self.events_processed += 1
-            if kind == "arrival":
-                payloads = [payload]
-                while (self._events and self._events[0][0] == t
-                       and self._events[0][2] == "arrival"):
-                    payloads.append(heapq.heappop(self._events)[3])
-                self.events_processed += len(payloads) - 1
-                self._process_arrival_cohort(t, payloads)
-            elif kind == "reap":
-                for sched in self.schedulers:
-                    sched.reap_idle(self.now)
-                if self._events:
-                    self._push(self.now + 60.0, "reap", None)
-            else:
-                self._handle_scheduled(t, kind, payload)
-        return self.results
-
-    def _run_fast(self, arrivals: List[Arrival]) -> List[InvocationResult]:
-        """Array-backed hot loop (the default). The trace's arrivals
-        never enter a priority queue: a stable argsort over their
-        timestamps IS their pop order (ties keep list order, exactly
-        the ``(t, seq)`` order the legacy heap gave them, since legacy
-        seqs were assigned in list order). Scheduled events (finish /
-        warm_start / xfer_start / reap) go through a bucketed
-        :class:`CalendarQueue` whose pop order matches a global heap.
-        Retries get a THIRD lane, a plain deque: every arrival re-push
-        is scheduled at ``now + retry_interval_s`` with ``now``
-        non-decreasing and seq strictly increasing, so append order is
-        already ``(t, seq)`` order and no heap is needed for the event
-        class that dominates a saturated run. The three streams merge
-        on ``(t, seq)``: virtual arrival seqs are their list indices
-        (all < n), and ``self._seq`` starts at n, so every scheduled
-        event sorts after every same-timestamp fresh arrival — as it
-        did under the single heap."""
+    def _run(self, arrivals: List[Arrival]) -> List[InvocationResult]:
+        """The event loop. Every event pops in ``(t, seq)`` order, one
+        global order over three lanes. The trace's arrivals never enter
+        a priority queue: a stable argsort over their timestamps IS
+        their pop order, and their virtual seqs are their list indices.
+        Scheduled events (finish / warm_start / xfer_start /
+        chain_arrival / reap) go through a bucketed
+        :class:`CalendarQueue`. Retries get a THIRD lane, a plain deque:
+        every arrival re-push is scheduled at ``now + retry_interval_s``
+        with ``now`` non-decreasing and seq strictly increasing, so
+        append order is already ``(t, seq)`` order and no heap is
+        needed for the event class that dominates a saturated run.
+        ``self._seq`` starts at n, so every scheduled event sorts after
+        every same-timestamp fresh arrival. Consecutive same-timestamp
+        arrivals (fresh first, then retries in seq order, up to any
+        scheduled event with a smaller seq) form one cohort."""
         n = len(arrivals)
         self._seq = itertools.count(n)  # seqs 0..n-1 belong to arrivals
-        self._queue = q = CalendarQueue()
-        self._retry_q = rq = deque()
-        try:
-            if n:
-                order = np.argsort(
-                    np.array([a.t for a in arrivals], dtype=np.float64),
-                    kind="stable",
-                ).tolist()
-            else:
-                order = []
-            self._push(60.0, "reap", None)  # seq n, as under the heap
-            ai = 0
-            while ai < n or q or rq:
-                head = q.peek()
-                # effective scheduled head = min over both lanes
-                head_is_retry = False
-                if rq:
-                    r = rq[0]
-                    if head is None or r[0] < head[0] or (
-                            r[0] == head[0] and r[1] < head[1]):
-                        head = r
-                        head_is_retry = True
-                if ai < n:
-                    oi = order[ai]
-                    a = arrivals[oi]
-                    # oi < n <= any queued seq: fresh arrival wins ties
-                    if head is None or a.t < head[0] or (
-                            a.t == head[0] and oi < head[1]):
-                        t = a.t
-                        self.now = t
-                        ai += 1
-                        payloads = [(a, t, None, None)]
-                        while ai < n:
-                            b = arrivals[order[ai]]
-                            if b.t != t:
-                                break
-                            payloads.append((b, t, None, None))
-                            ai += 1
-                        # retries at the same t (their seqs all exceed
-                        # every fresh arrival's) extend the cohort
-                        # while they are the globally next events — a
-                        # calendar event at the same t with a smaller
-                        # seq breaks the consecutive run, exactly as it
-                        # broke the run the heap popped
-                        if rq and rq[0][0] == t:
-                            ch = q.peek()
-                            while rq:
-                                r = rq[0]
-                                if r[0] != t or (ch is not None
-                                                 and ch[0] == t
-                                                 and ch[1] < r[1]):
-                                    break
-                                payloads.append(r[3])
-                                rq.popleft()
-                        self.events_processed += len(payloads)
-                        self._process_arrival_cohort(t, payloads)
-                        continue
-                if head_is_retry:
-                    t, _, _k, payload = rq.popleft()
+        q, rq = self._queue, self._retry_q
+        if n:
+            order = np.argsort(
+                np.array([a.t for a in arrivals], dtype=np.float64),
+                kind="stable",
+            ).tolist()
+        else:
+            order = []
+        self._push(60.0, "reap", None)  # seq n
+        ai = 0
+        while ai < n or q or rq:
+            head = q.peek()
+            # effective scheduled head = min over both lanes
+            head_is_retry = False
+            if rq:
+                r = rq[0]
+                if head is None or r[0] < head[0] or (
+                        r[0] == head[0] and r[1] < head[1]):
+                    head = r
+                    head_is_retry = True
+            if ai < n:
+                oi = order[ai]
+                a = arrivals[oi]
+                # oi < n <= any queued seq: fresh arrival wins ties
+                if head is None or a.t < head[0] or (
+                        a.t == head[0] and oi < head[1]):
+                    t = a.t
                     self.now = t
-                    self.events_processed += 1
-                    nxt = rq[0] if rq else None
-                    if nxt is None or nxt[0] != t:
-                        # lone retry — the common case in a retry storm
-                        # (retry timestamps inherit their arrival's
-                        # fractional offset, so they rarely collide);
-                        # identical to a single-payload cohort, minus
-                        # the list build
-                        a, fs, al, ax = payload
-                        self._on_arrival(a, fs, al, ax)
-                    else:
-                        # retry-only cohort: drain same-t retries while
-                        # no same-t calendar event with a smaller seq
-                        # intervenes (heap-run parity, as above)
+                    ai += 1
+                    payloads = [(a, t, None, None)]
+                    while ai < n:
+                        b = arrivals[order[ai]]
+                        if b.t != t:
+                            break
+                        payloads.append((b, t, None, None))
+                        ai += 1
+                    # retries at the same t (their seqs all exceed
+                    # every fresh arrival's) extend the cohort while
+                    # they are the globally next events — a calendar
+                    # event at the same t with a smaller seq breaks
+                    # the consecutive run
+                    if rq and rq[0][0] == t:
                         ch = q.peek()
-                        payloads = [payload]
                         while rq:
                             r = rq[0]
                             if r[0] != t or (ch is not None
@@ -1215,22 +1048,49 @@ class Simulator:
                                 break
                             payloads.append(r[3])
                             rq.popleft()
-                        self.events_processed += len(payloads) - 1
-                        self._process_arrival_cohort(t, payloads)
+                    self.events_processed += len(payloads)
+                    self._process_arrival_cohort(t, payloads)
                     continue
-                t, _, kind, payload = q.pop()
+            if head_is_retry:
+                t, _, _k, payload = rq.popleft()
                 self.now = t
                 self.events_processed += 1
-                if kind == "reap":
-                    for sched in self.schedulers:
-                        sched.reap_idle(t)
-                    if ai < n or q or rq:
-                        self._push(t + 60.0, "reap", None)
+                nxt = rq[0] if rq else None
+                if nxt is None or nxt[0] != t:
+                    # lone retry — the common case in a retry storm
+                    # (retry timestamps inherit their arrival's
+                    # fractional offset, so they rarely collide);
+                    # identical to a single-payload cohort, minus
+                    # the list build
+                    a, fs, al, ax = payload
+                    self._on_arrival(a, fs, al, ax)
                 else:
-                    self._handle_scheduled(t, kind, payload)
-        finally:
-            self._queue = None
-            self._retry_q = None
+                    # retry-only cohort: drain same-t retries while
+                    # no same-t calendar event with a smaller seq
+                    # intervenes (as above)
+                    ch = q.peek()
+                    payloads = [payload]
+                    while rq:
+                        r = rq[0]
+                        if r[0] != t or (ch is not None
+                                         and ch[0] == t
+                                         and ch[1] < r[1]):
+                            break
+                        payloads.append(r[3])
+                        rq.popleft()
+                    self.events_processed += len(payloads) - 1
+                    self._process_arrival_cohort(t, payloads)
+                continue
+            t, _, kind, payload = q.pop()
+            self.now = t
+            self.events_processed += 1
+            if kind == "reap":
+                for sched in self.schedulers:
+                    sched.reap_idle(t)
+                if ai < n or q or rq:
+                    self._push(t + 60.0, "reap", None)
+            else:
+                self._handle_scheduled(t, kind, payload)
         return self.results
 
 

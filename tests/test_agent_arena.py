@@ -686,10 +686,9 @@ def test_same_timestamp_arrivals_batch_identically():
 
 
 def test_retry_payload_caches_featurization():
-    """Satellite: under the legacy per-retry re-allocation path the
-    featurized input + input size ride the retry payload — the
-    Featurizer runs exactly once per invocation no matter how many
-    retries re-enter allocate."""
+    """The featurized input + input size ride the retry payload with
+    the cached allocation — the Featurizer runs exactly once per
+    invocation no matter how many times it retries."""
     from repro.serving import baselines as B
     from repro.serving.simulator import Simulator
     from repro.serving.workload import Arrival
@@ -705,7 +704,7 @@ def test_retry_payload_caches_featurization():
     arrivals = [Arrival(0, 0.0, fn, 0)] + [
         Arrival(i, 1.5, fn, 0) for i in range(1, 6)]
     cfg = _small_cfg(n_workers=1, vcpus_per_worker=12, vcpu_limit=12,
-                     physical_cores=12, legacy_retry_alloc=True)
+                     physical_cores=12)
     sim = Simulator(policy=pol, profiles=profiles, input_pool=pool,
                     slo_table=slo, cfg=cfg)
     results = sim.run(arrivals)

@@ -1,39 +1,43 @@
-"""Array-backed event loop vs the legacy single-heapq loop.
+"""The simulator's event loop against frozen references.
 
-The fast loop (``SimConfig(legacy_event_loop=False)``, the default) is
-a pure fast path: sorted-array arrivals + calendar-queue scheduled
-events + a FIFO retry lane must replay the exact event sequence the
-global heap produced. These tests pin that equivalence end to end
-(per-field ``InvocationResult`` equality on scenarios that exercise
-retries, front-door sheds, and warming-soon binds), pin the
-same-timestamp cohort partition both loops feed the policy batch hook,
-and pin the :class:`CalendarQueue` boundary cases (including pushing
+The loop keeps the trace's arrivals as a sorted array, scheduled events
+in a :class:`CalendarQueue` and retries in a FIFO lane, and must pop
+them all in one global ``(t, seq)`` order. Each cell below pins, for
+one configuration, the number of results, ``events_processed`` and a
+sha256 over every ``InvocationResult`` field in result order (plus the
+chain metrics where chains run). Every reference was recorded from two
+independent implementations that agreed on all three: this loop and a
+single global ``heapq`` over every event, each run both with the
+per-worker aggregates and warm-container index and with O(containers)
+scans for contention and warm lookups.
+
+The cells cover retries (capacity-queued and front-door-held), queue
+timeouts, the three admission modes, warming-soon binds, image-layer
+pulls, spawned chain arrivals, dynamic-contention finish re-queues and
+remote ``xfer_start`` placements. The cohort test pins the same-
+timestamp partition the loop feeds the policy's batch hook, and the
+:class:`CalendarQueue` units pin its boundary cases (including pushing
 into the bucket currently being drained, and pushing an event EARLIER
 than the cached head bucket).
-
-The committed golden under tests/goldens/legacy-event-loop/ must stay
-byte-identical to the main golden of the same scenario — unlike the
-legacy-acquire fork, the two loops are one semantics.
 """
 
 import dataclasses
+import hashlib
 import heapq
 import json
-import os
 import random
+from collections import Counter
 
 import pytest
 
 from repro.serving import baselines as B
 from repro.serving.event_queue import CalendarQueue
 from repro.serving.experiment import make_policy
-from repro.serving.golden import (LEGACY_EVENT_LOOP_SCENARIOS,
-                                  golden_sim_config, golden_specs)
+from repro.serving.golden import golden_sim_config, golden_specs
 from repro.serving.profiles import build_input_pool, build_profiles
 from repro.serving.simulator import InvocationResult, SimConfig, Simulator
-from repro.serving.workload import Arrival, generate_scenario
+from repro.serving.workload import Arrival, ScenarioSpec, generate_scenario
 
-GOLDEN_DIR = os.path.join(os.path.dirname(__file__), "goldens")
 FIELDS = [f.name for f in dataclasses.fields(InvocationResult)]
 
 
@@ -44,127 +48,173 @@ def _build_stack():
     return profiles, pool, slo
 
 
-def _run_loop(policy, spec, cfg, legacy):
+def _run_loop(policy, spec, cfg):
+    """Run one cell; also count the scheduled events by kind."""
     profiles, pool, slo = _build_stack()
     trace = generate_scenario(
         spec, functions=sorted(profiles),
         inputs_per_function={f: len(pool[f]) for f in profiles})
-    cfg = dataclasses.replace(cfg, legacy_event_loop=legacy)
     pol = make_policy(policy, profiles, pool, slo, seed=0)
     sim = Simulator(policy=pol, profiles=profiles, input_pool=pool,
                     slo_table=slo, cfg=cfg)
-    return sim, sim.run(trace)
+    kinds = Counter()
+    handle = sim._handle_scheduled
+
+    def counting_handle(t, kind, payload):
+        kinds[kind] += 1
+        handle(t, kind, payload)
+
+    sim._handle_scheduled = counting_handle
+    return sim, sim.run(trace), kinds
 
 
-def _assert_field_equal(fast, legacy):
-    assert len(fast) == len(legacy)
-    for a, b in zip(fast, legacy):
-        for f in FIELDS:
-            assert getattr(a, f) == getattr(b, f), (
-                f"invocation {a.invocation_id} field {f}: "
-                f"fast={getattr(a, f)!r} legacy={getattr(b, f)!r}")
+def _digest(results, chain_summary=None):
+    h = hashlib.sha256()
+    for r in results:
+        h.update(repr(tuple(getattr(r, f) for f in FIELDS)).encode())
+    if chain_summary is not None:
+        h.update(json.dumps(chain_summary, sort_keys=True).encode())
+    return h.hexdigest()
 
 
-# ---------------------------------------------------- full-sim equality
-def test_equal_oversubscribe_retry_storm():
-    """Saturating cell with queue-mode admission: retries (both
-    capacity-queued and front-door-held), timeouts, and the retry FIFO
-    lane all in play, under the learning policy."""
-    spec = golden_specs()["oversubscribe"]
-    cfg = dataclasses.replace(
-        golden_sim_config("oversubscribe"),
-        admission="queue", admission_headroom=0.5)
-    sim_f, fast = _run_loop("shabari", spec, cfg, legacy=False)
-    sim_l, legacy = _run_loop("shabari", spec, cfg, legacy=True)
-    assert sim_f.events_processed == sim_l.events_processed
-    assert sim_f.router.admission_queue_events > 0  # front-door holds
-    assert any(r.timed_out for r in fast)  # retries actually timed out
-    _assert_field_equal(fast, legacy)
+# ---------------------------------------------------- frozen references
+_SPECS = golden_specs()
+_FLASH_90S = ScenarioSpec(scenario="flash-crowd", rps=2.0, duration_s=90.0,
+                          seed=0)
+# a 4 x 32-vCPU cluster with a bounded retry backlog
+_SMALL = dict(n_workers=4, vcpus_per_worker=32, physical_cores=32,
+              mem_mb_per_worker=16 * 1024, vcpu_limit=32, seed=0,
+              retry_interval_s=1.0, queue_timeout_s=45.0)
 
 
-def test_equal_flash_crowd_sheds():
-    """Shed-mode admission on the spike scenario: terminal front-door
-    drops must land on the same invocations in both loops."""
-    spec = golden_specs()["flash-crowd"]
-    cfg = dataclasses.replace(
-        golden_sim_config("flash-crowd"),
-        admission="shed", admission_headroom=0.5)
-    sim_f, fast = _run_loop("static-large", spec, cfg, legacy=False)
-    sim_l, legacy = _run_loop("static-large", spec, cfg, legacy=True)
-    assert sim_f.router.admission_shed > 0
-    assert any(r.shed for r in fast)
-    _assert_field_equal(fast, legacy)
+def _golden_cfg(scenario, **over):
+    return dataclasses.replace(golden_sim_config(scenario), **over)
 
 
-def test_equal_estimate_routing_warming_binds():
-    """Estimate routing on the multi-cluster golden cell: invocations
-    bound to still-warming containers (pending commits + reservation
-    cancellation on timeout) must replay identically."""
-    spec = golden_specs()["multi-cluster"]
-    cfg = dataclasses.replace(
-        golden_sim_config("multi-cluster"), routing="estimate")
-    sim_f, fast = _run_loop("shabari", spec, cfg, legacy=False)
-    sim_l, legacy = _run_loop("shabari", spec, cfg, legacy=True)
-    assert sim_f.router.binds_warming > 0  # the path is exercised
-    assert sim_f.router.binds_warming == sim_l.router.binds_warming
-    _assert_field_equal(fast, legacy)
+def _check_retry_storm(sim, results, kinds):
+    assert sim.router.admission_queue_events > 0  # front-door holds
+    assert any(r.timed_out for r in results)  # retries actually timed out
 
 
-def test_equal_registry_storm_image_cache():
-    """Registry-storm with the image cache ON (the PR 8/9 gap): layer
-    pulls, LRU evictions, and cache-affinity placement landed after the
-    event-loop A/B matrix was chosen — per-field equality under
-    legacy_event_loop=True closes it."""
-    spec = golden_specs()["registry-storm"]
-    cfg = golden_sim_config("registry-storm")
-    assert cfg.image_cache is not None  # the golden cell keeps it on
-    sim_f, fast = _run_loop("shabari", spec, cfg, legacy=False)
-    sim_l, legacy = _run_loop("shabari", spec, cfg, legacy=True)
-    assert sim_f.events_processed == sim_l.events_processed
-    # the cache subsystem actually fired: layers were pulled somewhere
-    pulls = sum(w.image_cache.misses
-                for cl in sim_f.clusters for w in cl.workers)
-    assert pulls > 0
-    _assert_field_equal(fast, legacy)
+def _check_sheds(sim, results, kinds):
+    assert sim.router.admission_shed > 0
+    assert any(r.shed for r in results)
 
 
-def test_equal_chain_pipeline_spawned_arrivals():
-    """Chain cell: downstream stage arrivals are pushed at t == now via
-    the new "chain_arrival" event kind — the fast loop routes them
-    through the calendar queue (NOT the retry FIFO, whose ordering
-    invariant assumes now + retry_interval_s pushes). Both loops must
-    replay identical results AND identical end-to-end chain metrics."""
-    spec = golden_specs()["chain-pipeline"]
-    cfg = golden_sim_config("chain-pipeline")
-    sim_f, fast = _run_loop("shabari", spec, cfg, legacy=False)
-    sim_l, legacy = _run_loop("shabari", spec, cfg, legacy=True)
-    assert sim_f.chain_summary()["chain_stage_spawned"] > 0
-    assert sim_f.chain_summary() == sim_l.chain_summary()
-    fast = sorted(fast, key=lambda r: r.invocation_id)
-    legacy = sorted(legacy, key=lambda r: r.invocation_id)
-    _assert_field_equal(fast, legacy)
+def _check_binds(sim, results, kinds):
+    assert sim.router.binds_warming > 0
 
 
-def test_legacy_event_loop_golden_is_byte_identical():
-    """The pinned legacy-event-loop snapshot equals the main golden —
-    the two loops are one semantics, not a fork."""
-    for scenario in LEGACY_EVENT_LOOP_SCENARIOS:
-        with open(os.path.join(GOLDEN_DIR, f"{scenario}.json")) as f:
-            main = json.load(f)
-        with open(os.path.join(
-                GOLDEN_DIR, "legacy-event-loop", f"{scenario}.json")) as f:
-            legacy = json.load(f)
-        assert main["summary"] == legacy["summary"]
-        assert main["spec"] == legacy["spec"]
+def _check_pulls(sim, results, kinds):
+    assert sim.cfg.image_cache is not None
+    assert sum(w.image_cache.misses
+               for cl in sim.clusters for w in cl.workers) > 0
 
 
-# ------------------------------------------------ cohort-order parity
+def _check_chains(sim, results, kinds):
+    assert sim.chain_summary()["chain_stage_spawned"] > 0
+    assert kinds["chain_arrival"] > 0
+
+
+def _check_warm_hits(sim, results, kinds):
+    assert any(not r.cold_start and not r.timed_out for r in results)
+    assert any(r.timed_out for r in results)
+
+
+def _check_retimes(sim, results, kinds):
+    # co-runner starts and finishes re-queued finish events
+    assert kinds["finish"] > 2 * len(results)
+
+
+def _check_xfer(sim, results, kinds):
+    assert kinds["xfer_start"] > 0
+
+
+# cell -> (policy, spec, config, check, n results, events_processed,
+# sha256 of _digest)
+CELLS = {
+    # saturating cell with queue-mode admission: capacity-queued and
+    # front-door-held retries, timeouts and the retry FIFO lane, under
+    # the learning policy
+    "oversubscribe-retry-storm": (
+        "shabari", _SPECS["oversubscribe"],
+        _golden_cfg("oversubscribe", admission="queue",
+                    admission_headroom=0.5),
+        _check_retry_storm, 502, 16219,
+        "04c6a91e6ef5c7ad8b60d492de3b9ff99e2be5b20d9840d159254a833310e490"),
+    # shed-mode admission on the spike: terminal front-door drops
+    "flash-crowd-sheds": (
+        "static-large", _SPECS["flash-crowd"],
+        _golden_cfg("flash-crowd", admission="shed", admission_headroom=0.5),
+        _check_sheds, 768, 928,
+        "5ffaba9086400d9e299aba436478d212c610edb76df90b2c48984a682393c23b"),
+    # estimate routing: invocations bound to still-warming containers
+    # (pending commits, reservation cancellation on timeout)
+    "estimate-routing-warming-binds": (
+        "shabari", _SPECS["multi-cluster"],
+        _golden_cfg("multi-cluster", routing="estimate"),
+        _check_binds, 561, 7032,
+        "4d54ce0d0503d69827b8829ed2651c4da0fed68f6b77b098ca0584b11cf6c82e"),
+    # image cache on: layer pulls, LRU evictions, affinity placement
+    "registry-storm-image-cache": (
+        "shabari", _SPECS["registry-storm"], _golden_cfg("registry-storm"),
+        _check_pulls, 426, 11225,
+        "909a407da78254b7a4c5a0ab77b2fe213331e5bf325452547b60365dbf68b269"),
+    # downstream stage arrivals pushed at t == now as "chain_arrival"
+    # (through the calendar queue, never the retry lane)
+    "chain-pipeline-spawned-arrivals": (
+        "shabari", _SPECS["chain-pipeline"], _golden_cfg("chain-pipeline"),
+        _check_chains, 522, 1206,
+        "9eb40dca68d91149dacb4b5ce4ea20ed21e81cecb46b154539291125cf8aefef"),
+    # the per-worker contention aggregates and the warm-container index
+    # against the scans they replaced
+    "flash-crowd-warm-index": (
+        "shabari", _FLASH_90S, SimConfig(**_SMALL),
+        _check_warm_hits, 986, 29830,
+        "fa467f99c13094488473fac1e9e4bc5ee9f6d64ddd129b0fdd9864913d64e24c"),
+    # dynamic contention re-queues finish events as co-runners come and
+    # go (vcpu_limit above the cores, so contention exists at all)
+    "dynamic-contention": (
+        "shabari", _FLASH_90S,
+        SimConfig(**{**_SMALL, "vcpu_limit": 44}, contention_mode="dynamic"),
+        _check_retimes, 986, 31201,
+        "e2458514775725a31e470b52a26a584b7deedf7a9f52fe1d4faf2a26ae7fde57"),
+    # SLO-native admission sheds work that cannot meet its budget
+    "oversubscribe-slo-admission": (
+        "shabari", _SPECS["oversubscribe"],
+        _golden_cfg("oversubscribe", admission="slo"),
+        _check_sheds, 502, 1876,
+        "a9e0f8443f6170b4d5c96f10b77cf3ff45b5b5e4345b786d4337c098e922bfd6"),
+    # load-headroom shedding across two clusters behind the router
+    "multi-cluster-shed-admission": (
+        "shabari", _SPECS["multi-cluster"],
+        _golden_cfg("multi-cluster", admission="shed"),
+        _check_sheds, 561, 2401,
+        "8c8f872e6bf46f3aca8300be1274dc7195aa961e726bf4316515d0b1f195a19e"),
+    # remote warm placements wait for their payload: xfer_start events
+    "wan-spill-xfer-start": (
+        "shabari", _SPECS["wan-spill"], _golden_cfg("wan-spill"),
+        _check_xfer, 561, 11309,
+        "0516b915c2d4ec692d6228d664e9b0bfc7cb279bcdefc63f6c6eece0d41418b1"),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_loop_matches_frozen_reference(cell):
+    policy, spec, cfg, check, n, events, sha = CELLS[cell]
+    sim, results, kinds = _run_loop(policy, spec, cfg)
+    check(sim, results, kinds)
+    assert (len(results), sim.events_processed) == (n, events)
+    assert _digest(results, sim.chain_summary()) == sha
+
+
+# ------------------------------------------------ cohort partition
 def _record_cohorts(sim):
     """Record (a) the flattened order every arrival is processed in and
     (b) the multi-payload cohort partitions handed to the policy batch
     hook. Singleton cohorts are equivalent to a direct ``_on_arrival``
-    call (the batch hook only fires for len > 1), and the fast loop
-    exploits that by dispatching lone retries directly — so only the
+    call (the batch hook only fires for len > 1), and the loop exploits
+    that by dispatching lone retries directly — so only the
     multi-payload partitions are pinned, plus the total order."""
     orig_cohort = sim._process_arrival_cohort
     orig_arrival = sim._on_arrival
@@ -187,31 +237,40 @@ def _record_cohorts(sim):
 
 def test_same_timestamp_cohorts_partition_identically():
     """Fresh arrivals sharing a timestamp form one cohort; retries
-    landing on that timestamp extend it in seq order. Both loops must
-    process arrivals in the same total order and feed the policy the
-    same multi-payload (t, ids) partitions."""
+    landing on that timestamp extend it in seq order. The total order
+    and the multi-payload (t, ids) partitions match the frozen
+    reference, recorded from this loop and a single global heapq loop,
+    which agreed. static-large's allocation never fits the one 8-vCPU
+    worker, so all five invocations retry until their 300 s timeout."""
     profiles, pool, slo = _build_stack()
-    fn = "lrtrain"  # ~2.5 s at 8 vCPUs: serializes a 1-worker cluster
+    fn = "lrtrain"
     trace = [Arrival(0, 0.0, fn, 0),
              Arrival(1, 1.0, fn, 0), Arrival(2, 1.0, fn, 0),
              # collides with the t=1.5 retries of invocations 1 and 2
              Arrival(3, 1.5, fn, 0),
              Arrival(4, 9.0, fn, 0)]
-    orders, cohorts = {}, {}
-    for legacy in (False, True):
-        cfg = SimConfig(n_workers=1, vcpus_per_worker=8, physical_cores=8,
-                        mem_mb_per_worker=4096, vcpu_limit=8,
-                        retry_interval_s=0.5, queue_timeout_s=300.0,
-                        seed=0, legacy_event_loop=legacy)
-        pol = make_policy("static-large", profiles, pool, slo, seed=0)
-        sim = Simulator(policy=pol, profiles=profiles, input_pool=pool,
-                        slo_table=slo, cfg=cfg)
-        orders[legacy], cohorts[legacy] = _record_cohorts(sim)
-        sim.run(list(trace))
-    assert orders[False] == orders[True]
-    assert cohorts[False] == cohorts[True]
+    cfg = SimConfig(n_workers=1, vcpus_per_worker=8, physical_cores=8,
+                    mem_mb_per_worker=4096, vcpu_limit=8,
+                    retry_interval_s=0.5, queue_timeout_s=300.0, seed=0)
+    pol = make_policy("static-large", profiles, pool, slo, seed=0)
+    sim = Simulator(policy=pol, profiles=profiles, input_pool=pool,
+                    slo_table=slo, cfg=cfg)
+    order, cohorts = _record_cohorts(sim)
+    sim.run(list(trace))
+    assert sim.events_processed == 3016
+    assert len(order) == 3010 and len(cohorts) == 603
+    assert order[:10] == [(0.0, 0), (0.5, 0), (1.0, 1), (1.0, 2), (1.0, 0),
+                          (1.5, 3), (1.5, 1), (1.5, 2), (1.5, 0), (2.0, 3)]
+    assert cohorts[:3] == [(1.0, (1, 2, 0)), (1.5, (3, 1, 2, 0)),
+                           (2.0, (3, 1, 2, 0))]
+    assert cohorts[-3:] == [(301.0, (4, 3, 1, 2)), (301.5, (4, 3, 1, 2)),
+                            (302.0, (4, 3))]
+    assert hashlib.sha256(repr(order).encode()).hexdigest() == (
+        "62a6408f08f77790614ef54bcc1a4d7701200dae436359a37fc17a4ce0e67552")
+    assert hashlib.sha256(repr(cohorts).encode()).hexdigest() == (
+        "b1706668ceaca018442c983054b6f5861a8e45cf95f73e5e1c17607dcb9ebb8e")
     # the trace actually produced a mixed fresh+retry cohort at t=1.5
-    mixed = [ids for t, ids in cohorts[False] if t == 1.5]
+    mixed = [ids for t, ids in cohorts if t == 1.5]
     assert mixed and set(mixed[0]) >= {1, 2, 3}
     # fresh arrival 3 (virtual seq < any retry seq) leads its cohort
     assert mixed[0][0] == 3

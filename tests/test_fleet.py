@@ -4,8 +4,7 @@ Three layers of coverage:
 
 * the homogeneous-default EQUIVALENCE contract — an explicit uniform
   FleetSpec with free links reproduces the committed goldens
-  byte-for-byte (the same A/B discipline as legacy_scans/legacy_acquire,
-  here asserted with exact equality, not tolerance);
+  byte-for-byte (asserted with exact equality, not tolerance);
 * unit behavior of the new vocabulary — Topology transfer math,
   per-machine cold curves, per-worker §5 contention/NIC denominators,
   exec-speed factors, preemptible-last cold placement, clone-pooled

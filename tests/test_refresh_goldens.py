@@ -20,7 +20,7 @@ from repro.serving.golden import ATOL, GOLDEN_POLICY, RTOL, golden_specs
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SCRIPT = os.path.join(REPO, "scripts", "refresh_goldens.py")
 GOLDEN_DIR = os.path.join(REPO, "tests", "goldens")
-SCENARIO = "poisson-steady"  # cheapest member of LEGACY_ACQUIRE_SCENARIOS
+SCENARIO = "multi-cluster"  # also snapshotted under estimate-routing/
 
 
 def _assert_matches_committed(emitted_path: str, committed_path: str) -> dict:
@@ -56,11 +56,16 @@ def test_refresh_goldens_round_trip(tmp_path):
         str(tmp_path / f"{SCENARIO}.json"),
         os.path.join(GOLDEN_DIR, f"{SCENARIO}.json"),
     )
-    # the acquire-on-placement A/B snapshot rides along for this scenario
+    # the estimate-routing A/B snapshot rides along for this scenario
     _assert_matches_committed(
-        str(tmp_path / "legacy-acquire" / f"{SCENARIO}.json"),
-        os.path.join(GOLDEN_DIR, "legacy-acquire", f"{SCENARIO}.json"),
+        str(tmp_path / "estimate-routing" / f"{SCENARIO}.json"),
+        os.path.join(GOLDEN_DIR, "estimate-routing", f"{SCENARIO}.json"),
     )
+    # and nothing else is written
+    written = sorted(str(p.relative_to(tmp_path))
+                     for p in tmp_path.rglob("*.json"))
+    assert written == [f"estimate-routing/{SCENARIO}.json",
+                       f"{SCENARIO}.json"]
 
 
 def test_refresh_goldens_rejects_unknown_scenario(tmp_path):

@@ -3,15 +3,9 @@
 Covers the resource-lifecycle change (capacity reserved when a cold
 start is PLACED, not when it starts): worker/cluster accounting,
 ``Worker.fits`` and ``Router._load`` seeing committed-but-warming
-capacity, conversion/cancellation of reservations, the
-``SimConfig(legacy_acquire=True)`` A/B (pinned against the
-tests/goldens/legacy-acquire/ snapshots), and front-door admission
-control (shed / queue) under fleet-wide overload.
+capacity, conversion/cancellation of reservations, and front-door
+admission control (shed / queue) under fleet-wide overload.
 """
-
-import json
-import math
-import os
 
 import pytest
 
@@ -21,19 +15,9 @@ from repro.core.router import Router
 from repro.core.scheduler import ShabariScheduler
 from repro.serving import baselines as B
 from repro.serving.experiment import make_policy, run_scenario
-from repro.serving.golden import (
-    ATOL,
-    LEGACY_ACQUIRE_SCENARIOS,
-    RTOL,
-    run_golden,
-)
 from repro.serving.profiles import build_input_pool, build_profiles
 from repro.serving.simulator import SimConfig, Simulator
 from repro.serving.workload import Arrival, ScenarioSpec
-
-LEGACY_GOLDEN_DIR = os.path.join(
-    os.path.dirname(__file__), "goldens", "legacy-acquire"
-)
 
 
 # ------------------------------------------------- worker-level accounting
@@ -137,16 +121,6 @@ def test_second_cold_start_not_stacked_onto_reserved_worker(stack):
     assert sim.cluster.reserved_vcpus == 24
 
 
-def test_legacy_acquire_defers_to_start_and_stacks(stack):
-    sim, fn = _sim(stack, legacy_acquire=True)
-    sim._on_arrival(Arrival(0, 0.0, fn, 0), 0.0)
-    assert sim.cluster.used_vcpus == 0  # free-looking while warming
-    sim._on_arrival(Arrival(1, 0.0, fn, 0), 0.0)
-    workers = {c.worker.wid
-               for w in sim.cluster.workers for c in w.containers.values()}
-    assert len(workers) == 1  # both cold starts herd onto the home worker
-
-
 def test_reservation_converts_and_releases_through_full_run(stack):
     sim, fn = _sim(stack)
     results = sim.run([Arrival(0, 0.0, fn, 0), Arrival(1, 0.5, fn, 1)])
@@ -167,15 +141,6 @@ def test_reservation_released_when_cold_start_outlives_timeout(stack):
     # the warmed container survives as idle warm capacity
     (c,) = [c for w in sim.cluster.workers for c in w.containers.values()]
     assert not c.busy and not c.reserved
-
-
-def test_legacy_acquire_runs_late_cold_start(stack):
-    # same sub-cold-latency timeout under legacy accounting: no
-    # reservation exists, so the invocation still runs (the pre-change
-    # semantics the A/B switch must preserve)
-    sim, fn = _sim(stack, queue_timeout_s=0.05, legacy_acquire=True)
-    results = sim.run([Arrival(0, 0.0, fn, 0)])
-    assert len(results) == 1 and not results[0].timed_out
 
 
 # --------------------------------------------------------- admission control
@@ -253,24 +218,3 @@ def test_admission_queue_end_to_end_sheds_nothing():
     )
     assert res.summary["shed_pct"] == 0.0
     assert res.summary["n"] > 0
-
-
-# ----------------------------------------------------- legacy golden pinning
-@pytest.mark.parametrize("scenario", LEGACY_ACQUIRE_SCENARIOS)
-def test_legacy_acquire_reproduces_legacy_goldens(scenario):
-    """SimConfig(legacy_acquire=True) must keep reproducing the
-    pre-reservation metrics, pinned under tests/goldens/legacy-acquire/
-    (regenerated alongside the main goldens by refresh_goldens.py)."""
-    path = os.path.join(LEGACY_GOLDEN_DIR, f"{scenario}.json")
-    assert os.path.exists(path), (
-        f"missing legacy-acquire snapshot {path}; run refresh_goldens.py"
-    )
-    with open(path) as f:
-        want = json.load(f)["summary"]
-    got = run_golden(scenario, legacy_acquire=True)
-    assert set(got) == set(want)
-    for key, expect in want.items():
-        assert math.isclose(got[key], expect, rel_tol=RTOL, abs_tol=ATOL), (
-            f"legacy-acquire {scenario}.{key}: got {got[key]!r}, "
-            f"golden {expect!r}"
-        )

@@ -1,12 +1,11 @@
 """Retry/timeout-path tests: the policy is consulted exactly once per
 invocation regardless of retries, timed-out invocations never touch it,
-queue accounting is recorded, and the legacy A/B toggle restores the
-pre-fix behavior."""
+and queue accounting is recorded."""
 
 from repro.core.allocator import Allocation
 from repro.serving import baselines as B
 from repro.serving.profiles import build_input_pool, build_profiles
-from repro.serving.simulator import Policy, SimConfig, Simulator, summarize
+from repro.serving.simulator import Policy, SimConfig, Simulator
 from repro.serving.workload import Arrival
 
 FN = "lrtrain"  # ~2.5 s at 8 vCPUs on its smallest input
@@ -103,35 +102,3 @@ def test_timed_out_invocations_release_cached_features():
     _, results = _run(pol, arrivals, cfg)
     assert sum(r.timed_out for r in results) == 7
     assert not pol._features
-
-
-def test_legacy_retry_alloc_restores_per_retry_predicts():
-    pol = CountingPolicy()
-    cfg = _one_worker_cfg(legacy_retry_alloc=True)
-    arrivals = [Arrival(0, 0.0, FN, 0)] + [
-        Arrival(i, 1.5, FN, 0) for i in range(1, 6)
-    ]
-    _, results = _run(pol, arrivals, cfg)
-    assert len(results) == 6
-    # the pre-fix path re-runs allocate on every retry
-    assert max(pol.calls.values()) > 1
-
-
-def test_retry_cache_metric_neutral_for_non_queued_invocations():
-    """With a deterministic-allocation policy the fix is a pure fast
-    path: metrics identical to the legacy retry path even under
-    saturation (same alloc on every retry), and trivially so when
-    nothing ever queues."""
-    for arrivals in (
-        [Arrival(i, 10.0 * i, FN, 0) for i in range(4)],      # no queueing
-        [Arrival(0, 0.0, FN, 0)] + [
-            Arrival(i, 1.5, FN, 0) for i in range(1, 6)       # retry storm
-        ],
-    ):
-        summaries = []
-        for legacy in (False, True):
-            pol = CountingPolicy()
-            cfg = _one_worker_cfg(legacy_retry_alloc=legacy)
-            _, results = _run(pol, arrivals, cfg)
-            summaries.append(summarize(results))
-        assert summaries[0] == summaries[1]

@@ -1,7 +1,5 @@
 """Scenario-engine tests: registry coverage, trace shape, determinism,
-and the incremental-core equivalence/dynamic-contention properties."""
-
-import dataclasses
+and the dynamic-contention properties."""
 
 import numpy as np
 import pytest
@@ -113,19 +111,6 @@ def test_scenario_simulation_deterministic():
         s1 = run_scenario("shabari", spec, sim_cfg=SimConfig(**SMALL_CFG))
         s2 = run_scenario("shabari", spec, sim_cfg=SimConfig(**SMALL_CFG))
         assert s1.summary == s2.summary, scenario
-
-
-def test_incremental_matches_legacy_scans():
-    """The incremental per-worker aggregates + warm-container index are
-    a pure fast path: metrics identical to the pre-refactor scans."""
-    spec = ScenarioSpec(scenario="flash-crowd", rps=2.0, duration_s=90.0,
-                        seed=0)
-    fast = run_scenario(
-        "shabari", spec, sim_cfg=SimConfig(**SMALL_CFG)).summary
-    legacy = run_scenario(
-        "shabari", spec,
-        sim_cfg=SimConfig(**SMALL_CFG, legacy_scans=True)).summary
-    assert fast == legacy
 
 
 def test_dynamic_contention_mode():
