@@ -11,6 +11,13 @@ simulator and policy, as ``repro.serving.experiment.build_simulator``
 builds them, and runs the cell's whole trace with ``Simulator.run``.
 Passes run back to back until the window's seconds are spent; the pass
 in flight then is stopped at its next policy or router call.
+
+The result line's ``attempted`` and ``failed`` count one whole pass, the
+first: the seed's trace and those of its invocations that were shed,
+timed out or OOM-killed. Every later whole pass replays the same trace
+and is checked equal to the first (``pass_disagreements``, limit 0), so
+summing over passes would only weight the seed's failure share by how
+many passes the window held, that is, by the program's speed.
 """
 
 from __future__ import annotations
@@ -343,8 +350,8 @@ def main(argv=None, t_start: Optional[float] = None) -> int:
     dev = {"platform": device.platform, "kind": device.device_kind,
            "count": jax.device_count(), "memory_peak_bytes": peak_bytes}
     out = {"correct": correct,
-           "attempted": sum(q["n"] for q in qualities),
-           "failed": sum(q["failed"] for q in qualities),
+           "attempted": qualities[0]["n"],
+           "failed": qualities[0]["failed"],
            "metrics": metrics, "device": dev}
     if trace_red is not None:
         dev["busy_s"] = trace_red.get("busy_s", 0.0)
@@ -352,6 +359,8 @@ def main(argv=None, t_start: Optional[float] = None) -> int:
         out["breakdown"] = {"device_ops": trace_red.get("top_ops", []),
                             "idle_gaps": trace_red.get("idle_by_span", [])}
     say(f"quality of the first pass: {qualities[0]}")
+    say(f"failures: {qualities[0]['failed']} of {qualities[0]['n']} in one "
+        f"whole pass, the first of {len(passes)}")
     for k in checks:
         say(f"check {k}: {checks[k]!r} (limit {limits[k]!r})")
     out["checks"] = {k: {"value": checks[k], "limit": limits[k]} for k in checks}

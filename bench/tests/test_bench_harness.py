@@ -1,6 +1,7 @@
 """Whole runs of the harness on the CPU, with the look for a chip
-patched out: the result line's shape, whole passes, faults planted under
-the timed path, a cell added by files alone, and the refusals."""
+patched out: the result line's shape, its counts of one whole pass,
+faults planted under the timed path, a cell added by files alone, and
+the refusals."""
 
 import json
 import os
@@ -13,6 +14,7 @@ import jax.numpy as jnp
 import pytest
 
 from bench import harness, peaks, traffic
+from bench.spans import Probe
 from repro import compile_cache
 from repro.core import agent_arena
 from repro.core.agent_arena import ArenaEngine
@@ -31,6 +33,23 @@ def on_cpu(monkeypatch):
     monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "off")
 
 
+class OnePass(Probe):
+    """Ends the window after its first ``whole`` passes, whatever the
+    clock says: those run to their end however long they take, and the
+    next is stopped at its first call."""
+    whole = 1
+    passes = 0
+
+    def instrument(self, sim):
+        self.passes += 1
+        self.deadline = float("inf") if self.passes <= self.whole else 0.0
+        super().instrument(sim)
+
+
+class TwoPasses(OnePass):
+    whole = 2
+
+
 def _run(capsys, workload, seconds=2, trace=0, seed=2**31 + 5):
     rc = harness.main(["--workload", workload, "--seed", str(seed),
                        "--seconds", str(seconds), "--trace", str(trace)])
@@ -41,13 +60,17 @@ def _run(capsys, workload, seconds=2, trace=0, seed=2**31 + 5):
 
 @pytest.mark.parametrize("trace", [0, 1])
 @pytest.mark.parametrize("workload,per_pass", [("testbed.azure", 1200)])
-def test_result_line(on_cpu, capsys, trace, workload, per_pass):
+def test_result_line(on_cpu, capsys, monkeypatch, trace, workload,
+                     per_pass):
+    # one whole pass, so that a loaded machine cannot end the window
+    # with none
+    monkeypatch.setattr(harness, "Probe", OnePass)
     rc, res, err = _run(capsys, workload, trace=trace)
     assert rc == 0, err
     assert list(res) == REQUIRED + (["breakdown"] if trace else []) + ["checks"]
     assert res["correct"] is True, res["checks"]
-    # attempted counts whole passes only
-    assert res["attempted"] > 0 and res["attempted"] % per_pass == 0
+    # attempted and failed count the first whole pass
+    assert res["attempted"] == per_pass
     assert 0 <= res["failed"] < res["attempted"]
     key = "per_layer" if trace else "end_to_end"
     names = {m["name"] for m in harness.load_json(
@@ -83,6 +106,31 @@ def _answer_altered(monkeypatch):
         return (None if v is None else (v + 1) % self.n_vcpu_classes), m
 
     monkeypatch.setattr(ArenaEngine, "predict", predict)
+
+
+def _mem_class_down(monkeypatch, shift=2):
+    """Every served memory class moved ``shift`` classes down (not below
+    the first), where the engine's outermost predict call returns it."""
+    def down(vm):
+        v, m = vm
+        return v, (None if m is None else max(m - shift, 0))
+
+    depth = [0]
+
+    def outermost(orig, fix):
+        def call(self, *args):
+            depth[0] += 1
+            try:
+                out = orig(self, *args)
+            finally:
+                depth[0] -= 1
+            return out if depth[0] else fix(out)
+        return call
+
+    monkeypatch.setattr(ArenaEngine, "predict",
+                        outermost(ArenaEngine.predict, down))
+    monkeypatch.setattr(ArenaEngine, "predict_batch", outermost(
+        ArenaEngine.predict_batch, lambda out: [down(vm) for vm in out]))
 
 
 def _one_dim_unchanged(monkeypatch):
@@ -159,7 +207,8 @@ def _updates_lost(monkeypatch):
 
 
 @pytest.mark.parametrize("fault", [_state_unchanged, _half_batch,
-                                   _answer_altered, _one_dim_unchanged,
+                                   _answer_altered, _mem_class_down,
+                                   _one_dim_unchanged,
                                    _one_dim_half, _bf16_dots, _updates_lost])
 @pytest.mark.parametrize("workload", ["testbed.azure"])
 def test_faults_under_the_timed_path_are_not_correct(on_cpu, capsys,
@@ -169,6 +218,42 @@ def test_faults_under_the_timed_path_are_not_correct(on_cpu, capsys,
     # long enough for one whole pass of a fault's slower path on the CPU
     rc, res, err = _run(capsys, workload, seconds=8)
     assert rc == 0, err
+    assert res["correct"] is False
+    assert any(c["value"] > c["limit"] for c in res["checks"].values())
+
+
+@pytest.mark.parametrize("workload", ["testbed.azure", "pop384.azure"])
+def test_counts_are_one_pass_however_many_the_window_holds(
+        on_cpu, capsys, monkeypatch, workload):
+    """The same seed with one and with two whole passes in the window
+    reports the same ``attempted`` and ``failed``: the first pass's."""
+    runs = []
+    for probe, whole in ((OnePass, 1), (TwoPasses, 2)):
+        monkeypatch.setattr(harness, "Probe", probe)
+        rc, res, err = _run(capsys, workload)
+        assert rc == 0, err
+        assert res["correct"] is True, res["checks"]
+        assert f"{whole} whole passes," in err
+        assert f"in one whole pass, the first of {whole}" in err
+        runs.append((res["attempted"], res["failed"]))
+    assert runs[0] == runs[1]
+    assert runs[0][0] == 1200
+
+
+def test_a_memory_fault_raises_failed(on_cpu, capsys, monkeypatch):
+    """Served memory two classes too small OOM-kills what it serves: at
+    seed 2**31 + 5 in ``testbed.azure`` one pass fails 38 invocations
+    (4 OOM kills) unfaulted and 496 (450 OOM kills) faulted, on the CPU.
+    The count of one pass sees a program that fails more."""
+    monkeypatch.setattr(harness, "Probe", OnePass)
+    rc, clean, err = _run(capsys, "testbed.azure")
+    assert rc == 0, err
+    assert clean["correct"] is True, clean["checks"]
+    _mem_class_down(monkeypatch)
+    rc, res, err = _run(capsys, "testbed.azure")
+    assert rc == 0, err
+    assert res["attempted"] == clean["attempted"] == 1200
+    assert res["failed"] > clean["failed"]
     assert res["correct"] is False
     assert any(c["value"] > c["limit"] for c in res["checks"].values())
 

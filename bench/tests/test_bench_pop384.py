@@ -18,26 +18,15 @@ import json
 
 import jax
 import pytest
-from test_bench_harness import _bf16_dots
+from test_bench_harness import OnePass, _bf16_dots
 
 from bench import harness, peaks, program
-from bench.spans import Probe
 from repro import compile_cache, spans
 from repro.core import agent_arena
 
 BLOCK = agent_arena._MAX_BUCKET
 ARENA_SHARES = ("arena_transfer_share_pct", "arena_launch_share_pct",
                 "arena_host_share_pct")
-
-
-class OnePass(Probe):
-    """Ends the window after its first pass, whatever the clock says."""
-    passes = 0
-
-    def instrument(self, sim):
-        self.passes += 1
-        self.deadline = float("inf") if self.passes == 1 else 0.0
-        super().instrument(sim)
 
 
 def _v5e_on_cpu(mp):
