@@ -53,8 +53,11 @@ from repro.serving.simulator import SimConfig, summarize  # noqa: E402
 TIE_RTOL = 1e-4
 WEIGHT_RTOL = 1e-3
 
-KERNELS = ("_csc_predict", "_csc_update", "_batched_update",
-           "_batched_predict")
+# what the arena calls once a launch: the per-row kernels, and the
+# launches of the block kernels on a staged array, which take the kernel
+# first
+KERNELS = ("_csc_predict", "_csc_update", "_staged_update",
+           "_staged_predict")
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -93,7 +96,8 @@ def counting_kernels():
         def counted(*args, **kwargs):
             out = fn(*args, **kwargs)
             # w's last axis is dim + 1
-            counter.calls[(name, args[0].shape[-1] - 1)] += 1
+            w = args[1] if name.startswith("_staged") else args[0]
+            counter.calls[(name, w.shape[-1] - 1)] += 1
             platforms = {d.platform for leaf in jax.tree.leaves(out)
                          for d in leaf.devices()}
             counter.platforms["+".join(sorted(platforms))] += 1

@@ -26,16 +26,23 @@ overhead wall the paper measures in Fig. 14. The arena fuses them:
   makes served allocations bit-identical to the sequential path: updates
   touching distinct rows commute exactly (disjoint state), and same-row
   updates are applied in arrival order via conflict-free passes.
-* **Masked block dispatches** — on resident state a flush pass copies
-  in, per touched block, a ``(16, dim+1)`` input and a ``(16,
-  n_vcpu + n_mem)`` cost matrix with the pass's rows at their slot
-  offsets and zeros elsewhere, launches :data:`_batched_update` (buffers
-  donated) on the whole block and reads nothing back: a zero input row
-  is an exact no-op (zero gradient, so ``w`` and ``g2`` keep their
-  bits). A predict launches :data:`_batched_predict` once per touched
-  block, reads all its cost blocks in one transfer and takes the
-  arg-min of each wanted resource's classes on the host. The kernels
-  are looked up at call time and never wrapped in a further ``jit`` (a
+* **Masked block dispatches** — on resident state a flush pass stages,
+  per touched block, a 16-row slab with the pass's inputs (``dim+1``
+  columns) and stacked ``n_vcpu + n_mem`` costs at their slot offsets
+  and zeros elsewhere, all slabs in one fixed-shape host array (one more
+  per further ``_STAGE_BLOCKS`` blocks) handed to one ``device_put``
+  (:func:`_stage`). It then launches :data:`_batched_update` (buffers
+  donated) once per touched block, on the whole block, and reads nothing
+  back: a zero input row is an exact no-op (zero gradient, so ``w`` and
+  ``g2`` keep their bits). A predict stages its inputs the same way,
+  launches :data:`_batched_predict` once per touched block, reads all
+  its cost blocks in one transfer and takes the arg-min of each wanted
+  resource's classes on the host. Each launch picks its block out of
+  the staged array inside its own program (:func:`_staged_update`,
+  :func:`_staged_predict`), at a position held as a device scalar, so
+  a call's copy-in is one host array whatever the blocks it touches, and
+  its shapes, hence its programs, do not follow them. The kernels are
+  looked up at call time; no program gathers or scatters state rows (a
   fused gather/update/scatter program is not bit-identical to the
   reference on the CPU). The engine does not consult
   :func:`vmap_backend`: where the batched kernel differs from the
@@ -143,6 +150,45 @@ def _batched_predict(w, xb):
 
 # rows of a resident block: every device dispatch has this many rows
 _MAX_BUCKET = 16
+# blocks a staged host array holds (see _stage): a fixed shape, so that
+# the number of blocks a call touches compiles nothing new
+_STAGE_BLOCKS = 8
+# input columns of a staged block: the widest feature schema (6
+# features) and its bias
+_XB_COLS = 7
+
+
+# A launch on resident state reads its block's inputs out of its call's
+# staged array inside its own program (a slice outside would itself be a
+# dispatch), at a position ``k`` that is a device scalar in range. The
+# kernel is a static argument that the caller looks up at call time, so
+# a kernel patched into the module is the one that runs. The columns are
+# sliced before the block is picked: on the CPU that keeps the kernel's
+# codegen, and its bits, those of a call on the block alone.
+def _block(staged, cols: slice, k):
+    return jax.lax.dynamic_index_in_dim(staged[..., cols], k, keepdims=False,
+                                        allow_negative_indices=False)
+
+
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
+def _staged_update(kernel, w, g2, staged, k, lr):
+    """``kernel`` on the block at ``k`` of ``staged``: its inputs in the
+    first ``dim+1`` columns, its costs in the last ``n_classes``."""
+    return kernel(w, g2, _block(staged, slice(None, w.shape[-1]), k),
+                  _block(staged, slice(-w.shape[1], None), k), lr)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _staged_predict(kernel, w, staged, k):
+    """``kernel`` on the inputs of the block at ``k`` of ``staged``."""
+    return kernel(w, _block(staged, slice(None, w.shape[-1]), k))
+
+
+@functools.lru_cache(maxsize=None)
+def _positions() -> Tuple[jax.Array, ...]:
+    """The positions of a staged array's blocks as device int32 scalars,
+    made once: a Python int would be copied in at every launch."""
+    return tuple(jax.device_put([np.int32(k) for k in range(_STAGE_BLOCKS)]))
 
 
 # ---------------------------------------------------------------------------
@@ -343,22 +389,30 @@ def numpy_backend(dim: int) -> bool:
 
 @functools.lru_cache(maxsize=None)
 def vmap_backend(dim: int) -> bool:
-    """True iff the vmapped batched kernels, on a resident block whose
-    rows stack 32 vCPU and 40 memory classes, match per-agent reference
-    calls on each half bitwise (they do on CPU XLA; a diagnostic the
-    benchmark prints, not a switch)."""
+    """True iff the vmapped batched kernels, launched as the resident
+    path launches them on a block whose rows stack 32 vCPU and 40 memory
+    classes, match per-agent reference calls on each half bitwise (they
+    do on CPU XLA; a diagnostic the benchmark prints, not a switch). The
+    programs it compiles are the ones the resident path runs."""
     rng = np.random.default_rng(0xBA7C)
     lr = F32(0.5)
     k = _MAX_BUCKET
     W, G2, X, C = (np.stack(a) for a in zip(
         *[_reference_pair(rng, 32 + 40, dim) for _ in range(k)]))
-    XB = np.concatenate([X, np.ones((k, 1), F32)], axis=1)
-    # copies: _batched_update donates its first two buffers
-    bw, bg = (np.asarray(a) for a in _batched_update(
-        jnp.asarray(W), jnp.asarray(G2), jnp.asarray(XB),
-        jnp.asarray(C), jnp.asarray(lr),
-    ))
-    bc = np.asarray(_batched_predict(jnp.asarray(W), jnp.asarray(XB)))
+    # the block staged at position 0 of an update's and a predict's
+    # staged array, as _stage lays them out
+    xcols = max(_XB_COLS, dim + 1)
+    staged = np.zeros((_STAGE_BLOCKS, k, xcols + 32 + 40), F32)
+    staged[0, :, :dim] = X
+    staged[0, :, dim] = 1.0
+    staged[0, :, xcols:] = C
+    k0 = _positions()[0]
+    # copies: the update donates its first two buffers
+    bw, bg = (np.asarray(a) for a in _staged_update(
+        _batched_update, jnp.asarray(W), jnp.asarray(G2),
+        jnp.asarray(staged), k0, jnp.asarray(lr)))
+    bc = np.asarray(_staged_predict(_batched_predict, jnp.asarray(W),
+                                    jnp.asarray(staged[..., :xcols]), k0))
     for i in range(k):
         for h in (slice(0, 32), slice(32, 72)):
             rw, rg = _csc_update(
@@ -532,47 +586,57 @@ class AgentArena:
 
 
 def _stage(groups):
-    """Copy in the inputs of resident dispatches, all in one
-    ``device_put``. Per ``(arena, functions, xbs, costs or None)`` group
-    (functions distinct) and touched block: ``XB (16, dim+1)`` and ``C
-    (16, n_classes)`` with the group's rows at their slot offsets, zeros
-    elsewhere. Returns ``(group, arena, block, js, rs, XB, C)`` per
-    block: ``js`` index the group's rows, ``rs`` their offsets in the
-    block."""
-    host: List[np.ndarray] = []
+    """Copy in the inputs of resident dispatches: every touched block's
+    rows in one host array, handed to one ``device_put``.
+
+    Per ``(arena, functions, xbs, costs or None)`` group (functions
+    distinct) and touched block, a ``(16, width)`` slab holds the
+    group's inputs in its first ``dim+1`` columns and, for an update,
+    its costs in its last ``n_classes``, with the rows at their slot
+    offsets and zeros elsewhere. A host array holds ``_STAGE_BLOCKS``
+    slabs; a call touching more blocks copies in one more array for each
+    further ``_STAGE_BLOCKS``, in the same ``device_put``. Returns
+    ``(group, arena, block, js, rs, staged, k)`` per block: ``js`` index
+    the group's rows, ``rs`` their offsets in the block, ``staged`` is
+    the device array that holds the block's slab and ``k`` its position
+    there, a device scalar."""
     todo = []
-    for g, (ar, fns, xbs, costs) in enumerate(groups):
+    for g, (ar, fns, _, _) in enumerate(groups):
         by_block: Dict[int, Tuple[List[int], List[int]]] = {}
         for j, fn in enumerate(fns):
             b, r = divmod(ar.slot(fn), _MAX_BUCKET)
             js, rs = by_block.setdefault(b, ([], []))
             js.append(j)
             rs.append(r)
-        for b, (js, rs) in by_block.items():
-            XB = np.zeros((_MAX_BUCKET, ar.dim + 1), F32)
-            XB[rs] = xbs[js]
-            kx = len(host)
-            host.append(XB)
-            kc = None
-            if costs is not None:
-                C = np.zeros((_MAX_BUCKET, ar.n_classes), F32)
-                C[rs] = costs[js]
-                kc = len(host)
-                host.append(C)
-            todo.append((g, ar, b, js, rs, kx, kc))
+        todo += [(g, ar, b, js, rs) for b, (js, rs) in by_block.items()]
+    # inputs at the left, costs (updates only) at the right
+    width = max(_XB_COLS, *(ar.dim + 1 for ar, _, _, _ in groups)) + max(
+        (ar.n_classes for ar, _, _, c in groups if c is not None), default=0)
+    host = np.zeros((-(-len(todo) // _STAGE_BLOCKS), _STAGE_BLOCKS,
+                     _MAX_BUCKET, width), F32)
+    for slab, (g, ar, _, js, rs) in zip(
+            host.reshape(-1, _MAX_BUCKET, width), todo):
+        _, _, xbs, costs = groups[g]
+        slab[rs, :ar.dim + 1] = xbs[js]
+        if costs is not None:
+            slab[rs, width - ar.n_classes:] = costs[js]
     with spans.span("arena.h2d"):
-        dev = jax.device_put(host)
-    return [(g, ar, b, js, rs, dev[kx], None if kc is None else dev[kc])
-            for g, ar, b, js, rs, kx, kc in todo]
+        dev = jax.device_put(list(host))
+    spans.count("arena.h2d_buffers", len(host))
+    pos = _positions()
+    return [(g, ar, b, js, rs, dev[i // _STAGE_BLOCKS],
+             pos[i % _STAGE_BLOCKS])
+            for i, (g, ar, b, js, rs) in enumerate(todo)]
 
 
 def _update_resident(groups) -> None:
     """Apply each ``(arena, functions, xbs, costs)`` group: one masked
     ``_batched_update`` per touched block, state rebound to its donated
     outputs, nothing read back."""
-    for _, ar, b, js, _, XB, C in _stage(groups):
+    for _, ar, b, js, _, staged, k in _stage(groups):
         with spans.span("arena.launch"):
-            ar.blocks[b] = _batched_update(*ar.blocks[b], XB, C, ar.lr_dev)
+            ar.blocks[b] = _staged_update(_batched_update, *ar.blocks[b],
+                                          staged, k, ar.lr_dev)
         _dispatched("batched_update", ar.dim, len(js))
 
 
@@ -582,9 +646,10 @@ def _predict_resident(groups) -> List[np.ndarray]:
     touched block, then one read of every block's costs."""
     staged = _stage(groups)
     costs = []
-    for _, ar, b, js, _, XB, _ in staged:
+    for _, ar, b, js, _, dev, k in staged:
         with spans.span("arena.launch"):
-            costs.append(_batched_predict(ar.blocks[b][0], XB))
+            costs.append(_staged_predict(_batched_predict, ar.blocks[b][0],
+                                         dev, k))
         _dispatched("batched_predict", ar.dim, len(js))
     with spans.span("arena.d2h"):
         costs = jax.device_get(costs)
@@ -612,8 +677,8 @@ class ArenaEngine:
     every agent of equal dim (vCPU and memory regressors included) into
     a single row-stacked update; on resident state, where each dim's
     vCPU and memory regressors share one stacked arena, the pass copies
-    its inputs in once and launches one masked block update per dim and
-    touched block, reading nothing back."""
+    its inputs in once, as one host array, and launches one masked block
+    update per dim and touched block, reading nothing back."""
 
     def __init__(
         self,

@@ -9,8 +9,10 @@ calibrated NumPy backend and the vmapped JAX fallback, same-timestamp
 arrival microbatching in the simulator, cloned functions whose resident
 agents span two device blocks through the simulator, the resident
 arena's stacked rows (one slot for a function's vCPU and memory agents,
-each side served only where wanted), the retry-payload featurization
-cache, and the legacy-engine golden pin."""
+each side served only where wanted), the resident path's staging (every
+block a call touches in one staged array, at shapes that compile nothing
+after warm-up), the retry-payload featurization cache, and the
+legacy-engine golden pin."""
 
 import json
 import os
@@ -527,6 +529,110 @@ def test_stacked_row_one_slot_and_release_zeroes_both_halves(resident, dim):
         assert not np.asarray(a[r]).any()
     for old, new in zip(bystander, ar.row("b")):
         assert np.array_equal(old, new)
+
+
+def _staged_groups(with_costs):
+    """Groups of one call over three blocks of dim 2 (rows out of slot
+    order, block 1 skipped) and one block of dim 5, each arena stacking
+    72 classes, and the ``(16, dim+1)`` inputs and ``(16, 72)`` costs
+    of each touched block with the call's rows at their slot offsets."""
+    rng = np.random.default_rng(17)
+    B = agent_arena._MAX_BUCKET
+    groups, want = [], {}
+    for dim, slots in ((2, [3, 40, 0, 70, 67, 15]), (5, [9, 2])):
+        ar = AgentArena(72, dim)
+        fns = [f"d{dim}s{s}" for s in range(max(slots) + 1)]
+        for f in fns:
+            ar.slot(f)
+        xbs = np.concatenate([rng.standard_normal((len(slots), dim)),
+                              np.ones((len(slots), 1))], 1).astype(np.float32)
+        costs = (rng.random((len(slots), 72)) * 30).astype(np.float32)
+        groups.append((ar, [fns[s] for s in slots], xbs,
+                       costs if with_costs else None))
+        for j, s in enumerate(slots):
+            xb, c = want.setdefault((dim, s // B), (
+                np.zeros((B, dim + 1), np.float32),
+                np.zeros((B, 72), np.float32)))
+            xb[s % B] = xbs[j]
+            c[s % B] = costs[j]
+    return groups, want
+
+
+@pytest.mark.parametrize("call", ["flush", "predict"])
+def test_stage_lays_every_block_into_one_staged_array(resident, call):
+    """Three blocks of dim 2 and one of dim 5 go in one staged array, at
+    distinct positions; each block's slab holds the block's inputs in its
+    first ``dim+1`` columns and, for a flush, its costs in its last 72,
+    as a block staged on its own would carry them, and zeros elsewhere."""
+    groups, want = _staged_groups(call == "flush")
+    staged = agent_arena._stage(groups)
+    assert len(staged) == len(want) == 4
+    (dev,) = {id(s[5]): s[5] for s in staged}.values()
+    width = agent_arena._XB_COLS + (72 if call == "flush" else 0)
+    assert dev.shape == (agent_arena._STAGE_BLOCKS, agent_arena._MAX_BUCKET,
+                         width)
+    host = np.asarray(dev)
+    ks = [int(k) for *_, k in staged]
+    assert sorted(ks) == list(range(4))
+    for (g, ar, b, js, rs, _, _), k in zip(staged, ks):
+        xb, c = want[(ar.dim, b)]
+        slab = host[k]
+        assert np.array_equal(slab[:, :ar.dim + 1], xb)
+        if call == "flush":
+            assert np.array_equal(slab[:, width - 72:], c)
+            assert not slab[:, ar.dim + 1:width - 72].any()
+        else:
+            assert not slab[:, ar.dim + 1:].any()
+    assert not host[4:].any()
+
+
+def test_resident_calls_compile_nothing_after_warm_up(resident,
+                                                      monkeypatch):
+    """After calibration and one feedback and predict per dim, flush
+    passes and predict calls touching 1, 2, 3 and ``_STAGE_BLOCKS + 1``
+    blocks compile nothing: the staged arrays' shape does not follow the
+    blocks a call touches."""
+    import jax
+
+    B, K = agent_arena._MAX_BUCKET, agent_arena._STAGE_BLOCKS
+    rng = np.random.default_rng(23)
+    eng = ResourceAllocator(engine="arena")._arena
+    fns = {2: [f"a{i}" for i in range((K + 1) * B)], 5: ["b0"]}
+    xs = {f: rng.standard_normal(d).astype(np.float32)
+          for d, names in fns.items() for f in names}
+    agent_arena.calibrate(list(fns))
+    for d, names in fns.items():
+        agent_arena.vmap_backend(d)
+        eng.enqueue_update(names[0], xs[names[0]], _rand_obs(rng))
+        eng.predict(names[0], xs[names[0]], True, True)
+    for f in xs:  # every slot, so every block, before the count
+        eng.enqueue_update(f, xs[f], _rand_obs(rng))
+    eng.flush()
+    staged = []
+    orig = agent_arena._stage
+    monkeypatch.setattr(agent_arena, "_stage",
+                        lambda g: staged.append(orig(g)) or staged[-1])
+    a = fns[2]
+    calls = {1: a[:3], 2: [a[0], a[B + 1]], 3: [a[0], a[B], "b0"],
+             K + 1: a}
+    compiles = []
+
+    def on_event(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        for n, names in calls.items():
+            staged.clear()
+            for f in names:
+                eng.enqueue_update(f, xs[f], _rand_obs(rng))
+            eng.flush()
+            eng.predict_batch([(f, xs[f], True, True) for f in names])
+            assert [len(s) for s in staged] == [n, n]
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert compiles == []
 
 
 def test_arena_growth_preserves_weights():

@@ -3,14 +3,15 @@ forced (no NumPy path, so every dimension keeps its state resident on
 the device, one arena per dimension whose rows stack a function's vCPU
 and memory agents): every device dispatch is a masked 16-row block
 kernel, counted once under its kernel and feature dim with the real rows
-it carries; a flush pass copies in once, an input and a cost block per
-dimension and touched block, launches once per dimension and touched
-block and reads nothing back; a predict call copies in once, launches
-once per dimension and touched block, whichever sides its items want,
-and reads once, a cost block per launch; ``arena.predict_want/*`` count
-the items a stacked predict serves; the arena's copy, launch and own
-host seconds still make up its outermost seconds; and tracing changes no
-decision and no weight."""
+it carries; a flush pass and a predict call each copy in once, one host
+array holding every block they touch (one more per further
+``_STAGE_BLOCKS`` blocks), counted by ``arena.h2d_buffers``; a flush
+pass launches once per dimension and touched block and reads nothing
+back; a predict call launches once per dimension and touched block,
+whichever sides its items want, and reads once, a cost block per launch;
+``arena.predict_want/*`` count the items a stacked predict serves; the
+arena's copy, launch and own host seconds still make up its outermost
+seconds; and tracing changes no decision and no weight."""
 
 import collections
 
@@ -24,6 +25,7 @@ from repro.core.cost_functions import Observation
 
 DIMS = {"f1": 1, "f2": 2, "f3": 3, "f5": 5, "f6": 6, "g3": 3, "g1": 1}
 BLOCK = agent_arena._MAX_BUCKET
+STAGED = agent_arena._STAGE_BLOCKS
 
 
 @pytest.fixture(autouse=True)
@@ -37,25 +39,37 @@ def clean():
 
 @pytest.fixture
 def v5e(monkeypatch):
-    """The v5e's backend table, and kernels that count their calls by
-    (kernel, feature dim) and the block rows each call is handed."""
+    """The v5e's backend table, and kernels that count their launches by
+    (kernel, feature dim): a block kernel's launch is handed a 16-row
+    block and the call's staged array of ``STAGED`` blocks."""
     monkeypatch.setattr(agent_arena, "numpy_backend", lambda d: False)
     calls = collections.Counter()
 
-    def counted(name, fn, dim_of):
+    def counted(name, dim_of):
+        fn = getattr(agent_arena, "_" + name)
+
         def wrapped(*args):
             calls[(name, dim_of(args))] += 1
-            if name.startswith("batched"):
-                assert args[0].shape[0] == BLOCK
             return fn(*args)
         monkeypatch.setattr(agent_arena, "_" + name, wrapped)
 
-    counted("csc_predict", agent_arena._csc_predict, lambda a: a[1].shape[0])
-    counted("csc_update", agent_arena._csc_update, lambda a: a[2].shape[0])
-    counted("batched_predict", agent_arena._batched_predict,
-            lambda a: a[1].shape[1] - 1)
-    counted("batched_update", agent_arena._batched_update,
-            lambda a: a[2].shape[1] - 1)
+    def launched(name):
+        fn = getattr(agent_arena, "_staged_" + name)
+
+        def wrapped(kernel, w, *args):
+            assert kernel is getattr(agent_arena, "_batched_" + name)
+            assert w.shape[0] == BLOCK
+            # after w: (g2, staged, k, lr) or (staged, k)
+            staged = args[-3 if name == "update" else -2]
+            assert staged.shape[:2] == (STAGED, BLOCK)
+            calls[("batched_" + name, w.shape[-1] - 1)] += 1
+            return fn(kernel, w, *args)
+        monkeypatch.setattr(agent_arena, "_staged_" + name, wrapped)
+
+    counted("csc_predict", lambda a: a[1].shape[0])
+    counted("csc_update", lambda a: a[2].shape[0])
+    launched("predict")
+    launched("update")
     return calls
 
 
@@ -202,8 +216,8 @@ def transfers(monkeypatch):
 def test_predict_launches_once_per_wanted_arena_block(v5e, transfers, want):
     """A cohort spanning two blocks of one dim: one launch per touched
     block of the dim's stacked arena, whichever sides the items want,
-    one copy-in of an input block per launch and exactly one read for
-    the whole call, carrying one cost block per launch."""
+    one host array copied in for the whole call and exactly one read,
+    carrying one cost block per launch."""
     eng, xs = _engine(BLOCK + 4)
     v5e.clear()
     transfers["in"].clear()
@@ -218,7 +232,7 @@ def test_predict_launches_once_per_wanted_arena_block(v5e, transfers, want):
     assert snap["counters"]["arena.dispatch_rows"] == len(items)
     assert snap["spans"]["arena.h2d"]["calls"] == 1
     assert snap["spans"]["arena.d2h"]["calls"] == 1
-    assert transfers == {"in": [2], "out": [2]}
+    assert transfers == {"in": [1], "out": [2]}
     assert {k: n for k, n in snap["counters"].items()
             if k.startswith("arena.predict_want/")} == {
         "arena.predict_want/" + WANTS[want]: len(items)}
@@ -246,8 +260,8 @@ def test_predict_splits_a_repeated_function_into_rounds(v5e):
 def test_flush_pass_launches_once_per_arena_block_and_reads_nothing(
         v5e, transfers):
     """A pass over 20 functions of dim 2 (two blocks) and one of dim 5:
-    one launch per dim and touched block, one copy-in of an input and a
-    stacked cost block per launch, no read."""
+    one launch per dim and touched block, one host array copied in that
+    holds every block's inputs and stacked costs, no read."""
     eng, xs = _engine(BLOCK + 4)
     rng = np.random.default_rng(5)
     for f, x in xs.items():
@@ -263,8 +277,47 @@ def test_flush_pass_launches_once_per_arena_block_and_reads_nothing(
     assert snap["counters"]["arena.flush_pass"] == 1
     assert snap["counters"]["arena.dispatch_rows"] == len(xs) + 1
     assert snap["spans"]["arena.h2d"]["calls"] == 1
-    assert transfers == {"in": [2 * 3], "out": []}
+    assert transfers == {"in": [1], "out": []}
+    assert snap["counters"]["arena.h2d_buffers"] == 1
     assert "arena.d2h" not in snap["spans"]
+
+
+@pytest.mark.parametrize("call", ["flush", "predict"])
+def test_a_call_past_one_staged_array_copies_in_one_more(v5e, transfers,
+                                                         call):
+    """A flush pass or a predict call touching ``STAGED + 1`` blocks of
+    one dim: two host arrays in one copy-in, inside one ``arena.h2d``
+    span, and one launch per block."""
+    eng, xs = _engine(STAGED * BLOCK + 1)
+    if call == "flush":
+        rng = np.random.default_rng(9)
+        for f, x in xs.items():
+            eng.enqueue_update(f, x, _obs(rng))
+    v5e.clear()
+    transfers["in"].clear()
+    spans.enable()
+    if call == "flush":
+        eng.flush()
+    else:
+        eng.predict_batch([(f, x, True, True) for f, x in xs.items()])
+    snap = spans.snapshot()
+    kernel = "batched_update" if call == "flush" else "batched_predict"
+    assert v5e == {(kernel, 2): STAGED + 1}
+    assert snap["counters"]["arena.dispatch_rows"] == len(xs)
+    assert snap["spans"]["arena.h2d"]["calls"] == 1
+    assert transfers["in"] == [2]
+    assert snap["counters"]["arena.h2d_buffers"] == 2
+
+
+def test_h2d_buffers_count_one_array_a_copy_in(v5e):
+    """Over a random stream whose calls touch fewer than ``STAGED + 1``
+    blocks, every copy-in carries one host array."""
+    spans.enable()
+    _stream()
+    snap = spans.snapshot()
+    h2d = snap["spans"]["arena.h2d"]["calls"]
+    assert h2d > 0
+    assert snap["counters"]["arena.h2d_buffers"] == h2d
 
 
 def test_tracing_changes_no_decision_and_no_weight(v5e):

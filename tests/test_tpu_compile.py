@@ -5,8 +5,9 @@ compiles each kernel at the shapes the simulator dispatches — 32 vCPU
 and 40 memory classes, the feature dims of the 12 functions (1, 2, 3,
 5, 6), the resident arena's 16-row block and the 1-row bucket — and the
 block kernels at the resident block whose rows stack both agents' 72
-classes. Every program must stay in f32: no bf16 anywhere in the
-compiled text.
+classes. A block kernel is compiled as it is launched: on one block of a
+call's staged array. Every program must stay in f32: no bf16 anywhere in
+the compiled text.
 
 The topology is described inside a module-scoped fixture, never while
 a module is imported, and the persistent compile cache is off around
@@ -59,12 +60,17 @@ def _lower(kernel, n, dim, bucket, s):
         return agent_arena._csc_update.lower(
             _f32((n, dim + 1), s), _f32((n, dim + 1), s), _f32((dim,), s),
             _f32((n,), s), _f32((), s))
+    # a block kernel is launched on a block of its call's staged array
+    k = jax.ShapeDtypeStruct((), jnp.int32, sharding=s)
+    staged = (agent_arena._STAGE_BLOCKS, bucket, agent_arena._XB_COLS)
     if kernel == "_batched_update":
-        return agent_arena._batched_update.lower(
+        return agent_arena._staged_update.lower(
+            agent_arena._batched_update,
             _f32((bucket, n, dim + 1), s), _f32((bucket, n, dim + 1), s),
-            _f32((bucket, dim + 1), s), _f32((bucket, n), s), _f32((), s))
-    return agent_arena._batched_predict.lower(
-        _f32((bucket, n, dim + 1), s), _f32((bucket, dim + 1), s))
+            _f32(staged[:2] + (staged[2] + n,), s), k, _f32((), s))
+    return agent_arena._staged_predict.lower(
+        agent_arena._batched_predict, _f32((bucket, n, dim + 1), s),
+        _f32(staged, s), k)
 
 
 # (n_classes, dim, bucket): the 16-row block, the one shape every
